@@ -307,9 +307,11 @@ def run(n_params=64, shape=(64, 32), batch=32, hidden=256, iters=10,
         ok = (ok and ratios["spmd_ring_int8_per_hop"] >= 3.5
               and ratios["spmd_ring_int4_per_hop"] >= 6.0)
     recompiles = sum(r["post_warmup_recompiles"] for r in spmd_ab.values())
+    from incubator_mxnet_tpu import config
+
     return {
         "bench": "collectives",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "n_params": n_params,
         "shape": list(shape),
         "batch": batch,
@@ -346,6 +348,9 @@ def main(argv=None):
                         "per-hop for the ring)")
     p.add_argument("--json", dest="json_path", default=None, metavar="PATH")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     kw = dict(n_params=args.n_params, shape=(args.side, 32),
               batch=args.batch, hidden=args.hidden, iters=args.iters,
               warmup=args.warmup, repeats=args.repeats, algo=args.algo)

@@ -138,9 +138,11 @@ def run(n_ops=64, iters=30, shape=(8, 8), warmup=5, repeats=5):
                                     warmup=prev[2])
         registry.clear_dispatch_cache()
 
+    from incubator_mxnet_tpu import config
+
     line = {
         "bench": "eager_dispatch",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "n_ops": n_ops,
         "iters": iters,
         "warmup": warmup,
@@ -174,6 +176,9 @@ def main(argv=None):
                         "config) bench trajectory harvesting reads instead "
                         "of hand-copied numbers")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     line = run(n_ops=args.n_ops, iters=args.iters,
                shape=(args.side, args.side), warmup=args.warmup,
                repeats=args.repeats)

@@ -168,9 +168,11 @@ def run(n_requests=120, units=24, layers=1, max_prompt=16, max_new=24,
     net = build_model(units=units, layers=layers, seed=seed)
     prompts, budgets = make_workload(n_requests, max_prompt, max_new, seed)
 
+    from incubator_mxnet_tpu import config
+
     line = {
         "bench": "generation",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "smoke": smoke,
         "n_requests": n_requests,
         "units": units,
@@ -243,6 +245,9 @@ def main(argv=None):
     p.add_argument("--json", dest="json_path", default=None, metavar="PATH",
                    help="also write the result object to PATH")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     if args.smoke:
         cfg = dict(n_requests=40, units=16, layers=1, max_prompt=8,
                    max_new=12, slots=4, ttft_slo_ms=args.ttft_slo_ms,

@@ -167,9 +167,11 @@ def run(steps=40, warmup=8, trials=3, batch=256, feat=512, hidden=1024,
                 extras = extra  # last trial's steady-state evidence
     medians = {m: _median(ts) for m, ts in times.items()}
     steps_per_sec = {m: steps / v for m, v in medians.items()}
+    from incubator_mxnet_tpu import config
+
     return {
         "bench": "input_pipeline",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "devices": len(jax.devices()),
         "steps": steps,
         "warmup": warmup,
@@ -213,6 +215,9 @@ def main(argv=None):
                    help="also write the result object to PATH — the "
                         "machine-readable record evidence harvesting reads")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     kw = dict(steps=args.steps, warmup=args.warmup, trials=args.trials,
               batch=args.batch, feat=args.feat, layers=args.layers,
               host_ms=args.host_ms, num_workers=args.workers)

@@ -218,9 +218,11 @@ def run(n_stages=4, layers_per_stage=1, n_microbatches=8, batch=16, seq=8,
     ok = (bubbles["1f1b"]["bubble_fraction"]
           < bubbles["gpipe"]["bubble_fraction"]
           and bubbles["1f1b"]["bubble_fraction"] <= 1.5 * analytic)
+    from incubator_mxnet_tpu import config
+
     return {
         "bench": "pipeline",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "stages": P,
         "layers_per_stage": layers_per_stage,
         "microbatches": n_microbatches,
@@ -256,6 +258,9 @@ def main(argv=None):
                         "bubble-acceptance failure)")
     p.add_argument("--json", dest="json_path", default=None, metavar="PATH")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     kw = dict(n_stages=args.stages, layers_per_stage=args.layers_per_stage,
               n_microbatches=args.microbatches, batch=args.batch,
               seq=args.seq, units=args.units, hidden=args.hidden,

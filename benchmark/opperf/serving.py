@@ -250,9 +250,11 @@ def run(n_requests=400, layers=4, feat=64, max_length=128, max_batch=16,
     speedup = None
     if seq_best and served_best:
         speedup = round(served_best["throughput"] / seq_best["throughput"], 2)
+    from incubator_mxnet_tpu import config
+
     line = {
         "bench": "serving",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "smoke": smoke,
         "slo_ms": slo_ms,
         "n_requests": n_requests,
@@ -297,6 +299,9 @@ def main(argv=None):
     p.add_argument("--json", dest="json_path", default=None, metavar="PATH",
                    help="also write the result object to PATH")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     if args.smoke:
         cfg = dict(n_requests=80, layers=2, feat=16, max_length=64,
                    max_batch=8, slo_ms=args.slo_ms, seed=args.seed,

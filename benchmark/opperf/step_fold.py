@@ -160,9 +160,11 @@ def run(layers=12, width=32, batch=8, iters=10, warmup=4, repeats=3):
             gc.enable()
     medians = {m: _median(ts) for m, ts in times.items()}
     steps_per_sec = {m: 1.0 / v for m, v in medians.items()}
+    from incubator_mxnet_tpu import config
+
     return {
         "bench": "step_fold",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "layers": layers, "width": width, "batch": batch,
         "rounds": rounds,
         "steps_per_sec": {m: round(v, 2) for m, v in steps_per_sec.items()},
@@ -256,9 +258,11 @@ def run_k_sweep(ks=(1, 4, 16), layers=12, width=32, batch=8, iters=10,
     recompiles = (profiler.counters()["recompile_steady_state"] - c_base)
     medians = {k: _median(ts) for k, ts in times.items()}
     kmax, kmin = max(ks), min(ks)
+    from incubator_mxnet_tpu import config
+
     return {
         "bench": "step_fold_k_sweep",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "layers": layers, "width": width, "batch": batch,
         "rounds": rounds, "ks": list(ks),
         "logical_steps_per_sec": {str(k): round(1.0 / m, 2)
@@ -421,6 +425,9 @@ def main(argv=None):
                    help=argparse.SUPPRESS)
     p.add_argument("--json", dest="json_path", default=None, metavar="PATH")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
 
     if args.dist_worker:
         dist_worker(args.layers or 12, args.width or 256, args.batch or 32,

@@ -103,9 +103,11 @@ def run(n_params=200, shape=(16, 4), iters=10, warmup=3, repeats=3,
 
     medians = {m: _median(ts) for m, ts in times.items()}
     steps_per_sec = {m: 1.0 / v for m, v in medians.items()}
+    from incubator_mxnet_tpu import config
+
     return {
         "bench": "trainer_step",
-        "backend": os.environ.get("JAX_PLATFORMS", "default"),
+        **config.device_record(),
         "n_params": n_params,
         "shape": list(shape),
         "optimizer": optimizer,
@@ -138,6 +140,9 @@ def main(argv=None):
                         "config) bench trajectory harvesting reads instead "
                         "of hand-copied numbers")
     args = p.parse_args(argv)
+    from incubator_mxnet_tpu import config
+
+    config.enable_compile_cache()
     line = run(n_params=args.n_params, iters=args.iters,
                shape=(args.side, 4), warmup=args.warmup,
                repeats=args.repeats, optimizer=args.optimizer)

@@ -49,7 +49,7 @@ keep the aggregate-residual invariant.
 
 The replication checker cannot see through ``ppermute`` — wrap bodies
 that return ring results replicated with
-``get_shard_map(check_rep=False)`` (parallel/mesh.py).
+``jax.shard_map(..., check_vma=False)``.
 """
 from __future__ import annotations
 
@@ -365,8 +365,6 @@ def ring_allreduce_sharded(codec, flat, mesh, axis_names=("dp",),
     the two decode bit-identically at world size 1."""
     axis_names = (axis_names,) if isinstance(axis_names, str) \
         else tuple(axis_names)
-    from ..parallel.mesh import get_shard_map
-
     site = "comm.ring_allreduce" if algo == "ring" else "comm.psum_allreduce"
     key = (site, codec.id, axis_names, tuple(flat.shape), str(flat.dtype))
     sig = {"codec": codec.id, "axes": "x".join(axis_names),
@@ -377,9 +375,9 @@ def ring_allreduce_sharded(codec, flat, mesh, axis_names=("dp",),
             return _comp.traced_allreduce(codec, x, None, axis_names,
                                           algo=algo)
 
-        smap = get_shard_map(check_rep=False)
-        return jax.jit(smap(body, mesh=mesh, in_specs=(P(),),
-                            out_specs=(P(), P(axis_names))))
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P(),),
+            out_specs=(P(), P(axis_names)), check_vma=False))
 
     fn = _compiled(site, key, sig, build)
     return fn(flat)
@@ -392,8 +390,6 @@ def ring_rs_ag_sharded(codec, flat, mesh, axis_name="fsdp"):
     the reduced shards — the standalone twin of the fsdp step's comm
     structure.  ``flat`` length must divide by the axis size; returns
     ``(gathered, residual)`` global arrays."""
-    from ..parallel.mesh import get_shard_map
-
     key = ("comm.ring_rs_ag", codec.id, axis_name, tuple(flat.shape),
            str(flat.dtype))
     sig = {"codec": codec.id, "axes": axis_name,
@@ -404,9 +400,9 @@ def ring_rs_ag_sharded(codec, flat, mesh, axis_name="fsdp"):
             shard, err = ring_reduce_scatter(codec, x, None, axis_name)
             return ring_all_gather(codec, shard, axis_name), err
 
-        smap = get_shard_map(check_rep=False)
-        return jax.jit(smap(body, mesh=mesh, in_specs=(P(),),
-                            out_specs=(P(), P(axis_name))))
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P(),),
+            out_specs=(P(), P(axis_name)), check_vma=False))
 
     fn = _compiled("comm.ring_rs_ag", key, sig, build)
     return fn(flat)

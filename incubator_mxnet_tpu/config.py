@@ -38,14 +38,50 @@ MXNET_TEST_CTX                    ``tpu`` enables the real-chip test tier
 ================================  ============================================
 
 ``describe()`` prints the live table with current values.
+
+Two helpers every measurement entry point (``chip_smoke.py``, ``bench.py``,
+``benchmark/opperf/*``) calls before its first compile:
+:func:`enable_compile_cache` and :func:`device_record`.
 """
 from __future__ import annotations
 
 import os
 
-__all__ = ["apply_env", "describe", "memory_info"]
+__all__ = ["apply_env", "describe", "memory_info", "enable_compile_cache",
+           "device_record"]
 
 _APPLIED = {}
+
+# <checkout>/.jax_cache — located from this file, never from cwd, a temp
+# dir, a pid or a clock: the directory is part of the cache key, so a
+# default that moves between runs never hits
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets no other; otherwise the cache lives at
+    ``<checkout>/.jax_cache``.  JAX's own thresholds decide what is worth
+    an entry (compiles of a second or more)."""
+    import jax
+
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir
+
+
+def device_record():
+    """Where this process runs, as JAX reports it — the three fields every
+    benchmark record carries so no number can pass for a device metric
+    without naming its device."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "device_kind": devs[0].device_kind,
+            "n_devices": len(devs)}
 
 
 def apply_env():
@@ -87,10 +123,7 @@ def apply_env():
     if os.environ.get("MXNET_ENFORCE_DETERMINISM") == "1":
         import jax
 
-        try:
-            jax.config.update("jax_threefry_partitionable", False)
-        except Exception:
-            pass
+        jax.config.update("jax_threefry_partitionable", False)
         _APPLIED["MXNET_ENFORCE_DETERMINISM"] = "threefry sequential"
 
     # Hardware PRNG by default: threefry computes its bits in the loop
@@ -114,11 +147,11 @@ def apply_env():
         prng = "rbg"
     import jax
 
-    try:
-        jax.config.update("jax_default_prng_impl", prng)
-        _APPLIED["MXNET_TPU_PRNG"] = f"jax_default_prng_impl={prng}"
-    except Exception:
-        pass
+    # JAX names its default impl "threefry2x32"; MXNET_TPU_PRNG keeps the
+    # short spelling
+    impl = "threefry2x32" if prng == "threefry" else prng
+    jax.config.update("jax_default_prng_impl", impl)
+    _APPLIED["MXNET_TPU_PRNG"] = f"jax_default_prng_impl={impl}"
 
 
 def describe():

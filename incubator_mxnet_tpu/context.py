@@ -2,10 +2,11 @@
 
 Parity target: [U:python/mxnet/context.py] (Context objects, ``with ctx:``
 scoping, ``num_gpus()``) — extended with ``mx.tpu()`` as a first-class context
-per the north-star.  A Context resolves lazily to a concrete ``jax.Device``;
-``gpu``/``tpu`` fall back to whatever accelerator JAX exposes (on this image the
-TPU chip may surface under an experimental platform name), and finally to CPU so
-CPU-only test runs still work by swapping nothing.
+per the north-star.  A Context resolves lazily to a concrete ``jax.Device``.
+``gpu``/``tpu`` name the i-th accelerator ``jax.local_devices()`` holds
+(``mx.gpu()`` aliases the TPU so unmodified ``ctx=mx.gpu()`` scripts run) and
+raise ``MXNetError`` when there is no such device: a requested accelerator is
+never substituted by the host CPU or by another chip.
 """
 from __future__ import annotations
 
@@ -90,8 +91,7 @@ def _accelerator_devices():
 
     # process-LOCAL: under multi-process (dist kvstore / launch_local.py)
     # eager arrays must land on a device this process can address
-    devs = jax.local_devices()
-    return [d for d in devs if d.platform not in ("cpu",)] or []
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def _resolve_jax_device(device_type, device_id):
@@ -114,10 +114,14 @@ def _resolve_jax_device(device_type, device_id):
             dev = jax.local_devices()[0]
     else:
         accel = _accelerator_devices()
-        if accel:
-            dev = accel[device_id % len(accel)]
-        else:
-            dev = jax.local_devices()[min(device_id, len(jax.local_devices()) - 1)]
+        if not 0 <= device_id < len(accel):
+            from .base import MXNetError
+
+            raise MXNetError(
+                f"{device_type}({device_id}) requested but this process "
+                f"holds {len(accel)} accelerator device(s); "
+                f"jax.local_devices() = {jax.local_devices()}")
+        dev = accel[device_id]
     with _device_lock:
         _device_cache[key] = dev
     return dev

@@ -791,7 +791,6 @@ class StepProgram:
 
         from .. import kvstore as kv_mod
         from ..comm import compression as comp_mod
-        from ..parallel.mesh import get_shard_map
 
         tr = self._trainer
         mesh = kv._worker_mesh()
@@ -815,10 +814,7 @@ class StepProgram:
                 off += int(a.size)
             buckets.append((bk["codec"], tuple(rows)))
         n_train = len(touched)
-        # ring outputs are replicated by explicit ppermute relay, which
-        # the static replication checker cannot infer through
         algo = policy.algo if policy is not None else "psum"
-        smap = get_shard_map(check_rep=(algo != "ring"))
         P0 = P()
         PW = P("w")
         # per-LOGICAL-step batch spec: inside a K-window the scan body
@@ -860,10 +856,12 @@ class StepProgram:
             aux_vals = tuple(jax.lax.pmean(a, "w") for a in aux_vals)
             return (tuple(new_grads), tuple(new_resid), loss_out, aux_vals)
 
-        mapped = smap(
+        # ring outputs are replicated by explicit ppermute relay, which
+        # the static replication checker cannot infer through
+        mapped = jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P0, P0, P0, PW) + batch_specs,
-            out_specs=(P0, PW, PW, P0))
+            out_specs=(P0, PW, PW, P0), check_vma=(algo != "ring"))
 
         def dist_step(key, lr, wd, t, scalars, param_arrs, state_arrs,
                       residuals, batch):

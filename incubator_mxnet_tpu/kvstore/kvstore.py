@@ -595,14 +595,7 @@ class KVStoreDist(KVStore):
 
             from ..parallel.mesh import init_distributed
 
-            try:
-                already = jax.distributed.is_initialized()
-            except AttributeError:  # older jax
-                already = getattr(
-                    getattr(getattr(jax, "_src", None), "distributed", None),
-                    "global_state", None) is not None and \
-                    jax._src.distributed.global_state.client is not None
-            if not already:
+            if not jax.distributed.is_initialized():
                 init_distributed()
         self._initialized_dist = True
 

@@ -5,8 +5,9 @@ area where this framework intentionally EXCEEDS the reference).
 
 Three tiers, chosen by :func:`flash_attention`:
 
-1. **Pallas flash kernel** (TPU, and CPU tests via ``interpret=True``):
-   blockwise online-softmax forward — queries tiled over the grid, K/V
+1. **Pallas flash kernel** (compiled by Mosaic on TPU; the Pallas
+   interpreter only when asked for by name, ``MXNET_TPU_FLASH=interpret``
+   — the CPU test tier does): blockwise online-softmax forward — queries tiled over the grid, K/V
    streamed through VMEM in ``block_k`` chunks, so the S×S score matrix is
    never materialized in HBM.  Accumulation in fp32 on the MXU
    (``preferred_element_type``), inputs may be bf16.
@@ -40,6 +41,7 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as _pl
 
 __all__ = ["flash_attention", "attention_reference"]
 
@@ -49,17 +51,20 @@ _LANE = 128
 
 
 def _use_pallas(x=None):
+    """(use the kernel, run it in the interpreter).  ``on`` means the
+    COMPILED kernel wherever the call lands — off-TPU that is a lowering
+    error, not a quiet switch to the interpreter; interpret mode is only
+    ever asked for by name."""
     mode = os.environ.get("MXNET_TPU_FLASH", "auto")
     if mode == "off":
         return False, False
     if mode == "interpret":
         return True, True
+    if mode == "on":
+        return True, False
     from ..util import resolve_platform
 
-    on_tpu = resolve_platform(x) == "tpu"
-    if mode == "on":
-        return True, not on_tpu
-    return on_tpu, False  # auto
+    return resolve_platform(x) == "tpu", False  # auto
 
 
 # ---------------------------------------------------------------------------
@@ -143,17 +148,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
         # keeps their gradient contributions zero.
         m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
         lse_ref[0] = jnp.broadcast_to(m_safe + jnp.log(l), lse_ref.shape[1:])
-
-
-try:  # pallas import is deferred-safe: CPU-only jax builds still have it
-    from jax.experimental import pallas as _pl
-    from jax.experimental.pallas import tpu as _pltpu
-
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _pl = None
-    _pltpu = None
-    _HAVE_PALLAS = False
 
 
 def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=128,
@@ -402,8 +396,8 @@ def _should_use_pallas(q, k, seq_axis=2):
         use = False  # Mosaic has no f16; XLA reference path handles it
     if use and not interpret and max(sq, sk) < _PALLAS_FWD_MIN_SEQ:
         use = False
-    blocks = _pallas_blocks(sq, sk) if use and _HAVE_PALLAS else None
-    return use and _HAVE_PALLAS and blocks is not None, interpret, blocks
+    blocks = _pallas_blocks(sq, sk) if use else None
+    return blocks is not None, interpret, blocks
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

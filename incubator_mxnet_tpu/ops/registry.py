@@ -51,13 +51,10 @@ import numpy as _np
 # an exact type test against the one concrete array class)
 _JArray = _jax.Array
 _JTracer = _jax.core.Tracer
-try:
-    # the concrete eager array class, WITHOUT running a computation —
-    # type(jnp.zeros(())) would initialize the XLA backend at import time
-    # and break jax.distributed.initialize() on multi-host workers
-    from jax._src.array import ArrayImpl as _ArrayImpl
-except ImportError:  # jax internals moved: exact-type fast path off,
-    _ArrayImpl = ()  # the isinstance(_JArray) slow path still catches all
+# the concrete eager array class, WITHOUT running a computation —
+# type(jnp.zeros(())) would initialize the XLA backend at import time
+# and break jax.distributed.initialize() on multi-host workers
+from jax._src.array import ArrayImpl as _ArrayImpl
 
 _SDSharding = _jax.sharding.SingleDeviceSharding
 _SCALAR_TYPES = frozenset((bool, int, float, complex, str, type(None)))

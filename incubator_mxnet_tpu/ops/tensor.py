@@ -202,10 +202,7 @@ for _name, _fn in _UNARY.items():
 def gamma_fn(x):
     """Γ(x) (MXNet ``gamma`` is the gamma *function*, distinct from
     ``gammaln``)."""
-    try:
-        return jax.scipy.special.gamma(x)
-    except AttributeError:
-        return jnp.exp(jax.scipy.special.gammaln(x))
+    return jax.scipy.special.gamma(x)
 
 
 @register("digamma")

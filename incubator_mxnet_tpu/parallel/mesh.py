@@ -40,36 +40,8 @@ __all__ = [
     "init_distributed",
     "mesh_scope",
     "sync_profiler_clock",
-    "get_shard_map",
 ]
 
-
-def get_shard_map(check_rep=True):
-    """THE ``shard_map`` entry for the whole repo.  The stable location has
-    moved across jax releases (``jax.shard_map`` → only some versions;
-    ``jax.experimental.shard_map.shard_map`` → everywhere this repo
-    supports), and resolving it per call site already produced one broken
-    tier (TestRingAttention at HEAD) — so every user goes through here.
-
-    ``check_rep=False`` disables shard_map's static replication check —
-    required by bodies whose replicated outputs are built from explicit
-    ``ppermute`` exchange (the quantized ring collectives in
-    ``comm/ring.py``: every device decodes the SAME relayed codes, so the
-    result is replicated by construction, but the checker cannot infer
-    replication through ppermute).  The keyword's name moved across jax
-    releases (``check_rep`` → ``check_vma``); the wrapper tries both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if check_rep:
-        return sm
-
-    def unchecked(*args, **kwargs):
-        try:
-            return sm(*args, check_rep=False, **kwargs)
-        except TypeError:
-            return sm(*args, check_vma=False, **kwargs)
-    return unchecked
 
 # Outermost → innermost.  jax.devices() enumerates in topology order on TPU
 # and the last axes step fastest through it, so the bandwidth-hungriest
@@ -177,10 +149,7 @@ def init_distributed(coordinator_address=None, num_processes=None, process_id=No
     # gloo implementation selected BEFORE the backend initializes.
     if "cpu" in os.environ.get("JAX_PLATFORMS", "").lower():
         impl = os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", impl)
-        except Exception:
-            pass  # older jax: flag absent — keep the previous behavior
+        jax.config.update("jax_cpu_collectives_implementation", impl)
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,

@@ -72,10 +72,6 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, n_microbatches, axis="pp"):
         raise ValueError(f"batch {B} not divisible by {n_microbatches} microbatches")
     mb = B // n_microbatches
 
-    from .mesh import get_shard_map
-
-    shard_map = get_shard_map()
-
     in_specs = (
         jax.tree_util.tree_map(
             lambda leaf: P(*((axis,) + (None,) * (leaf.ndim - 1))), stage_params),
@@ -117,10 +113,6 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, n_microbatches, axis="pp"):
         outs = lax.psum(jnp.where(s == pp - 1, outs, jnp.zeros_like(outs)), axis)
         return outs.reshape((B,) + xin.shape[1:])
 
-    try:  # stable API (check_vma) vs experimental (check_rep)
-        fn = shard_map(ranked, mesh=mesh, in_specs=in_specs,
+    fn = jax.shard_map(ranked, mesh=mesh, in_specs=in_specs,
                        out_specs=out_spec, check_vma=False)
-    except TypeError:
-        fn = shard_map(ranked, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_spec, check_rep=False)
     return fn(stage_params, x)

@@ -86,11 +86,9 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None):
 def ring_attention_sharded(q, k, v, mesh, causal=False, scale=None, batch_axes=("dp", "fsdp")):
     """Global-array entry: q/k/v are [B, H, S, D] jax.Arrays; the sequence
     axis is sharded over 'sp' and batch over ``batch_axes``."""
-    from .mesh import get_shard_map
-
     spec = P(batch_axes, None, "sp", None)
     fn = functools.partial(ring_attention, causal=causal, scale=scale)
-    return get_shard_map()(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -1207,7 +1207,6 @@ class SPMDTrainer:
         BatchNorm aux updates see the LOCAL batch shard and are pmean'd
         — the multi-worker data-parallel convention, not the global-batch
         one the uncompressed single-program build computes."""
-        from .mesh import get_shard_map
         from ..comm import compression as comp_mod
 
         cfg = self._comm_cfg
@@ -1222,9 +1221,6 @@ class SPMDTrainer:
         mesh = self._mesh
         AX = ("dp", "fsdp")
         fsdp = int(mesh.shape["fsdp"])
-        # ring outputs are replicated by explicit relay, which the static
-        # replication checker cannot see through ppermute
-        smap = get_shard_map(check_rep=(algo != "ring"))
         P0 = P()
         batch_specs = tuple(batch_pspec(a.ndim) for a in example_arrays)
 
@@ -1278,8 +1274,11 @@ class SPMDTrainer:
             else:
                 comm_state, batch = None, rest
             train_arrs = [param_arrs[j] for j in trainable_idx]
-            mapped = smap(shard_body, mesh=mesh,
-                          in_specs=in_specs, out_specs=out_specs)
+            # ring outputs are replicated by explicit relay, which the
+            # static replication checker cannot see through ppermute
+            mapped = jax.shard_map(shard_body, mesh=mesh,
+                                   in_specs=in_specs, out_specs=out_specs,
+                                   check_vma=(algo != "ring"))
             if ef:
                 grads_t, new_comm, loss_mean, aux_vals, extras = mapped(
                     train_arrs, list(param_arrs), key, comm_state, *batch)
@@ -1314,7 +1313,6 @@ class SPMDTrainer:
         Gradients return with the parameter shardings, so the optimizer
         tail outside the shard_map partitions elementwise with zero
         comms."""
-        from .mesh import get_shard_map
         from ..comm import ring as ring_mod
 
         cfg = self._comm_cfg
@@ -1337,7 +1335,6 @@ class SPMDTrainer:
         def is_sharded(spec):
             return len(spec) > 0 and spec[0] is not None
 
-        smap = get_shard_map(check_rep=False)
         P0 = P()
         batch_specs = tuple(batch_pspec(a.ndim) for a in example_arrays)
 
@@ -1432,8 +1429,9 @@ class SPMDTrainer:
             else:
                 comm_state, batch = None, rest
             train_arrs = tuple(param_arrs[j] for j in trainable_idx)
-            mapped = smap(shard_body, mesh=mesh,
-                          in_specs=in_specs, out_specs=out_specs)
+            mapped = jax.shard_map(shard_body, mesh=mesh,
+                                   in_specs=in_specs, out_specs=out_specs,
+                                   check_vma=False)
             if ef:
                 grads_t, new_comm, loss_mean, aux_vals, extras = mapped(
                     train_arrs, tuple(param_arrs), key, comm_state, *batch)

@@ -148,8 +148,15 @@ class StatefulExecutor:
         for k, v in self._state.items():
             sig[f"state:{k}"] = profiler.sig_array(v)
         for k, v in (inputs or {}).items():
-            sig[k] = (profiler.sig_array(v) if hasattr(v, "shape")
-                      else profiler.sig_static(v))
+            if hasattr(v, "shape"):
+                sig[k] = profiler.sig_array(v)
+            elif isinstance(v, (list, tuple)):
+                # a pytree of arrays (e.g. a server's frozen weights):
+                # one token per leaf — repr() would pull them to the host
+                for i, leaf in enumerate(v):
+                    sig[f"{k}[{i}]"] = profiler.sig_array(leaf)
+            else:
+                sig[k] = profiler.sig_static(v)
         return sig
 
     def run(self, program, **inputs):

@@ -13,9 +13,11 @@ input ``Ref``s then output ``Ref``s and writes results with ``o[...] =``.
 ``launch`` mirrors the reference's shape: positional NDArray args, an
 optional grid, and it allocates + returns the outputs.
 
-Off-TPU the kernel runs under ``interpret=True`` (the same dispatch
-discipline as ops/attention.py), so rtc kernels are testable on the CPU
-mesh.  Like the reference, rtc kernels are raw compute: no autograd
+Kernels are compiled by Mosaic for the TPU.  ``PallasModule(...,
+interpret=True)`` runs them in the Pallas interpreter instead — asked for
+by name (the same discipline as ops/attention.py), which is how the CPU
+test tier exercises them; without it a launch off-TPU is a lowering error,
+never a quiet switch to the interpreter.  Like the reference, rtc kernels are raw compute: no autograd
 (wrap one in ``mx.operator.CustomOp`` to differentiate through it).
 """
 from __future__ import annotations
@@ -25,16 +27,16 @@ import textwrap
 import jax
 import jax.numpy as jnp
 
-from .util import resolve_platform
-
 __all__ = ["PallasModule"]
 
 
 class Kernel:
     """A launchable kernel (parity shape: ``mx.rtc.CudaKernel``)."""
 
-    def __init__(self, fn, name, out_shapes, out_dtypes, grid, in_specs, out_specs):
+    def __init__(self, fn, name, out_shapes, out_dtypes, grid, in_specs,
+                 out_specs, interpret=False):
         self._fn = fn
+        self._interpret = bool(interpret)
         self.name = name
         self._out_shapes = tuple(tuple(s) for s in out_shapes)
         self._out_dtypes = tuple(out_dtypes)
@@ -42,12 +44,11 @@ class Kernel:
         self._in_specs = in_specs
         self._out_specs = out_specs
         # compiled-once discipline (the reference compiles at get_kernel
-        # time): pallas_call closures cached per (grid, platform)
+        # time): pallas_call closures cached per grid
         self._calls = {}
 
-    def _call(self, grid, platform):
-        key = (grid, platform)
-        call = self._calls.get(key)
+    def _call(self, grid):
+        call = self._calls.get(grid)
         if call is not None:
             return call
         from jax.experimental import pallas as pl
@@ -65,10 +66,10 @@ class Kernel:
         call = jax.jit(pl.pallas_call(
             self._fn,
             out_shape=out_shape[0] if single else out_shape,
-            interpret=platform != "tpu",
+            interpret=self._interpret,
             **kwargs,
         ))
-        self._calls[key] = call
+        self._calls[grid] = call
         return call
 
     def launch(self, args, ctx=None, grid_dims=None):
@@ -86,8 +87,7 @@ class Kernel:
         if isinstance(grid, list):
             grid = tuple(grid)
         xs = [a._data if isinstance(a, NDArray) else jnp.asarray(a) for a in args]
-        platform = resolve_platform(xs[0] if xs else None)
-        out = self._call(grid, platform)(*xs)
+        out = self._call(grid)(*xs)
         if len(self._out_shapes) == 1:
             return NDArray(out)
         return tuple(NDArray(o) for o in out)
@@ -102,6 +102,8 @@ class PallasModule:
     ``pl``, ``jnp``, ``jax`` — the runtime-compilation analog of NVRTC),
     or a callable / iterable of callables.  ``exports`` optionally limits
     which names are retrievable, like the reference's exports list.
+    ``interpret=True`` runs every kernel of the module in the Pallas
+    interpreter (debugging / CPU tests) instead of compiling it for TPU.
 
     Example::
 
@@ -114,9 +116,10 @@ class PallasModule:
         z = k.launch([x, y])
     """
 
-    def __init__(self, source, exports=()):
+    def __init__(self, source, exports=(), interpret=False):
         from jax.experimental import pallas as pl
 
+        self._interpret = bool(interpret)
         self._kernels = {}
         if isinstance(source, str):
             ns = {"pl": pl, "jnp": jnp, "jax": jax}
@@ -167,4 +170,4 @@ class PallasModule:
                 f"out_specs has {len(out_specs)} entries for "
                 f"{len(out_shapes)} out_shapes")
         return Kernel(self._kernels[name], name, out_shapes, out_dtypes,
-                      grid, in_specs, out_specs)
+                      grid, in_specs, out_specs, interpret=self._interpret)

@@ -306,7 +306,19 @@ class _TransformerAdapter:
                 "model parameters are uninitialized/deferred — run one "
                 "forward (or load a checkpoint) before binding a "
                 "GenerationServer")
-        self.param_arrays = [p._data._data for p in self.params]
+        # The server runs where its KV cache lands — the process's default
+        # device — and the weights ride every program as ARGUMENTS (not
+        # closed-over constants: XLA would bake a copy of them into each
+        # bucket's program and constant-fold over it).  Committed
+        # arguments decide where a program runs, so place them here:
+        # ``net.initialize()`` with no ctx commits parameters to the host
+        # CPU, and a TPU host would otherwise decode there without a word.
+        import jax
+        import jax.numpy as jnp
+
+        self.device = next(iter(jnp.zeros(()).devices()))
+        self.param_arrays = jax.device_put(
+            [p._data._data for p in self.params], self.device)
         self.dtype = self.param_arrays[0].dtype
 
     def _attend(self, q, k, v, mask):
@@ -324,7 +336,7 @@ class _TransformerAdapter:
         return out.reshape(out.shape[0], 1, self.units)
 
     def make_prefill(self, prompt_bucket, mem_width):
-        """Program: (src [1, Lb] int32, src_len 0-d) → (mem_k, mem_v)
+        """Program: (params, src [1, Lb] int32, src_len 0-d) → (mem_k, mem_v)
         each [layers, 1, mem_width, H, dh].  The encoder self-attention
         masks keys past ``src_len``, so the first ``src_len`` memory rows
         are computed exactly as an unpadded encode would (pad rows emit
@@ -340,7 +352,7 @@ class _TransformerAdapter:
 
         def pure(state, inputs):
             src, src_len = inputs["src"], inputs["src_len"]
-            with traced_params(self.params, self.param_arrays):
+            with traced_params(self.params, inputs["params"]):
                 x = model.embed(NDArray(src))._data * math.sqrt(units)
                 x = x + pos[None].astype(x.dtype)
                 valid = jnp.arange(Lb) < src_len            # [Lb] keys
@@ -388,7 +400,7 @@ class _TransformerAdapter:
 
     def make_decode(self, slots, bucket, mem_width):
         """Program: state {self_k, self_v, mem_k, mem_v} + inputs
-        (tok [S], pos [S], mem_len [S]) → (logits [S, V], new state).
+        (params, tok [S], pos [S], mem_len [S]) → (logits [S, V], new state).
         Writes each slot's K/V at its own position, then attends ``<=
         pos`` — write-before-read is what lets ``free()`` skip clearing
         device rows."""
@@ -408,7 +420,7 @@ class _TransformerAdapter:
             rows = jnp.arange(S)
             valid_self = jnp.arange(T)[None, :] <= pos[:, None]     # [S,T]
             valid_mem = jnp.arange(Sm)[None, :] < mem_len[:, None]  # [S,Sm]
-            with traced_params(self.params, self.param_arrays):
+            with traced_params(self.params, inputs["params"]):
                 x = model.embed(NDArray(tok.reshape(S, 1)))._data \
                     * math.sqrt(units)
                 x = x + jnp.take(pos_table, pos, axis=0)[:, None, :] \
@@ -558,6 +570,8 @@ class GenerationServer:
         self._rr = list(self.tenants)      # round-robin admission order
 
         # -- executors (programs bound here, compiled in start()) --------
+        import jax
+
         self._prefill_exe = StatefulExecutor(
             {}, name="generation_prefill", compile_site="generation.prefill")
         mem_w = self._prompt_bucketer.buckets[-1]
@@ -566,8 +580,13 @@ class GenerationServer:
                 f"prefill_{lb}", self._adapter.make_prefill(lb, mem_w))
         self._exes = {}
         for b, pool in self._ladder.pools.items():
-            exe = StatefulExecutor(pool.state, name=f"generation_decode_{b}",
-                                   compile_site="generation.decode")
+            # committed like the weights: a cache that starts uncommitted
+            # and comes back committed from its first decode would change
+            # the insert program's signature after warmup
+            exe = StatefulExecutor(
+                jax.device_put(pool.state, self._adapter.device),
+                name=f"generation_decode_{b}",
+                compile_site="generation.decode")
             pool.state = None     # ownership transfers: the donated buffers
                                   # now live in (and only in) the executor
             exe.add_program("decode",
@@ -593,6 +612,12 @@ class GenerationServer:
         if autostart:
             self.start()
 
+    @property
+    def param_arrays(self):
+        """The frozen weights as placed on the serving device — what every
+        prefill/decode program receives as its ``params`` argument."""
+        return self._adapter.param_arrays
+
     # ------------------------------------------------------------------
     def start(self):
         """Compile every program (prefill per prompt bucket; decode +
@@ -612,12 +637,13 @@ class GenerationServer:
                 for lb in self._prompt_bucketer.buckets:
                     src = _np.zeros((1, lb), _np.int32)
                     warm_mem = self._prefill_exe.run(
-                        f"prefill_{lb}", src=src, src_len=_np.int32(1))
+                        f"prefill_{lb}", params=self.param_arrays,
+                        src=src, src_len=_np.int32(1))
                 mk, mv = warm_mem
                 for b, exe in self._exes.items():
                     pool = self._ladder.pools[b]
                     exe.run("insert", slot=_np.int32(0), mem_k=mk, mem_v=mv)
-                    exe.run("decode",
+                    exe.run("decode", params=self.param_arrays,
                             tok=_np.zeros(pool.slots, _np.int32),
                             pos=_np.zeros(pool.slots, _np.int32),
                             mem_len=_np.ones(pool.slots, _np.int32))
@@ -859,8 +885,8 @@ class GenerationServer:
             src = _np.zeros((1, req.prompt_bucket), _np.int32)
             src[0, :req.prompt.size] = req.prompt
             mem_k, mem_v = self._prefill_exe.run(
-                f"prefill_{req.prompt_bucket}", src=src,
-                src_len=_np.int32(req.prompt.size))
+                f"prefill_{req.prompt_bucket}", params=self.param_arrays,
+                src=src, src_len=_np.int32(req.prompt.size))
             self._exes[pool.bucket].run(
                 "insert", slot=_np.int32(slot), mem_k=mem_k, mem_v=mem_v)
             profiler.incr("generation_prefill")
@@ -925,7 +951,8 @@ class GenerationServer:
                 continue
             t0 = _perf()
             logits = self._exes[b].run(
-                "decode", tok=pool.last_token.copy(), pos=pool.pos.copy(),
+                "decode", params=self.param_arrays,
+                tok=pool.last_token.copy(), pos=pool.pos.copy(),
                 mem_len=pool.mem_len.copy())
             logits = _np.asarray(logits)
             now = _perf()

@@ -65,8 +65,7 @@ def resolve_platform(x=None):
     input's device wins (eager op on a CPU-placed array while the default
     backend is tpu, e.g. model init under ``jax.default_device(cpu)``);
     then an active ``jax_default_device`` override; then the default
-    backend.  Shared by ops/attention.py and rtc.py so the two dispatch
-    disciplines cannot drift."""
+    backend."""
     import jax
 
     platform = None

@@ -142,8 +142,9 @@ def test_astype_copy_context():
     assert_almost_equal(a, np.array([1.5, 2.5]))
     d = a.as_in_context(mx.cpu())
     assert d.context == mx.cpu()
-    e = mx.nd.zeros((2,), ctx=mx.tpu())
-    assert e.context.device_type == "tpu"
+    # a requested accelerator is never substituted by the host CPU
+    with pytest.raises(mx.MXNetError, match=r"tpu\(0\) requested"):
+        mx.nd.zeros((2,), ctx=mx.tpu())
     # copyto
     f = mx.nd.zeros((2,))
     a.copyto(f)

@@ -18,7 +18,7 @@ def saxpy_block(x_ref, y_ref, o_ref):
 
 
 def test_string_source_compile_and_launch():
-    mod = mx.rtc.PallasModule(SRC, exports=["scale_add", "saxpy_block"])
+    mod = mx.rtc.PallasModule(SRC, exports=["scale_add", "saxpy_block"], interpret=True)
     x = mx.nd.array(np.random.rand(16, 128).astype(np.float32))
     y = mx.nd.array(np.random.rand(16, 128).astype(np.float32))
     k = mod.get_kernel("scale_add", out_shapes=[(16, 128)])
@@ -30,7 +30,7 @@ def test_string_source_compile_and_launch():
 def test_grid_launch_with_block_specs():
     from jax.experimental import pallas as pl
 
-    mod = mx.rtc.PallasModule(SRC)
+    mod = mx.rtc.PallasModule(SRC, interpret=True)
     n_blocks = 4
     spec = pl.BlockSpec((8, 128), lambda i: (i, 0))
     k = mod.get_kernel("saxpy_block", out_shapes=[(8 * n_blocks, 128)],
@@ -48,7 +48,7 @@ def test_callable_source_and_multiple_outputs():
         lo_ref[...] = x_ref[...].min(keepdims=True)
         hi_ref[...] = x_ref[...].max(keepdims=True)
 
-    mod = mx.rtc.PallasModule(minmax)
+    mod = mx.rtc.PallasModule(minmax, interpret=True)
     k = mod.get_kernel("minmax", out_shapes=[(1, 1), (1, 1)])
     x = mx.nd.array(np.random.rand(32, 32).astype(np.float32))
     lo, hi = k.launch([x])
@@ -59,11 +59,11 @@ def test_callable_source_and_multiple_outputs():
 
 
 def test_unknown_kernel_and_missing_export():
-    mod = mx.rtc.PallasModule(SRC)
+    mod = mx.rtc.PallasModule(SRC, interpret=True)
     with pytest.raises(ValueError):
         mod.get_kernel("nope", out_shapes=[(2, 2)])
     with pytest.raises(ValueError):
-        mx.rtc.PallasModule(SRC, exports=["not_there"])
+        mx.rtc.PallasModule(SRC, exports=["not_there"], interpret=True)
 
 
 def test_indented_source_dedents():
@@ -71,7 +71,7 @@ def test_indented_source_dedents():
         def twice(x_ref, o_ref):
             o_ref[...] = 2.0 * x_ref[...]
     '''
-    mod = mx.rtc.PallasModule(src)
+    mod = mx.rtc.PallasModule(src, interpret=True)
     x = mx.nd.array(np.random.rand(4, 8).astype(np.float32))
     z = mod.get_kernel("twice", out_shapes=[(4, 8)]).launch([x])
     np.testing.assert_allclose(z.asnumpy(), 2 * x.asnumpy(), rtol=1e-6)
@@ -80,7 +80,7 @@ def test_indented_source_dedents():
 def test_bare_out_spec_and_dtype_validation():
     from jax.experimental import pallas as pl
 
-    mod = mx.rtc.PallasModule(SRC)
+    mod = mx.rtc.PallasModule(SRC, interpret=True)
     spec = pl.BlockSpec((8, 128), lambda i: (i, 0))
     k = mod.get_kernel("saxpy_block", out_shapes=[(16, 128)], grid=(2,),
                        in_specs=[spec, spec], out_specs=spec)  # bare spec
@@ -94,7 +94,7 @@ def test_bare_out_spec_and_dtype_validation():
 
 
 def test_launch_reuses_compiled_call():
-    mod = mx.rtc.PallasModule(SRC)
+    mod = mx.rtc.PallasModule(SRC, interpret=True)
     k = mod.get_kernel("scale_add", out_shapes=[(8, 8)])
     x = mx.nd.array(np.ones((8, 8), np.float32))
     k.launch([x, x])
@@ -105,7 +105,7 @@ def test_launch_reuses_compiled_call():
 def test_out_specs_count_validated_at_get_kernel():
     from jax.experimental import pallas as pl
 
-    mod = mx.rtc.PallasModule(SRC)
+    mod = mx.rtc.PallasModule(SRC, interpret=True)
     spec = pl.BlockSpec((8, 128), lambda i: (i, 0))
     with pytest.raises(ValueError):
         mod.get_kernel("scale_add", out_shapes=[(2, 2), (2, 2)],

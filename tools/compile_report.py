@@ -46,18 +46,11 @@ from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-PEAK_TFLOPS = float(os.environ.get("MXNET_TPU_PEAK_TFLOPS", "197"))
-
-# measured per-chip throughput the --analytic mode folds in (round-4
-# driver-era numbers; refresh from BENCH_EVIDENCE when a capture lands)
-MEASURED = {
-    "resnet50": ("img/s", 2455.0),
-    "ssd512-resnet18": ("img/s", 867.0),
-    "ssd512-vgg16": ("img/s", None),
-    "yolo3-darknet53": ("img/s", 566.0),
-    "bert-base-mlm": ("samples/s", 1474.0),
-    "transformer-big": ("samples/s", None),
-}
+# the bench configs the --analytic mode counts FLOPs for.  Counts only: a
+# utilisation needs a rate measured on a chip (the driver's ledger) and
+# that chip's peak (tools/device_peaks.py) — none is assumed here.
+ANALYTIC_CONFIGS = ("resnet50", "ssd512-resnet18", "ssd512-vgg16",
+                    "yolo3-darknet53", "bert-base-mlm", "transformer-big")
 
 
 def _open(path):
@@ -351,30 +344,24 @@ def _build(config):
 
 
 def analytic_report(configs=None, out=sys.stdout):
-    """The bench-config analytic FLOP/MFU table (3x-fwd training
-    convention; see PERF_NOTES) — exactly what tools/flops_report.py used
-    to print before it became a shim over this entry point."""
+    """The bench-config analytic FLOP table: XLA's forward FLOP count per
+    sample (training = 3x fwd by convention).  A count, valid from any
+    backend; it is the numerator of an MFU, not an MFU."""
     rows = []
-    for config in (configs or list(MEASURED)):
-        unit, rate = MEASURED.get(config, ("items/s", None))
+    for config in (configs or ANALYTIC_CONFIGS):
         net, xs = _build(config)
         gflops = _fwd_flops_per_sample(net, *xs) / 1e9
-        mfu = (rate * 3 * gflops / (PEAK_TFLOPS * 1e3)) if rate else None
-        rows.append((config, gflops, rate, mfu))
+        rows.append((config, gflops))
         out.write(json.dumps({
             "metric": f"{config}_fwd_gflops_per_sample",
             "value": round(gflops, 2),
-            "measured_per_sec": rate,
-            "train_mfu_at_measured": round(mfu, 4) if mfu else None,
         }) + "\n")
         out.flush()
 
-    out.write(f"\n| config | fwd GFLOP/sample | measured/s/chip | train MFU "
-              f"(3x fwd, {PEAK_TFLOPS:.0f} TF peak) |\n")
-    out.write("|---|---|---|---|\n")
-    for config, gflops, rate, mfu in rows:
-        out.write(f"| {config} | {gflops:.1f} | {rate if rate else '—'} | "
-                  f"{f'{100 * mfu:.1f}%' if mfu else '—'} |\n")
+    out.write("\n| config | fwd GFLOP/sample | train GFLOP/sample (3x) |\n")
+    out.write("|---|---|---|\n")
+    for config, gflops in rows:
+        out.write(f"| {config} | {gflops:.1f} | {3 * gflops:.1f} |\n")
     return 0
 
 

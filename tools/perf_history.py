@@ -1,161 +1,25 @@
 #!/usr/bin/env python
-"""Performance-trajectory regression gate (ISSUE 20).
+"""Structural gate over the scaling harness's evidence (ISSUE 20).
 
-Bench rounds 4–5 silently lost their headline numbers to infra — an
-outage round looks exactly like a catastrophic regression unless the
-harvester distinguishes them.  This tool reads every ``BENCH_r*.json``
-round (and optionally the scaling harness's ``--json`` evidence) into
-ONE classified trajectory:
-
-* ``good``                — rc 0 and a parsed numeric value,
-* ``backend_unavailable`` — the bench ran but the backend never came up
-  (``parsed.value`` null / an ``error`` field / nonzero rc with no
-  value): **reported, never gated** — an outage is not a regression,
-
-compares the newest good round of each metric against the committed
-rolling baseline (``docs/PERF_BASELINE.json``), and exits non-zero on a
-``>X%`` drop (``--threshold``, default 0.25 — generous: real-hardware
-rounds carry machine variance; the gate exists to catch the 2x cliff,
-not 3% noise).  Scaling evidence is gated structurally: the harness's
-own acceptance gates (efficiency floor, zero post-warmup recompiles,
-attribution match) must have passed.
+Reads ``benchmark/opperf/scaling.py --json`` evidence and checks what a
+CPU run can establish: the harness's own acceptance gates (efficiency
+floor, zero post-warmup recompiles, live-vs-merged-trace attribution
+match) must have passed, and no curve point may have recompiled after
+warmup.  Counts only — the curve's samples/sec come from CPU child
+processes and are not device metrics.  Device numbers are the driver's
+``PERF_LEDGER.jsonl``; nothing here reads or baselines them.
 
 Usage::
 
-    python tools/perf_history.py [--bench-glob 'BENCH_r*.json']
-        [--baseline docs/PERF_BASELINE.json] [--threshold 0.25]
-        [--scaling EVIDENCE.json] [--update-baseline] [--json]
+    python tools/perf_history.py --scaling EVIDENCE.json [--json]
 
-``--update-baseline`` rewrites the committed baseline from the rolling
-median of the newest good rounds (run it deliberately, commit the
-diff — the baseline is reviewed history, not a ratchet that silently
-follows every fast round).
-
-Exit codes: 0 ok, 1 regression / failed scaling gate, 2 unreadable input.
+Exit codes: 0 ok, 1 failed scaling gate, 2 unreadable input.
 """
 from __future__ import annotations
 
 import argparse
-import glob
 import json
-import os
 import sys
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# rolling-baseline window: median of the newest K good rounds
-_BASELINE_WINDOW = 5
-
-
-def _median(xs):
-    xs = sorted(xs)
-    n = len(xs)
-    return xs[n // 2] if n % 2 else 0.5 * (xs[n // 2 - 1] + xs[n // 2])
-
-
-def classify_round(doc):
-    """One bench round -> ``(status, metric, value)``.
-
-    ``backend_unavailable`` covers every infra shape the rounds have
-    actually produced: an explicit ``status``/``error`` field with a
-    null value (r05), and a nonzero rc with nothing parsed at all (r04,
-    the backend-init traceback)."""
-    parsed = doc.get("parsed")
-    if isinstance(parsed, dict):
-        metric = parsed.get("metric")
-        value = parsed.get("value")
-        if isinstance(value, (int, float)):
-            return "good", metric, float(value)
-        return "backend_unavailable", metric, None
-    if doc.get("rc", 0) != 0:
-        return "backend_unavailable", None, None
-    return "no_metric", None, None
-
-
-def load_trajectory(bench_glob):
-    """Every round, classified, ordered by round number:
-    ``{metric: [{"round", "status", "value"}]}`` plus the unattributed
-    infra rounds under the ``None`` key."""
-    rounds = []
-    for path in sorted(glob.glob(bench_glob)):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, json.JSONDecodeError) as e:
-            raise ValueError(f"{path}: unreadable bench round: {e}")
-        status, metric, value = classify_round(doc)
-        rounds.append({"round": doc.get("n"), "path": path,
-                       "status": status, "metric": metric, "value": value})
-    traj = {}
-    for r in rounds:
-        traj.setdefault(r["metric"], []).append(r)
-    return traj
-
-
-def load_baseline(path):
-    if not os.path.exists(path):
-        return {"schema": 1, "metrics": {}}
-    with open(path) as f:
-        doc = json.load(f)
-    if not isinstance(doc.get("metrics"), dict):
-        raise ValueError(f"{path}: not a perf baseline (no 'metrics')")
-    return doc
-
-
-def rebuild_baseline(traj, window=_BASELINE_WINDOW):
-    metrics = {}
-    for metric, rounds in traj.items():
-        if metric is None:
-            continue
-        good = [r for r in rounds if r["status"] == "good"]
-        if not good:
-            continue
-        tail = good[-window:]
-        metrics[metric] = {
-            "baseline": round(_median([r["value"] for r in tail]), 3),
-            "window_rounds": [r["round"] for r in tail],
-        }
-    return {"schema": 1, "metrics": metrics}
-
-
-def check_metrics(traj, baseline, threshold):
-    """Newest good round of each metric vs its committed baseline.
-    Returns (failures, report_rows)."""
-    failures, rows = [], []
-    for metric, rounds in sorted(traj.items(), key=lambda kv: str(kv[0])):
-        if metric is None:
-            for r in rounds:
-                rows.append({"metric": None, "round": r["round"],
-                             "status": r["status"], "value": None,
-                             "verdict": "ignored (infra)"})
-            continue
-        infra = sum(1 for r in rounds if r["status"] != "good")
-        good = [r for r in rounds if r["status"] == "good"]
-        base = (baseline.get("metrics") or {}).get(metric, {}).get("baseline")
-        if not good:
-            rows.append({"metric": metric, "round": None,
-                         "status": "backend_unavailable", "value": None,
-                         "verdict": f"no good round ({infra} infra) — "
-                                    "not a regression"})
-            continue
-        latest = good[-1]
-        row = {"metric": metric, "round": latest["round"],
-               "status": "good", "value": latest["value"],
-               "baseline": base, "infra_rounds": infra}
-        if base is None:
-            row["verdict"] = "no baseline (run --update-baseline)"
-        else:
-            floor = base * (1.0 - threshold)
-            if latest["value"] < floor:
-                row["verdict"] = (f"REGRESSION: {latest['value']} < "
-                                  f"{floor:.3f} ({threshold:.0%} below "
-                                  f"baseline {base})")
-                failures.append(row)
-            else:
-                row["verdict"] = (f"ok ({latest['value'] / base - 1:+.1%} "
-                                  "vs baseline)")
-        rows.append(row)
-    return failures, rows
 
 
 def check_scaling(path):
@@ -182,76 +46,29 @@ def check_scaling(path):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--bench-glob",
-                    default=os.path.join(_REPO, "BENCH_r*.json"))
-    ap.add_argument("--baseline",
-                    default=os.path.join(_REPO, "docs",
-                                         "PERF_BASELINE.json"))
-    ap.add_argument("--threshold", type=float,
-                    default=float(os.environ.get(
-                        "MXNET_PERF_REGRESSION_PCT", "0.25")))
-    ap.add_argument("--scaling", default=None,
+    ap.add_argument("--scaling", required=True,
                     help="scaling.py --json evidence to gate structurally")
-    ap.add_argument("--update-baseline", action="store_true",
-                    help="rewrite --baseline from the good-round rolling "
-                         "median and exit")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable report on stdout")
     args = ap.parse_args(argv)
 
     try:
-        traj = load_trajectory(args.bench_glob)
-    except ValueError as e:
-        print(f"perf_history: {e}", file=sys.stderr)
+        problems, report = check_scaling(args.scaling)
+    except (OSError, json.JSONDecodeError) as e:
+        print(f"perf_history: scaling evidence unreadable: {e}",
+              file=sys.stderr)
         return 2
-
-    if args.update_baseline:
-        doc = rebuild_baseline(traj)
-        os.makedirs(os.path.dirname(args.baseline) or ".", exist_ok=True)
-        with open(args.baseline, "w") as f:
-            json.dump(doc, f, indent=1)
-            f.write("\n")
-        print(f"perf_history: baseline -> {args.baseline} "
-              f"({len(doc['metrics'])} metric(s))")
-        return 0
-
-    try:
-        baseline = load_baseline(args.baseline)
-    except (OSError, json.JSONDecodeError, ValueError) as e:
-        print(f"perf_history: {e}", file=sys.stderr)
-        return 2
-
-    failures, rows = check_metrics(traj, baseline, args.threshold)
-    scaling_report = None
-    if args.scaling:
-        try:
-            problems, scaling_report = check_scaling(args.scaling)
-        except (OSError, json.JSONDecodeError) as e:
-            print(f"perf_history: scaling evidence unreadable: {e}",
-                  file=sys.stderr)
-            return 2
-        for p in problems:
-            failures.append({"metric": "scaling", "verdict": p})
 
     if args.json:
-        print(json.dumps({"schema": 1, "rows": rows,
-                          "scaling": scaling_report,
-                          "failures": failures,
-                          "pass": not failures}, indent=1))
+        print(json.dumps({"schema": 1, "scaling": report,
+                          "failures": problems, "pass": not problems},
+                         indent=1))
     else:
-        for row in rows:
-            val = (f"{row['value']}" if row.get("value") is not None
-                   else "-")
-            print(f"perf_history: {row.get('metric') or '<infra>'} "
-                  f"round {row.get('round')}: {val} — {row['verdict']}")
-        if scaling_report is not None:
-            print(f"perf_history: scaling curve "
-                  f"{scaling_report['curve']} — "
-                  f"{'ok' if scaling_report['pass'] else 'FAILED'}")
-        for f_ in failures:
-            print(f"perf_history: FAIL {f_.get('metric')}: "
-                  f"{f_['verdict']}", file=sys.stderr)
-    return 1 if failures else 0
+        print(f"perf_history: scaling curve {report['curve']} — "
+              f"{'ok' if report['pass'] else 'FAILED'}")
+        for p in problems:
+            print(f"perf_history: FAIL scaling: {p}", file=sys.stderr)
+    return 1 if problems else 0
 
 
 if __name__ == "__main__":

@@ -20,8 +20,18 @@ feature-map traffic (weights are ~25M params ≈ 50 MB bf16, noise at B=256):
   and the one-pass stats trick already removed one stats pass).
 
 Maxpool/residual-add/loss-head traffic is counted separately below.
+
+Pure arithmetic (touches no device):
+    python tools/resnet_roofline.py [BATCH] [DEVICE_KIND]
+with the peaks looked up in ``tools/device_peaks.py`` by ``DEVICE_KIND``
+(default "TPU v5 lite"); a device not in that table is an error.
 """
+import os
 import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from device_peaks import peaks_for  # noqa: E402
 
 
 def feature_maps(B):
@@ -40,7 +50,9 @@ def feature_maps(B):
 
 def main():
     B = int(sys.argv[1]) if len(sys.argv) > 1 else 256
-    HBM = float(sys.argv[2]) if len(sys.argv) > 2 else 819e9  # v5e GB/s
+    device_kind = sys.argv[2] if len(sys.argv) > 2 else "TPU v5 lite"
+    peaks = peaks_for(device_kind)  # unknown device = error
+    HBM = peaks["hbm_bytes_per_s"]
     bf16 = 2
 
     maps = feature_maps(B)
@@ -69,9 +81,9 @@ def main():
               f"+ pool {pool_bytes / 1e9:.1f} + opt {opt_bytes / 1e9:.1f} "
               f"= {total / 1e9:.1f} GB/step  -> floor "
               f"{t_bw * 1e3:.1f} ms ({B / t_bw:.0f} img/s)")
-    # MXU floor: 12.3 GFLOP/img fwd+bwd (3x fwd 4.1), bf16 peak 197 TFLOP/s
-    t_mxu = B * 12.3e9 / 197e12
-    print(f"MXU-bound floor: {t_mxu * 1e3:.1f} ms ({B / t_mxu:.0f} img/s) "
+    # MXU floor: 12.3 GFLOP/img fwd+bwd (3x fwd 4.1) at the bf16 peak
+    t_mxu = B * 12.3e9 / peaks["bf16_flops_per_s"]
+    print(f"[{device_kind}] MXU-bound floor: {t_mxu * 1e3:.1f} ms ({B / t_mxu:.0f} img/s) "
           f"-> bandwidth-bound by ~5x at this batch")
 
 
