@@ -317,6 +317,15 @@ class Block:
 
     # -- execution -------------------------------------------------------
     def __call__(self, *args, **kwargs):
+        if _is_tracing():
+            # inside a whole-graph capture every op carries its block's
+            # name in the HLO name stack (``spmd.forward/<model>/<layer>/…``
+            # in xprof); metadata only, and eager calls enter nothing
+            with jax.named_scope(self.name):
+                return self._call(*args, **kwargs)
+        return self._call(*args, **kwargs)
+
+    def _call(self, *args, **kwargs):
         for hook in self._forward_pre_hooks:
             hook(self, args)
         out = self.forward(*args, **kwargs)

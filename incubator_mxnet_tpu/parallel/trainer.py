@@ -18,6 +18,7 @@ use — same Block, same loss, same Optimizer subclass.
 """
 from __future__ import annotations
 
+import contextlib
 from time import perf_counter as _perf
 
 import jax
@@ -37,6 +38,34 @@ from .sharding import ShardingRules, default_rules, batch_pspec, param_sharding
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["SPMDTrainer"]
+
+
+def _phase(name):
+    """``jax.named_scope("spmd.<name>")``: the phase names every compiled
+    step build carries (forward, loss, grad_sync, optimizer; the backward
+    pass names itself ``transpose(jvp(spmd.forward))``).  Metadata only:
+    the optimized program and its compile-cache key do not change."""
+    return jax.named_scope("spmd." + name)
+
+
+def _hlo_text_thunk(fn, call_args):
+    """``() -> optimized HLO text`` of jitted ``fn`` as called with
+    ``call_args``, for ``profiler.compiled_text``: holds a weak reference
+    to ``fn`` and the ABSTRACT signature (shape, dtype, and the sharding of
+    committed arrays), so neither buffers nor the trainer are kept alive."""
+    import weakref
+
+    abstract = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None),
+        call_args)
+    ref = weakref.ref(fn)
+
+    def text():
+        f = ref()
+        return None if f is None else f.lower(*abstract).compile().as_text()
+
+    return text
 
 
 class _EveryKey(dict):
@@ -582,29 +611,28 @@ class SPMDTrainer:
         """Place host batch arrays on the mesh with (dp, fsdp)[, sp]
         sharding.  Accepts numpy or NDArray; returns jax.Arrays.  In
         multi-process runs each host passes its local shard."""
-        out = []
-        for a in arrays:
-            if isinstance(a, NDArray):
-                a = a._data
-            a = _np.asarray(a) if not isinstance(a, jax.Array) else a
-            spec = batch_pspec(a.ndim, self._sp_axis)
-            sharding = NamedSharding(self._mesh, spec)
-            if isinstance(a, jax.Array) and a.sharding == sharding:
-                out.append(a)  # idempotent: already staged on the mesh
-                continue       # (the io.DataPipeline fast path: batches
-                               # arrive device-resident, zero host work)
-            t0 = _perf() if _profiler._active else None
+        return tuple(self._place(a, lambda nd: batch_pspec(nd, self._sp_axis))
+                     for a in arrays)
+
+    def _place(self, a, spec_of):
+        """One array onto the mesh under ``spec_of(ndim)``.  An array already
+        staged there passes through with zero host work (the
+        io.DataPipeline fast path: batches arrive device-resident); a
+        transfer is a ``spmd.shard_batch`` span, which bills the step's
+        host bucket — a per-step transfer on the consumer thread is exactly
+        the host-input wall the async infeed removes, and its absence is
+        asserted in tests."""
+        if isinstance(a, NDArray):
+            a = a._data
+        a = _np.asarray(a) if not isinstance(a, jax.Array) else a
+        sharding = NamedSharding(self._mesh, spec_of(a.ndim))
+        if isinstance(a, jax.Array) and a.sharding == sharding:
+            return a
+        with _profiler.span("spmd.shard_batch", "trainer",
+                            {"bytes": int(a.nbytes)}):
             if jax.process_count() > 1:
-                out.append(jax.make_array_from_process_local_data(sharding, a))
-            else:
-                out.append(jax.device_put(a, sharding))
-            if t0 is not None:
-                # bills the step's host bucket: a per-step transfer on the
-                # consumer thread is exactly the host-input wall the async
-                # infeed removes — its absence is asserted in tests
-                _profiler.record_span("spmd.shard_batch", "trainer", t0,
-                                      args={"bytes": int(a.nbytes)})
-        return tuple(out)
+                return jax.make_array_from_process_local_data(sharding, a)
+            return jax.device_put(a, sharding)
 
     def _compile_sig(self, arrays, program):
         """Compile-registry signature for a step build: named batch inputs
@@ -742,66 +770,113 @@ class SPMDTrainer:
         are rescaled by 1/batch_size like ``Trainer.step``.
         """
         inputs = data if isinstance(data, (list, tuple)) else (data,)
-        arrays = self.shard_batch(*inputs, label)
-        if batch_size is None:
-            batch_size = arrays[0].shape[0]
-        sig = tuple((a.shape, str(a.dtype)) for a in arrays)
-        fn = self._step_cache.get(sig)
-        fresh = fn is None
-        if fresh:
-            fn = self._build_step(arrays)
-            self._step_cache[sig] = fn
-        self._t += 1
-        self._optimizer.num_update = self._t
-        lr = self.learning_rate()
-        rescale = self._optimizer.rescale_grad / batch_size
-        key = get_key()
+        with self._step_span("spmd.step"):
+            arrays = self.shard_batch(*inputs, label)
+            if batch_size is None:
+                batch_size = arrays[0].shape[0]
+            sig = tuple((a.shape, str(a.dtype)) for a in arrays)
+            fn = self._step_cache.get(sig)
+            fresh = fn is None
+            if fresh:
+                fn = self._build_step(arrays)
+                self._step_cache[sig] = fn
+            with _profiler.span("spmd.step.args", "trainer"):
+                self._t += 1
+                self._optimizer.num_update = self._t
+                lr = self.learning_rate()
+                rescale = self._optimizer.rescale_grad / batch_size
+                scalars = (get_key(), jnp.float32(self._t), jnp.float32(lr),
+                           jnp.float32(rescale))
+            loss = self._dispatch("spmd.step", "step", fn, scalars, arrays,
+                                  fresh)
+        return NDArray(loss)
+
+    def _step_span(self, name, k=None):
+        """The root span of one ``step*`` call: ``step=`` is the first
+        optimizer step it runs (the identifier shared with the device
+        trace), ``k=`` the steps in the dispatch, and under gradient
+        compression the payload args scaled by ``k`` so the trace sums to
+        the bytes the counters account (trace_report's comms table)."""
+        args = {"step": self._t + 1}
+        if k is not None:
+            args["k"] = k
+        if self._comm_span_args:
+            args.update(self._comm_span_args)
+            for key in ("bytes_raw", "bytes_wire"):
+                args[key] *= k or 1
+        return _profiler.span(name, "trainer", args)
+
+    def _scan_args(self, k, batch_size):
+        """The stacked per-step scalars of a ``k``-step scan: the same
+        num_update / lr / PRNG-key schedule as ``k`` calls of ``step``."""
+        with _profiler.span("spmd.step.args", "trainer"):
+            ts, lrs, keys = [], [], []
+            for _ in range(k):
+                self._t += 1
+                self._optimizer.num_update = self._t
+                ts.append(float(self._t))
+                lrs.append(self.learning_rate())
+                keys.append(get_key())
+            rescale = self._optimizer.rescale_grad / batch_size
+            return (jnp.stack(keys), jnp.asarray(ts, jnp.float32),
+                    jnp.asarray(lrs, jnp.float32), jnp.float32(rescale))
+
+    def _dispatch(self, site, program, fn, scalars, arrays, fresh, k=1,
+                  declared_warmup=False):
+        """Run one compiled step program and close its telemetry step: the
+        host side shared by ``step`` / ``step_bulk`` / ``step_window``.
+        ``spmd.step.enqueue`` is the jitted call (enqueue plus the wait for
+        the donated buffers), ``spmd.step.obs`` the counters, gauges and
+        ``step_boundary`` after it."""
         comm = self._comm_state is not None
-        call_args = (key, jnp.float32(self._t), jnp.float32(lr),
-                     jnp.float32(rescale), self._param_arrays,
-                     self._opt_states,
+        call_args = (*scalars, self._param_arrays, self._opt_states,
                      *((self._comm_state,) if comm else ()), *arrays)
-        lowered = None
-        if fresh and _profiler.compile_cost_enabled():
-            try:  # AOT lowering for XLA cost accounting (opt-in: the
-                lowered = fn.lower(*call_args)  # real call compiles again)
-            except Exception:
-                lowered = None
-        tc = _perf() if fresh else None
+        lowered = text = None
+        if fresh:
+            text = _hlo_text_thunk(fn, call_args)
+            if _profiler.compile_cost_enabled():
+                try:  # AOT lowering for XLA cost accounting (opt-in: the
+                    lowered = fn.lower(*call_args)  # real call compiles again)
+                except Exception:
+                    lowered = None
         tw = _perf()
-        t0 = tw if _profiler._active else None
         # the fused step is one XLA program whose collectives block on
         # every peer — the watchdog turns a dead peer into a clean exit
-        _elastic.watchdog_arm("spmd.step")
+        _elastic.watchdog_arm(site)
+        extras, done = None, False
         try:
             try:
-                if comm:
-                    (new_params, new_states, new_comm,
-                     loss, extras) = fn(*call_args)
-                    self._comm_state = new_comm
-                else:
-                    new_params, new_states, loss, extras = fn(*call_args)
+                with _profiler.span("spmd.step.enqueue", "trainer"):
+                    out = fn(*call_args)
             except Exception as e:
                 # the fused step is THE training-tier OOM choke point:
                 # a RESOURCE_EXHAUSTED here gets one postmortem naming
                 # the top ledger owners before it surfaces
-                _profiler.maybe_oom_postmortem(e, "spmd.step")
+                _profiler.maybe_oom_postmortem(e, site)
                 raise
-            self._param_arrays = new_params
-            self._opt_states = new_states
-            if tc is not None:
-                _profiler.record_compile(
-                    "spmd.step", self._compile_sig(arrays, "step"),
-                    (_perf() - tc) * 1e3, lowered=lowered)
-            if t0 is not None:
-                _profiler.record_span("spmd.step", "trainer", t0,
-                                      args=self._comm_span_args)
-            self._record_step_obs(extras, tw)
+            if comm:
+                self._comm_state = out[2]
+            self._param_arrays, self._opt_states = out[0], out[1]
+            loss, extras = out[-2:]
+            if fresh:
+                # a tail width is its own program, built once — a declared
+                # warmup, never a steady-state violation
+                with (_profiler.compile_guard_paused() if declared_warmup
+                      else contextlib.nullcontext()):
+                    _profiler.record_compile(
+                        "spmd.step", self._compile_sig(arrays, program),
+                        (_perf() - tw) * 1e3, lowered=lowered, text=text)
+            done = True
         finally:
-            _elastic.watchdog_disarm()
-            _profiler.step_boundary()
+            with _profiler.span("spmd.step.obs", "trainer"):
+                try:
+                    if done:
+                        self._record_step_obs(extras, tw, k=k)
+                finally:
+                    _elastic.watchdog_disarm()
+                    _profiler.step_boundary()
         self._post_step()
-        return NDArray(loss)
+        return loss
 
     # ------------------------------------------------------------------
     def step_bulk(self, data, label, k, batch_size=None):
@@ -820,74 +895,21 @@ class SPMDTrainer:
         """
         if k < 1:
             raise ValueError(f"step_bulk needs k >= 1, got {k}")
+        k = int(k)
         inputs = data if isinstance(data, (list, tuple)) else (data,)
-        arrays = self.shard_batch(*inputs, label)
-        if batch_size is None:
-            batch_size = arrays[0].shape[0]
-        sig = (tuple((a.shape, str(a.dtype)) for a in arrays), int(k))
-        fn = self._step_cache.get(sig)
-        fresh = fn is None
-        if fresh:
-            fn = self._build_bulk(arrays, int(k))
-            self._step_cache[sig] = fn
-        ts, lrs, keys = [], [], []
-        for _ in range(k):
-            self._t += 1
-            self._optimizer.num_update = self._t
-            ts.append(float(self._t))
-            lrs.append(self.learning_rate())
-            keys.append(get_key())
-        rescale = self._optimizer.rescale_grad / batch_size
-        comm = self._comm_state is not None
-        call_args = (jnp.stack(keys), jnp.asarray(ts, jnp.float32),
-                     jnp.asarray(lrs, jnp.float32), jnp.float32(rescale),
-                     self._param_arrays, self._opt_states,
-                     *((self._comm_state,) if comm else ()), *arrays)
-        lowered = None
-        if fresh and _profiler.compile_cost_enabled():
-            try:
-                lowered = fn.lower(*call_args)
-            except Exception:
-                lowered = None
-        tc = _perf() if fresh else None
-        tw = _perf()
-        t0 = tw if _profiler._active else None
-        _elastic.watchdog_arm("spmd.step_bulk")
-        try:
-            try:
-                if comm:
-                    (new_params, new_states, new_comm,
-                     loss, extras) = fn(*call_args)
-                    self._comm_state = new_comm
-                else:
-                    new_params, new_states, loss, extras = fn(*call_args)
-            except Exception as e:
-                _profiler.maybe_oom_postmortem(e, "spmd.step_bulk")
-                raise
-            self._param_arrays = new_params
-            self._opt_states = new_states
-            if tc is not None:
-                _profiler.record_compile(
-                    "spmd.step", self._compile_sig(arrays, f"step_bulk[{k}]"),
-                    (_perf() - tc) * 1e3, lowered=lowered)
-            if t0 is not None:
-                args = {"k": int(k)}
-                if self._comm_span_args:
-                    # one span covers k scanned steps: scale the payload
-                    # args so the trace sums to the same bytes the
-                    # counters account (trace_report's comms table)
-                    args.update(self._comm_span_args,
-                                bytes_raw=(self._comm_span_args["bytes_raw"]
-                                           * int(k)),
-                                bytes_wire=(self._comm_span_args["bytes_wire"]
-                                            * int(k)))
-                _profiler.record_span("spmd.step_bulk", "trainer", t0,
-                                      args=args)
-            self._record_step_obs(extras, tw, k=int(k))
-        finally:
-            _elastic.watchdog_disarm()
-            _profiler.step_boundary()  # one boundary per dispatch, not per k
-        self._post_step()
+        with self._step_span("spmd.step_bulk", k):
+            arrays = self.shard_batch(*inputs, label)
+            if batch_size is None:
+                batch_size = arrays[0].shape[0]
+            sig = (tuple((a.shape, str(a.dtype)) for a in arrays), k)
+            fn = self._step_cache.get(sig)
+            fresh = fn is None
+            if fresh:
+                fn = self._build_bulk(arrays, k)
+                self._step_cache[sig] = fn
+            loss = self._dispatch(
+                "spmd.step_bulk", f"step_bulk[{k}]", fn,
+                self._scan_args(k, batch_size), arrays, fresh, k=k)
         return NDArray(loss)
 
     def _build_bulk(self, example_arrays, k):
@@ -932,26 +954,10 @@ class SPMDTrainer:
         (dp, fsdp) — byte-identical to what ``io.DataPipeline``'s
         ``stage_window`` builds, so windows arriving device-resident pass
         through with zero host work."""
-        out = []
-        for a in arrays:
-            if isinstance(a, NDArray):
-                a = a._data
-            a = _np.asarray(a) if not isinstance(a, jax.Array) else a
-            inner = batch_pspec(max(0, a.ndim - 1), self._sp_axis)
-            spec = P(*((None,) + tuple(inner)))
-            sharding = NamedSharding(self._mesh, spec)
-            if isinstance(a, jax.Array) and a.sharding == sharding:
-                out.append(a)
-                continue
-            t0 = _perf() if _profiler._active else None
-            if jax.process_count() > 1:
-                out.append(jax.make_array_from_process_local_data(sharding, a))
-            else:
-                out.append(jax.device_put(a, sharding))
-            if t0 is not None:
-                _profiler.record_span("spmd.shard_batch", "trainer", t0,
-                                      args={"bytes": int(a.nbytes)})
-        return tuple(out)
+        def spec_of(ndim):
+            return P(None, *batch_pspec(max(0, ndim - 1), self._sp_axis))
+
+        return tuple(self._place(a, spec_of) for a in arrays)
 
     def step_window(self, data, label, batch_size=None):
         """Run K fused optimizer steps over K DIFFERENT pre-staged batches
@@ -966,87 +972,28 @@ class SPMDTrainer:
         dispatches a shorter program (registered as a declared warmup,
         not a steady-state recompile)."""
         inputs = data if isinstance(data, (list, tuple)) else (data,)
-        arrays = self.shard_window(*inputs, label)
-        if arrays[0].ndim < 2:
+        shape = _np.shape(inputs[0])
+        if len(shape) < 2:
             raise ValueError(
                 "step_window expects stacked [k, batch, ...] windows "
-                f"(pipeline.stage_window(k)); got {tuple(arrays[0].shape)}")
-        k = int(arrays[0].shape[0])
-        if batch_size is None:
-            batch_size = arrays[0].shape[1]
-        if self._window_k is None:
-            self._window_k = k     # first width seen = the steady width
-        sig = (tuple((a.shape, str(a.dtype)) for a in arrays), "window")
-        fn = self._step_cache.get(sig)
-        fresh = fn is None
-        if fresh:
-            fn = self._build_window(arrays)
-            self._step_cache[sig] = fn
-        ts, lrs, keys = [], [], []
-        for _ in range(k):
-            self._t += 1
-            self._optimizer.num_update = self._t
-            ts.append(float(self._t))
-            lrs.append(self.learning_rate())
-            keys.append(get_key())
-        rescale = self._optimizer.rescale_grad / batch_size
-        comm = self._comm_state is not None
-        call_args = (jnp.stack(keys), jnp.asarray(ts, jnp.float32),
-                     jnp.asarray(lrs, jnp.float32), jnp.float32(rescale),
-                     self._param_arrays, self._opt_states,
-                     *((self._comm_state,) if comm else ()), *arrays)
-        lowered = None
-        if fresh and _profiler.compile_cost_enabled():
-            try:
-                lowered = fn.lower(*call_args)
-            except Exception:
-                lowered = None
-        tc = _perf() if fresh else None
-        tw = _perf()
-        t0 = tw if _profiler._active else None
-        _elastic.watchdog_arm("spmd.step_window")
-        try:
-            try:
-                if comm:
-                    (new_params, new_states, new_comm,
-                     loss, extras) = fn(*call_args)
-                    self._comm_state = new_comm
-                else:
-                    new_params, new_states, loss, extras = fn(*call_args)
-            except Exception as e:
-                _profiler.maybe_oom_postmortem(e, "spmd.step_window")
-                raise
-            self._param_arrays = new_params
-            self._opt_states = new_states
-            if tc is not None:
-                if k != self._window_k:
-                    # a tail width is its own program, built once — a
-                    # declared warmup, never a steady-state violation
-                    with _profiler.compile_guard_paused():
-                        _profiler.record_compile(
-                            "spmd.step",
-                            self._compile_sig(arrays, f"step_window[{k}]"),
-                            (_perf() - tc) * 1e3, lowered=lowered)
-                else:
-                    _profiler.record_compile(
-                        "spmd.step",
-                        self._compile_sig(arrays, f"step_window[{k}]"),
-                        (_perf() - tc) * 1e3, lowered=lowered)
-            if t0 is not None:
-                args = {"k": int(k)}
-                if self._comm_span_args:
-                    args.update(self._comm_span_args,
-                                bytes_raw=(self._comm_span_args["bytes_raw"]
-                                           * int(k)),
-                                bytes_wire=(self._comm_span_args["bytes_wire"]
-                                            * int(k)))
-                _profiler.record_span("spmd.step_window", "trainer", t0,
-                                      args=args)
-            self._record_step_obs(extras, tw, k=int(k))
-        finally:
-            _elastic.watchdog_disarm()
-            _profiler.step_boundary()  # one boundary per dispatch
-        self._post_step()
+                f"(pipeline.stage_window(k)); got {tuple(shape)}")
+        k = int(shape[0])
+        with self._step_span("spmd.step_window", k):
+            arrays = self.shard_window(*inputs, label)
+            if batch_size is None:
+                batch_size = arrays[0].shape[1]
+            if self._window_k is None:
+                self._window_k = k     # first width seen = the steady width
+            sig = (tuple((a.shape, str(a.dtype)) for a in arrays), "window")
+            fn = self._step_cache.get(sig)
+            fresh = fn is None
+            if fresh:
+                fn = self._build_window(arrays)
+                self._step_cache[sig] = fn
+            loss = self._dispatch(
+                "spmd.step_window", f"step_window[{k}]", fn,
+                self._scan_args(k, batch_size), arrays, fresh, k=k,
+                declared_warmup=k != self._window_k)
         return NDArray(loss)
 
     def _build_window(self, example_arrays):
@@ -1164,9 +1111,11 @@ class SPMDTrainer:
             with trace_scope(params, full, key, True) as collector:
                 with moe_mod.moe_loss_frame() as moe_fr:
                     ins = [NDArray(b) for b in batch[:n_inputs]]
-                    out = block(*ins)
+                    with _phase("forward"):
+                        out = block(*ins)
                     label = NDArray(batch[n_inputs])
-                    loss = loss_fn(out, label)
+                    with _phase("loss"):
+                        loss = loss_fn(out, label)
                 # Differentiate the SUM (matching ``loss.backward()`` on a
                 # vector loss: implicit ones head-grads); Trainer-parity
                 # mean-reduction comes from rescale_grad = 1/batch_size.
@@ -1233,13 +1182,15 @@ class SPMDTrainer:
                 forward_loss, has_aux=True
             )(train_arrs, full_arrs, key, batch)
             new_grads = [None] * n_slots
-            for s in exact_slots:
-                new_grads[s] = jax.lax.psum(grads[s], AX)
-            flat = jnp.concatenate([grads[s].reshape(-1) for s in comp_slots])
-            reduced, resid_out = comp_mod.traced_allreduce(
-                codec, flat, residual[0] if ef else None, AX, algo=algo)
-            for (off, n, shape), s in zip(spans, comp_slots):
-                new_grads[s] = reduced[off:off + n].reshape(shape)
+            with _phase("grad_sync"):
+                for s in exact_slots:
+                    new_grads[s] = jax.lax.psum(grads[s], AX)
+                flat = jnp.concatenate(
+                    [grads[s].reshape(-1) for s in comp_slots])
+                reduced, resid_out = comp_mod.traced_allreduce(
+                    codec, flat, residual[0] if ef else None, AX, algo=algo)
+                for (off, n, shape), s in zip(spans, comp_slots):
+                    new_grads[s] = reduced[off:off + n].reshape(shape)
             # host-facing scalars reduce across shards, so every export
             # surface matches the global-batch build
             loss_mean = jax.lax.pmean(loss_mean, AX)
@@ -1367,31 +1318,33 @@ class SPMDTrainer:
                 forward_loss, has_aux=True
             )(gathered, full, key, batch)
             new_grads = [None] * n_slots
-            for s in exact_slots:
-                if is_sharded(train_specs[s]):
-                    g = grads[s]
-                    if dp_axes:
-                        g = jax.lax.psum(g, dp_axes)
-                    new_grads[s] = jax.lax.psum_scatter(
-                        g, shard_ax, scatter_dimension=0, tiled=True)
+            with _phase("grad_sync"):
+                for s in exact_slots:
+                    if is_sharded(train_specs[s]):
+                        g = grads[s]
+                        if dp_axes:
+                            g = jax.lax.psum(g, dp_axes)
+                        new_grads[s] = jax.lax.psum_scatter(
+                            g, shard_ax, scatter_dimension=0, tiled=True)
+                    else:
+                        new_grads[s] = jax.lax.psum(grads[s], AX)
+                # gradient bucket in the same ring-chunk order: row i of each
+                # slot's (F, shard) view lands in segment i
+                flat = jnp.concatenate(
+                    [grads[s].reshape(F, -1) for s in comp_slots],
+                    axis=1).reshape(-1)
+                comp = flat + residual[0] if ef else flat
+                if dp_size > 1:
+                    x, r_dp = ring_mod.ring_allreduce(
+                        codec, comp, None, dp_axes)
                 else:
-                    new_grads[s] = jax.lax.psum(grads[s], AX)
-            # gradient bucket in the same ring-chunk order: row i of each
-            # slot's (F, shard) view lands in segment i
-            flat = jnp.concatenate(
-                [grads[s].reshape(F, -1) for s in comp_slots],
-                axis=1).reshape(-1)
-            comp = flat + residual[0] if ef else flat
-            if dp_size > 1:
-                x, r_dp = ring_mod.ring_allreduce(codec, comp, None, dp_axes)
-            else:
-                x, r_dp = comp, None
-            shard_red, r_rs = ring_mod.ring_reduce_scatter(
-                codec, x, None, shard_ax)
-            resid = r_rs if r_dp is None else r_dp + r_rs / dp_size
-            for (off, ssz, shape), s in zip(spans, comp_slots):
-                new_grads[s] = shard_red[off:off + ssz].reshape(
-                    (shape[0] // F,) + tuple(shape[1:]))
+                    x, r_dp = comp, None
+                shard_red, r_rs = ring_mod.ring_reduce_scatter(
+                    codec, x, None, shard_ax)
+                resid = r_rs if r_dp is None else r_dp + r_rs / dp_size
+                for (off, ssz, shape), s in zip(spans, comp_slots):
+                    new_grads[s] = shard_red[off:off + ssz].reshape(
+                        (shape[0] // F,) + tuple(shape[1:]))
             loss_mean = jax.lax.pmean(loss_mean, AX)
             aux_vals = tuple(jax.lax.pmean(a, AX) for a in aux_vals)
             if extras:
@@ -1472,13 +1425,14 @@ class SPMDTrainer:
         try:
             new_full = list(param_arrs)
             new_states = []
-            for slot, j in enumerate(self._trainable_idx):
-                w = NDArray(param_arrs[j])
-                g = NDArray(grads[slot])
-                st = _state_to_ndarrays(opt_states[slot])
-                opt.update_multi_precision(j, w, g, st)
-                new_full[j] = w._data
-                new_states.append(_state_to_arrays(st))
+            with _phase("optimizer"):
+                for slot, j in enumerate(self._trainable_idx):
+                    w = NDArray(param_arrs[j])
+                    g = NDArray(grads[slot])
+                    st = _state_to_ndarrays(opt_states[slot])
+                    opt.update_multi_precision(j, w, g, st)
+                    new_full[j] = w._data
+                    new_states.append(_state_to_arrays(st))
         finally:
             (
                 opt._index_update_count,
@@ -1538,7 +1492,8 @@ class SPMDTrainer:
                             as collector:
                         with moe_mod.moe_loss_frame() as fr:
                             ins = h if isinstance(h, tuple) else (h,)
-                            out = block(*[NDArray(b) for b in ins])
+                            with _phase("forward"):
+                                out = block(*[NDArray(b) for b in ins])
                     side = moe_mod.frame_loss(fr)
                     if side is None:
                         side = jnp.zeros(())
@@ -1579,7 +1534,7 @@ class SPMDTrainer:
                 prev = getattr(_block_tls, "tracing", 0)
                 _block_tls.tracing = prev + 1
                 try:
-                    with autograd._scope(False, True):
+                    with autograd._scope(False, True), _phase("loss"):
                         if isinstance(h, tuple):
                             out = [NDArray(o) for o in h]
                         else:

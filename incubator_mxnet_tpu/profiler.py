@@ -11,9 +11,11 @@ of the jax_graft stack with three cooperating layers:
    fused optimizer step, kvstore pushpull, io prefetch, trainer step
    boundaries) records spans into it; ``dump()`` serializes the rings to a
    chrome://tracing JSON at ``_config['filename']`` (paired B/E events,
-   viewable in Perfetto / ``chrome://tracing`` alongside the xprof
-   capture).  When the recorder is off the instrumentation sites pay one
-   module-attribute read (``_active``) and a branch — nothing else.
+   for runs without xprof).  A measured interval is a ``span``, which
+   ALSO enters a ``jax.profiler.TraceAnnotation``: whoever started the
+   JAX trace session, the program's spans sit in its ``.xplane.pb`` on
+   the device's clock.  With both sinks off a span costs under a
+   microsecond; post-hoc ``record_span`` sites are ring-only.
 
 2. **xprof bridge** — ``start()``/``stop()`` still drive
    ``jax.profiler`` (XLA/xprof device traces, incl. per-HLO timing); a
@@ -101,6 +103,7 @@ __all__ = ["set_config", "start", "stop", "dump", "dumps", "pause", "resume",
            "reset_goodput",
            # -- compilation observability (ISSUE 10) --
            "record_compile", "compile_site", "compile_registry",
+           "compiled_text",
            "compile_stats", "reset_compiles", "sig_array", "sig_static",
            "diff_signatures", "compile_cost_enabled", "jit_cache_size",
            "arm_compile_guard", "disarm_compile_guard", "compile_guard_state",
@@ -145,6 +148,7 @@ _wt0 = time.time()
 _EPOCH_UNIX = (_wt0 + time.time()) / 2.0 - (time.perf_counter() - _EPOCH)
 del _wt0
 _perf = time.perf_counter
+_TraceAnnotation = jax.profiler.TraceAnnotation
 
 
 def _env_float(name, default):
@@ -596,9 +600,13 @@ def recorder_stats():
 def record_span(name, category, t0, t1=None, args=None, step=None):
     """Record one completed span.  ``t0``/``t1`` are ``time.perf_counter()``
     readings (``t1`` defaults to now); ``step`` defaults to the current
-    step id.  Cheap no-op when neither the recorder nor telemetry is armed
-    — but hot paths should pre-check ``profiler._active`` themselves so
-    the disabled path never pays the call."""
+    step id.  Cheap no-op when neither the recorder nor telemetry is armed.
+
+    RING ONLY, and post-hoc: for what is not a measured interval on the
+    calling thread — ``compile.jit``, the ``step`` telemetry row, instant
+    markers, and MODELED windows such as ``pipeline.stage``, which must
+    never be written beside real device events.  A measured interval is a
+    :class:`span`, which also reaches the device trace."""
     if not _active:
         return
     if t1 is None:
@@ -635,24 +643,41 @@ def record_span(name, category, t0, t1=None, args=None, step=None):
 
 
 class span:
-    """``with profiler.span('fwd', 'user'):`` — a recorded trace span.
-    Unlike :class:`scope` it does not touch ``jax.profiler`` (pure python,
-    hot-path safe) and appears in the chrome trace with its category."""
+    """``with profiler.span('fwd', 'user', {'step': 3}):`` — THE way a hot
+    path times an interval on its own thread.  One span, two sinks:
 
-    __slots__ = ("_name", "_cat", "_args", "_t0")
+    * always a ``jax.profiler.TraceAnnotation(name, **args)``: whenever any
+      JAX trace session is on (``mx.profiler.start()``, a bare
+      ``jax.profiler.start_trace``, a profiler server) the span lands on the
+      ``/host:CPU`` plane of the same ``.xplane.pb`` as the device
+      operations, on their clock, nested under the thread's open spans.
+      With no session on it costs about a microsecond, which no switch
+      could save;
+    * when the recorder or telemetry is armed, the ring (chrome trace,
+      step / goodput buckets) exactly as :func:`record_span`, stamped with
+      the step id current at ENTRY (a span may contain its step boundary).
+    """
+
+    __slots__ = ("_name", "_cat", "_args", "_t0", "_step", "_ann")
 
     def __init__(self, name, category="user", args=None):
         self._name = name
         self._cat = category
         self._args = args
+        self._ann = (_TraceAnnotation(name, **args) if args
+                     else _TraceAnnotation(name))
 
     def __enter__(self):
-        self._t0 = _perf() if _active else None
+        self._ann.__enter__()
+        self._step = _step_id
+        self._t0 = _perf()
         return self
 
     def __exit__(self, *a):
-        if self._t0 is not None and _active:
-            record_span(self._name, self._cat, self._t0, args=self._args)
+        if _active:
+            record_span(self._name, self._cat, self._t0, args=self._args,
+                        step=self._step)
+        self._ann.__exit__(*a)
         return False
 
 
@@ -2343,7 +2368,7 @@ def _extract_cost(lowered):
 
 
 def record_compile(site, signature, wall_ms, fn=None, args=None, kwargs=None,
-                   lowered=None):
+                   lowered=None, text=None):
     """Report one jit compilation into the process-wide compile registry.
 
     Parameters
@@ -2360,6 +2385,9 @@ def record_compile(site, signature, wall_ms, fn=None, args=None, kwargs=None,
         :func:`compile_cost_enabled`, the helper lowers once more to
         extract XLA cost/memory analysis.  ``lowered`` short-circuits that
         with a site-provided ``Lowered``/``Compiled`` stage.
+    text : optional thunk ``() -> str`` giving the program's optimized HLO
+        text on demand (:func:`compiled_text`); it may hold the jitted
+        function and its ABSTRACT signature, never device buffers.
 
     Returns the record dict appended to the registry.  In guard raise
     mode this RAISES CompileGuardError after the bookkeeping — call it
@@ -2369,6 +2397,8 @@ def record_compile(site, signature, wall_ms, fn=None, args=None, kwargs=None,
     signature = dict(signature or {})
     program = signature.get("__program__")
     wall_ms = float(wall_ms)
+    if text is not None:
+        _compile_text_of[site] = text
     if lowered is None and fn is not None and compile_cost_enabled():
         try:
             lowered = fn.lower(*(args or ()), **(kwargs or {}))
@@ -2480,6 +2510,19 @@ def compile_registry():
     return {"sites": sites, "records": records}
 
 
+_compile_text_of = {}   # site -> thunk of its newest program's HLO text
+
+
+def compiled_text(site):
+    """Optimized HLO text of the newest program ``site`` reported with a
+    ``text=`` thunk (``spmd.step`` does), else None.  Computed when asked
+    for: an ahead-of-time compile of the same function over the same
+    abstract signature — a load where the persistent compile cache is on.
+    What joins a device trace's ``%fusion.7`` to its ``op_name`` scope."""
+    thunk = _compile_text_of.get(site)
+    return None if thunk is None else thunk()
+
+
 def compile_stats():
     """Per-site compile summary only (no per-record detail)."""
     return compile_registry()["sites"]
@@ -2492,6 +2535,7 @@ def reset_compiles():
     with _compile_lock:
         _compile_records.clear()
         _compile_sites.clear()
+        _compile_text_of.clear()
 
 
 def _compile_provider():
@@ -2925,33 +2969,19 @@ def dumps(reset=False):
     return "\n".join(lines)
 
 
-class scope:
-    """``with profiler.scope('fwd'):`` — named region, visible in xprof as
-    a TraceAnnotation, tallied in ``dumps()``, and (when the recorder is
-    armed) present in the chrome trace under the ``user`` category."""
+class scope(span):
+    """``with profiler.scope('fwd'):`` — a :class:`span` of the ``user``
+    category (so: in the xprof trace, and in the chrome trace when the
+    recorder is armed) that is also tallied in ``dumps()``."""
+
+    __slots__ = ()
 
     def __init__(self, name="<unk>"):
-        self._name = name
-        self._ctx = None
-        self._t0 = None
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        try:
-            self._ctx = jax.profiler.TraceAnnotation(self._name)
-            self._ctx.__enter__()
-        except Exception:
-            self._ctx = None
-        return self
+        span.__init__(self, name, "user")
 
     def __exit__(self, *a):
-        if self._ctx is not None:
-            self._ctx.__exit__(*a)
-        t1 = time.perf_counter()
-        _tally(self._name, t1 - self._t0)
-        if _active:
-            record_span(self._name, "user", self._t0, t1)
-        return False
+        _tally(self._name, _perf() - self._t0)
+        return span.__exit__(self, *a)
 
 
 class Marker:

@@ -49,6 +49,7 @@ into the PR 9 registry with full signature attribution.
 """
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 import time
@@ -597,7 +598,7 @@ class GenerationServer:
         # -- scheduler state --------------------------------------------
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
-        self._rid = 0
+        self._rid = itertools.count(1)   # next() is atomic: no lock
         self._started = False
         self._closing = False
         self._closed = False
@@ -608,6 +609,7 @@ class GenerationServer:
         self._n_completed = 0
         self._ttfts = deque(maxlen=2048)
         self._tpots = deque(maxlen=2048)
+        self._queue_waits = deque(maxlen=2048)   # ms, admission - submit
         self._tok_window = deque(maxlen=4096)    # (t_emit,) for tokens/sec
         if autostart:
             self.start()
@@ -630,8 +632,11 @@ class GenerationServer:
                 raise RuntimeError("server is closed")
             self._started = True
         if self._do_warmup:
-            t0 = _perf()
-            with profiler.compile_site("generation.warmup"), \
+            with profiler.span(
+                    "generation.warmup", "serving",
+                    {"prompt_buckets": list(self._prompt_bucketer.buckets),
+                     "pools": list(self._exes)}), \
+                    profiler.compile_site("generation.warmup"), \
                     profiler.compile_guard_paused():
                 warm_mem = None
                 for lb in self._prompt_bucketer.buckets:
@@ -647,11 +652,6 @@ class GenerationServer:
                             tok=_np.zeros(pool.slots, _np.int32),
                             pos=_np.zeros(pool.slots, _np.int32),
                             mem_len=_np.ones(pool.slots, _np.int32))
-            if profiler._active:
-                profiler.record_span(
-                    "generation.warmup", "serving", t0,
-                    args={"prompt_buckets": list(self._prompt_bucketer.buckets),
-                          "pools": list(self._exes)})
             # the program set is closed and compiled: any further compile
             # is a steady-state violation (MXNET_COMPILE_GUARD escalates)
             profiler.arm_compile_guard("generation")
@@ -700,7 +700,13 @@ class GenerationServer:
             raise ValueError(f"unknown tenant {tenant!r}; tenants are "
                              f"{sorted(self.tenants)}")
         t0 = _perf()
-        with self._cond:
+        # the id is drawn before the span opens so that both sinks carry it
+        # (a shed request leaves a gap in the sequence)
+        rid = request_id if request_id is not None else next(self._rid)
+        with profiler.span("generation.enqueue", "serving",
+                           {"request": rid, "tenant": ten.name,
+                            "prompt_bucket": pb, "max_new": max_new}), \
+                self._cond:
             if self._closing or self._closed:
                 raise ServerDrainingError(
                     "server is draining/closed — retry against another "
@@ -714,18 +720,11 @@ class GenerationServer:
                 raise AdmissionError(
                     f"tenant {ten.name!r} queue at max_queue="
                     f"{ten.max_queue} — request shed (back off)")
-            self._rid += 1
-            rid = request_id if request_id is not None else self._rid
             req = _GenRequest(rid, ten, prompt, pb, max_new, on_token, t0)
             q.append(req)
             ten.submitted += 1
             self._cond.notify_all()
         profiler.incr("generation_request")
-        if profiler._active:
-            profiler.record_span(
-                "generation.enqueue", "serving", t0,
-                args={"request": rid, "tenant": ten.name,
-                      "prompt_bucket": pb, "max_new": max_new})
         return req.result
 
     def generate(self, prompt, timeout=120.0, **kw):
@@ -828,6 +827,10 @@ class GenerationServer:
         the benchmark compares against."""
         if self.batching == "static" and self._ladder.n_active > 0:
             return
+        with profiler.span("generation.admit", "serving"):
+            self._admit_queued()
+
+    def _admit_queued(self):
         joined = 0
         while joined < self.max_prefills_per_iter:
             with self._cond:
@@ -879,24 +882,24 @@ class GenerationServer:
             # the fallible prefill/insert dispatches — if one raises,
             # _fail_inflight frees the slot and decrements, so the
             # max_slots cap never goes negative
+            wait_ms = (_perf() - req.t_submit) * 1e3   # admission - submit
             with self._lock:
                 req.tenant.active_slots += 1
-            t0 = _perf()
-            src = _np.zeros((1, req.prompt_bucket), _np.int32)
-            src[0, :req.prompt.size] = req.prompt
-            mem_k, mem_v = self._prefill_exe.run(
-                f"prefill_{req.prompt_bucket}", params=self.param_arrays,
-                src=src, src_len=_np.int32(req.prompt.size))
-            self._exes[pool.bucket].run(
-                "insert", slot=_np.int32(slot), mem_k=mem_k, mem_v=mem_v)
+                self._queue_waits.append(wait_ms)
+            with profiler.span(
+                    "generation.prefill", "serving",
+                    {"request": req.rid, "tenant": req.tenant.name,
+                     "prompt_bucket": req.prompt_bucket, "pool": pool.bucket,
+                     "slot": int(slot), "queue_wait_ms": round(wait_ms, 3)}):
+                src = _np.zeros((1, req.prompt_bucket), _np.int32)
+                src[0, :req.prompt.size] = req.prompt
+                mem_k, mem_v = self._prefill_exe.run(
+                    f"prefill_{req.prompt_bucket}", params=self.param_arrays,
+                    src=src, src_len=_np.int32(req.prompt.size))
+                self._exes[pool.bucket].run(
+                    "insert", slot=_np.int32(slot), mem_k=mem_k, mem_v=mem_v)
             profiler.incr("generation_prefill")
             profiler.incr("generation_slot_join")
-            if profiler._active:
-                profiler.record_span(
-                    "generation.prefill", "serving", t0,
-                    args={"request": req.rid, "tenant": req.tenant.name,
-                          "prompt_bucket": req.prompt_bucket,
-                          "pool": pool.bucket, "slot": int(slot)})
             joined += 1
 
     def _harvest_cancelled(self):
@@ -908,6 +911,16 @@ class GenerationServer:
 
     def _leave(self, pool, slot, reason, exc=None):
         req = pool.owners[slot]
+        times = req.result._token_times
+        with profiler.span(
+                "generation.complete", "serving",
+                {"request": req.rid, "tenant": req.tenant.name,
+                 "reason": reason, "tokens": len(req.result._tokens),
+                 "ttft_ms": round((times[0] - req.t_submit) * 1e3, 3)
+                 if times else 0.0}):
+            self._leave_slot(pool, slot, req, reason, exc)
+
+    def _leave_slot(self, pool, slot, req, reason, exc):
         pool.free(slot)
         profiler.incr("generation_slot_leave")
         with self._lock:
@@ -924,13 +937,6 @@ class GenerationServer:
         if reason in ("eos", "length"):
             self._note_latency(req.result)
             self._judge_slo(req)
-        if profiler._active:
-            profiler.record_span(
-                "generation.complete", "serving", _perf(),
-                args={"request": req.rid, "tenant": req.tenant.name,
-                      "reason": reason,
-                      "tokens": len(req.result._tokens),
-                      "ttft_ms": round(req.result.ttft_ms or 0.0, 3)})
 
     def _judge_slo(self, req):
         res, ten = req.result, req.tenant
@@ -949,46 +955,51 @@ class GenerationServer:
             act = pool.active_slots()
             if len(act) == 0:
                 continue
-            t0 = _perf()
-            logits = self._exes[b].run(
-                "decode", params=self.param_arrays,
-                tok=pool.last_token.copy(), pos=pool.pos.copy(),
-                mem_len=pool.mem_len.copy())
-            logits = _np.asarray(logits)
+            with profiler.span("generation.step", "serving",
+                               {"pool": b, "active": int(len(act))}):
+                logits = self._exes[b].run(
+                    "decode", params=self.param_arrays,
+                    tok=pool.last_token.copy(), pos=pool.pos.copy(),
+                    mem_len=pool.mem_len.copy())
+                with profiler.span("generation.decode.d2h", "serving",
+                                   {"pool": b}):
+                    logits = _np.asarray(logits)
             now = _perf()
             profiler.incr("generation_decode_iter")
             profiler.incr("generation_token", int(len(act)))
-            if profiler._active:
-                profiler.record_span(
-                    "generation.step", "serving", t0, now,
-                    args={"pool": b, "active": int(len(act))})
-            emitted = []
-            with self._lock:      # ONE acquisition per pool, not per slot
-                for s in act:
-                    req = pool.owners[s]
-                    nxt = int(logits[s].argmax())
-                    pool.last_token[s] = nxt
-                    pool.pos[s] += 1
-                    req.tenant.tokens += 1
-                    # under the lock: stats() iterates this window from
-                    # the metrics-scrape thread
-                    self._tok_window.append(now)
-                    emitted.append((s, req, nxt))
-            # stream/callback/leave OUTSIDE the lock: on_token is user
-            # code and may well call stats() (non-reentrant lock)
-            for s, req, nxt in emitted:
-                req.result._push(nxt, now)
-                if req.on_token is not None:
-                    try:
-                        req.on_token(req.result, nxt)
-                    except Exception:  # noqa: BLE001 — a bad callback must
-                        pass           # not take the decode loop down
-                if nxt == self.eos:
-                    self._leave(pool, s, "eos")
-                elif len(req.result._tokens) >= req.max_new:
-                    self._leave(pool, s, "length")
+            with profiler.span("generation.decode.emit", "serving",
+                               {"pool": b, "active": int(len(act))}):
+                self._emit(pool, act, logits, now)
         with self._lock:
             self._iterations += 1
+
+    def _emit(self, pool, act, logits, now):
+        """Per-slot argmax, stream push, user callback and leave."""
+        emitted = []
+        with self._lock:      # ONE acquisition per pool, not per slot
+            for s in act:
+                req = pool.owners[s]
+                nxt = int(logits[s].argmax())
+                pool.last_token[s] = nxt
+                pool.pos[s] += 1
+                req.tenant.tokens += 1
+                # under the lock: stats() iterates this window from
+                # the metrics-scrape thread
+                self._tok_window.append(now)
+                emitted.append((s, req, nxt))
+        # stream/callback/leave OUTSIDE the lock: on_token is user
+        # code and may well call stats() (non-reentrant lock)
+        for s, req, nxt in emitted:
+            req.result._push(nxt, now)
+            if req.on_token is not None:
+                try:
+                    req.on_token(req.result, nxt)
+                except Exception:  # noqa: BLE001 — a bad callback must
+                    pass           # not take the decode loop down
+            if nxt == self.eos:
+                self._leave(pool, s, "eos")
+            elif len(req.result._tokens) >= req.max_new:
+                self._leave(pool, s, "length")
 
     def _iterate(self):
         self._harvest_cancelled()
@@ -1003,6 +1014,7 @@ class GenerationServer:
         pct = profiler.percentile
         with self._lock:
             ttfts, tpots = list(self._ttfts), list(self._tpots)
+            waits = list(self._queue_waits)
             queue_depth = sum(len(q) for q in self._queues.values())
             now = _perf()
             recent = [t for t in self._tok_window if now - t <= 10.0]
@@ -1017,6 +1029,8 @@ class GenerationServer:
                 "ttft_ms_p99": pct(ttfts, 0.99),
                 "tpot_ms_p50": pct(tpots, 0.50),
                 "tpot_ms_p99": pct(tpots, 0.99),
+                "queue_wait_ms_p50": pct(waits, 0.50),
+                "queue_wait_ms_p95": pct(waits, 0.95),
                 "tenants": {t: ten.stats()
                             for t, ten in self.tenants.items()},
             }

@@ -34,3 +34,33 @@ def with_seed(seed=None):
         return wrapper
 
     return deco
+
+
+def host_spans(trace_dir, prefix):
+    """``(line, start, end, name, stats)`` of the ``/host:CPU`` events whose
+    name starts with ``prefix`` in the newest ``.xplane.pb`` under
+    ``trace_dir``."""
+    import glob
+
+    import jax
+
+    files = sorted(glob.glob(os.path.join(
+        str(trace_dir), "**", "*.xplane.pb"), recursive=True))
+    assert files, f"no xplane under {trace_dir}"
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    out = []
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):   # thread names repeat
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.append((f"{i}:{line.name}", ev.start_ns,
+                                ev.start_ns + ev.duration_ns, ev.name,
+                                {k: str(v) for k, v in ev.stats}))
+    return out
+
+
+def span_inside(child, parent):
+    return (child[0] == parent[0] and parent[1] <= child[1]
+            and child[2] <= parent[2])

@@ -490,6 +490,62 @@ class TestObservability:
             assert want in names, names
 
 
+    def test_spans_nest_and_share_the_request_id(self, tiny_net, tmp_path):
+        """ISSUE 25: the scheduler's spans are in the xprof trace of whoever
+        traces, nested as the code nests, and ``request=`` joins a request's
+        enqueue, prefill and completion."""
+        from common import host_spans, span_inside
+
+        srv = _server(tiny_net, name="gen_nest")
+        try:
+            profiler.set_config(filename=str(tmp_path / "gen_nest.json"))
+            profiler.start()
+            res = srv.submit(_prompt(5, 0), max_new_tokens=3, request_id=77)
+            res.result(60.0)
+            trace_dir = profiler._state["dir"]
+            profiler.stop()
+        finally:
+            srv.close()
+        spans = host_spans(trace_dir, "generation.")
+        by = {}
+        for s in spans:
+            by.setdefault(s[3], []).append(s)
+        (prefill,), (done,) = by["generation.prefill"], by["generation.complete"]
+        (enq,) = by["generation.enqueue"]
+        assert {enq[4]["request"], prefill[4]["request"],
+                done[4]["request"]} == {"77"}
+        assert float(prefill[4]["queue_wait_ms"]) >= 0.0
+        assert enq[0] != prefill[0]        # submit thread, scheduler thread
+        assert sum(span_inside(prefill, a) for a in by["generation.admit"]) == 1
+        assert len(by["generation.step"]) == 3 == len(by["generation.decode.emit"])
+        for d2h in by["generation.decode.d2h"]:
+            assert sum(span_inside(d2h, st) for st in by["generation.step"]) == 1
+        for emit in by["generation.decode.emit"]:
+            assert not any(span_inside(emit, st) for st in by["generation.step"])
+        assert sum(span_inside(done, e) for e in by["generation.decode.emit"]) == 1
+        assert done[4]["reason"] == "length" and done[4]["tokens"] == "3"
+
+    @pytest.mark.parametrize("slots,waits", [(4, False), (1, True)])
+    def test_queue_wait_is_admission_minus_submit(self, tiny_net, slots, waits):
+        """``queue_wait_ms`` p50/p95 in ``stats()``: near zero while slots
+        are free; with ONE slot the later requests wait out the earlier
+        ones' decodes."""
+        srv = _server(tiny_net, name=f"gen_wait{slots}", slots_per_bucket=slots)
+        try:
+            assert srv.stats()["queue_wait_ms_p50"] is None
+            rs = [srv.submit(_prompt(4, i), max_new_tokens=6) for i in range(3)]
+            for r in rs:
+                r.result(60.0)
+            st = srv.stats()
+        finally:
+            srv.close()
+        assert 0.0 <= st["queue_wait_ms_p50"] <= st["queue_wait_ms_p95"]
+        ttft = max(r.ttft_ms for r in rs)
+        assert st["queue_wait_ms_p95"] <= ttft
+        if waits:   # the third request sat through two whole decodes
+            assert st["queue_wait_ms_p95"] > 0.5 * ttft
+
+
 class TestLifecycle:
     def test_close_drains(self, tiny_net):
         srv = _server(tiny_net, slots_per_bucket=1)

@@ -21,6 +21,8 @@ from incubator_mxnet_tpu.gluon import Trainer, nn
 
 import jax
 
+from common import host_spans, span_inside
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -919,3 +921,214 @@ class TestStragglerRegistryHygiene:
             ps.stop()
             with profiler._counter_lock:
                 profiler._peer_metrics.clear()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 25: one span, two sinks — the device trace and the ring
+# ---------------------------------------------------------------------------
+
+
+def _ring_names(prefix):
+    with profiler._counter_lock:
+        rings = list(profiler._rings)
+    return [ev[0] for r in rings for ev in r.snapshot()
+            if ev[0].startswith(prefix)]
+
+
+@pytest.mark.parametrize("session", ["jax_start_trace", "mx_profiler_start"])
+def test_span_reaches_the_device_trace_whoever_started_it(clean_profiler,
+                                                          session):
+    """A span is found on the /host:CPU plane of the session's xplane with
+    its args, nested inside its parent; the ring holds the same names only
+    when ``mx.profiler.start()`` armed it."""
+    tmp = clean_profiler
+    before = profiler.recorder_stats()["spans"]
+    if session == "jax_start_trace":
+        jax.profiler.start_trace(str(tmp / "xp"))
+    else:
+        profiler.start()
+    try:
+        with profiler.span("t25.parent", "user", {"step": 7}):
+            with profiler.span("t25.child", "user",
+                               {"request": 3, "pool": 128}):
+                time.sleep(0.002)
+        with profiler.scope("t25.scope"):
+            pass
+    finally:
+        if session == "jax_start_trace":
+            jax.profiler.stop_trace()
+            trace_dir = tmp / "xp"
+            in_ring = _ring_names("t25.")
+        else:
+            in_ring = _ring_names("t25.")
+            trace_dir = profiler._state["dir"]
+            profiler.stop()
+    spans = {s[3]: s for s in host_spans(trace_dir, "t25.")}
+    assert set(spans) == {"t25.parent", "t25.child", "t25.scope"}
+    assert spans["t25.parent"][4]["step"] == "7"
+    assert spans["t25.child"][4] == {"request": "3", "pool": "128"}
+    assert span_inside(spans["t25.child"], spans["t25.parent"])
+    assert not span_inside(spans["t25.scope"], spans["t25.parent"])
+    if session == "jax_start_trace":
+        assert in_ring == []
+        assert profiler.recorder_stats()["spans"] == before
+    else:
+        assert sorted(in_ring) == ["t25.child", "t25.parent", "t25.scope"]
+
+
+def test_span_with_both_sinks_off_changes_nothing(clean_profiler):
+    stats = profiler.recorder_stats()
+    agg = dict(profiler._agg)
+    for i in range(1000):
+        with profiler.span("t25off.x", "trainer", {"step": i}):
+            pass
+    assert profiler.recorder_stats() == stats   # an older session's rings stay
+    assert _ring_names("t25off.") == [] and dict(profiler._agg) == agg
+
+
+def test_span_keeps_the_step_id_of_its_entry(clean_profiler):
+    """A span that contains its step boundary stays in the step it opened
+    in (``spmd.step`` closes after ``step_boundary``)."""
+    profiler.start()
+    profiler.step_boundary()
+    sid = profiler.current_step()
+    with profiler.span("t25.straddle", "trainer"):
+        profiler.step_boundary()
+    assert profiler.current_step() == sid + 1
+    with profiler._counter_lock:
+        rings = list(profiler._rings)
+    got = [ev for r in rings for ev in r.snapshot()
+           if ev[0] == "t25.straddle"]
+    profiler.stop()
+    assert len(got) == 1 and got[0][4] == sid
+
+
+def _tiny_spmd(builder):
+    """A two-layer SPMDTrainer through each of the four ``pure_step``
+    builders, with a batch it accepts."""
+    from incubator_mxnet_tpu import gluon
+    from incubator_mxnet_tpu.parallel import (SPMDTrainer, fsdp_rules,
+                                              make_mesh)
+
+    mx.random.seed(11)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(32, activation="relu"), nn.Dense(32, activation="relu"),
+            nn.Dense(8))
+    net.initialize()
+    net(mx.nd.zeros((2, 16)))
+    kw = {"plain": dict(mesh=make_mesh()),
+          "compressed": dict(mesh=make_mesh(), compression="int8"),
+          "compressed_sharded": dict(mesh=make_mesh(fsdp=2),
+                                     rules=fsdp_rules(), compression="int8"),
+          "pipeline": dict(mesh=make_mesh(),
+                           stages=net.split_stages([2, 1]),
+                           pipeline={"schedule": "1f1b",
+                                     "n_microbatches": 2})}[builder]
+    tr = SPMDTrainer(net, gluon.loss.SoftmaxCrossEntropyLoss(), "adam",
+                     {"learning_rate": 0.01}, **kw)
+    rng = np.random.RandomState(0)
+    x = rng.randn(16, 16).astype(np.float32)
+    y = rng.randint(0, 8, (16,)).astype(np.float32)
+    return net, tr, x, y
+
+
+@pytest.mark.parametrize("builder", ["plain", "compressed",
+                                     "compressed_sharded", "pipeline"])
+def test_compiled_step_carries_phase_and_block_names(builder):
+    import jax.numpy as jnp
+
+    net, tr, x, y = _tiny_spmd(builder)
+    assert (tr._stages is not None) == (builder == "pipeline")
+    assert (tr._comm_cfg is not None) == builder.startswith("compressed")
+    if tr._comm_cfg is not None:
+        assert bool(tr._comm_cfg["sharded"]) == (builder
+                                                 == "compressed_sharded")
+    arrays = tr.shard_batch(x, y)
+    fn = tr._build_step(arrays)
+    assert fn.__name__ == "pure_step"   # the trace reads jit_pure_step(
+    comm = () if tr._comm_state is None else (tr._comm_state,)
+    text = fn.lower(jax.random.PRNGKey(0), jnp.float32(1), jnp.float32(0.01),
+                    jnp.float32(1.0), tr._param_arrays, tr._opt_states,
+                    *comm, *arrays).as_text(debug_info=True)
+    dense = net[0].name
+    for needle in ("spmd.forward", "spmd.loss", "spmd.optimizer",
+                   "transpose(jvp(spmd.forward", f"/{dense}/"):
+        assert needle in text, needle
+    assert ("spmd.grad_sync" in text) == builder.startswith("compressed")
+    assert "jit(pure_step)" in text
+
+
+def test_block_names_are_entered_only_while_tracing():
+    from incubator_mxnet_tpu.gluon.block import trace_scope
+
+    net = nn.Dense(4)
+    net.initialize()
+    net(mx.nd.zeros((2, 3)))
+    params = list(net.collect_params().values())
+
+    def traced(w, b, x):
+        with trace_scope(params, [w, b], jax.random.PRNGKey(0), False):
+            return net(mx.nd.NDArray(x))._data
+
+    def eager(x):
+        return net(mx.nd.NDArray(x))._data
+
+    arrs = [p.data()._data for p in params]
+    x = np.zeros((2, 3), np.float32)
+    assert f"[{net.name}]" in str(jax.make_jaxpr(traced)(*arrs, x).pretty_print(
+        name_stack=True))
+    assert net.name not in str(jax.make_jaxpr(eager)(x).pretty_print(
+        name_stack=True))
+
+
+@pytest.mark.parametrize("entry", ["step", "step_bulk", "step_window"])
+def test_step_spans_nest_once_per_call_in_both_sinks(clean_profiler, entry):
+    """Per ``trainer.step*`` call: one root span with ``step=``, and
+    ``spmd.step.args`` / ``.enqueue`` / ``.obs`` once each inside it, on the
+    xplane's host plane AND in the ring; ``spmd.shard_batch`` only where a
+    host batch is transferred."""
+    _, tr, x, y = _tiny_spmd("plain")
+    root = {"step": "spmd.step", "step_bulk": "spmd.step_bulk",
+            "step_window": "spmd.step_window"}[entry]
+    k = 1 if entry == "step" else 2
+
+    if entry == "step_window":
+        host = (np.stack([x, x]), np.stack([y, y]))
+        staged = tr.shard_window(*host)
+    else:
+        host, staged = (x, y), tr.shard_batch(x, y)
+
+    def call(host_batch):
+        batch = host if host_batch else staged
+        if entry == "step_window":
+            return tr.step_window(*batch)
+        return tr.step(*batch) if entry == "step" else tr.step_bulk(*batch, 2)
+
+    call(True)                       # compile outside the session
+    profiler.start()
+    try:
+        first = tr._t + 1
+        call(False)
+        call(True)
+        float(call(False).asnumpy())
+        ring = _ring_names("spmd.")
+        trace_dir = profiler._state["dir"]
+    finally:
+        profiler.stop()
+    spans = host_spans(trace_dir, "spmd.")
+    roots = sorted((s for s in spans if s[3] == root), key=lambda s: s[1])
+    assert len(roots) == 3
+    assert [int(r[4]["step"]) for r in roots] == [first, first + k,
+                                                  first + 2 * k]
+    if k > 1:
+        assert all(r[4]["k"] == str(k) for r in roots)
+    for child in ("spmd.step.args", "spmd.step.enqueue", "spmd.step.obs"):
+        inner = [s for s in spans if s[3] == child]
+        assert len(inner) == 3, child
+        for r in roots:
+            assert sum(span_inside(s, r) for s in inner) == 1, child
+    transfers = [s for s in spans if s[3] == "spmd.shard_batch"]
+    assert len(transfers) == 2 and all(span_inside(t, roots[1])
+                                       for t in transfers)
+    assert all(int(t[4]["bytes"]) > 0 for t in transfers)
+    assert sorted(ring) == sorted(s[3] for s in spans)
