@@ -30,7 +30,7 @@ from . import elastic as _elastic
 from .. import autograd
 from .. import optimizer as opt_mod
 from ..ndarray.ndarray import NDArray
-from ..random import get_key, push_traced_key, pop_traced_key
+from ..random import get_key, seed_epoch, push_traced_key, pop_traced_key
 from ..gluon.block import _tls as _block_tls
 from ..gluon.parameter import ParameterDict
 from .mesh import current_mesh, local_mesh
@@ -57,7 +57,8 @@ def _hlo_text_thunk(fn, call_args):
 
     abstract = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=a.sharding if a.committed else None),
+            a.shape, a.dtype,
+            sharding=a.sharding if getattr(a, "committed", False) else None),
         call_args)
     ref = weakref.ref(fn)
 
@@ -256,6 +257,11 @@ class SPMDTrainer:
             self._state_shardings.append(shard)
 
         self._t = self._optimizer.begin_num_update
+        # step t's PRNG key is fold_in(base key, t), derived INSIDE the
+        # compiled step (_jit_wrapped); the base key is drawn once from
+        # get_key() and again after mx.random.seed (_step_key)
+        self._base_key = None
+        self._key_epoch = None
         self._step_cache = {}
         self._window_k = None       # step_window's steady width (first
                                     # width seen; shorter tails are
@@ -780,15 +786,8 @@ class SPMDTrainer:
             if fresh:
                 fn = self._build_step(arrays)
                 self._step_cache[sig] = fn
-            with _profiler.span("spmd.step.args", "trainer"):
-                self._t += 1
-                self._optimizer.num_update = self._t
-                lr = self.learning_rate()
-                rescale = self._optimizer.rescale_grad / batch_size
-                scalars = (get_key(), jnp.float32(self._t), jnp.float32(lr),
-                           jnp.float32(rescale))
-            loss = self._dispatch("spmd.step", "step", fn, scalars, arrays,
-                                  fresh)
+            loss = self._dispatch("spmd.step", "step", fn,
+                                  self._step_args(batch_size), arrays, fresh)
         return NDArray(loss)
 
     def _step_span(self, name, k=None):
@@ -806,20 +805,42 @@ class SPMDTrainer:
                 args[key] *= k or 1
         return _profiler.span(name, "trainer", args)
 
-    def _scan_args(self, k, batch_size):
-        """The stacked per-step scalars of a ``k``-step scan: the same
-        num_update / lr / PRNG-key schedule as ``k`` calls of ``step``."""
+    def _step_key(self):
+        """The base key, replicated over the mesh and never donated: drawn
+        from ``get_key()`` before the first step and again when the user
+        called ``mx.random.seed`` since (an ``int`` compare a step)."""
+        epoch = seed_epoch()
+        if epoch != self._key_epoch:
+            self._set_base_key(get_key(), epoch)
+        return self._base_key
+
+    def _set_base_key(self, key, epoch):
+        self._base_key = jax.device_put(
+            _np.asarray(key), NamedSharding(self._mesh, P()))
+        self._key_epoch = epoch
+
+    def _step_args(self, batch_size, k=None):
+        """What a compiled step takes before the parameters (see
+        ``_jit_wrapped``): the base key and three HOST values, so that
+        making them dispatches no program and nothing travels from one
+        chip to the others — the int32 num_update and the float32 lr
+        (``[k]`` each for a ``k``-step scan) and the float32 rescale.  A
+        scan runs the same num_update / lr schedule as ``k`` calls of
+        ``step``, and the same PRNG keys, ``fold_in(base key,
+        num_update)``.  (One packed float32 array was tried: slicing it in
+        the program cost BERT-base's step 0.03 ms, PERF.md, PR 27.)"""
         with _profiler.span("spmd.step.args", "trainer"):
-            ts, lrs, keys = [], [], []
-            for _ in range(k):
+            ts, lrs = [], []
+            for _ in range(k or 1):
                 self._t += 1
                 self._optimizer.num_update = self._t
-                ts.append(float(self._t))
+                ts.append(self._t)
                 lrs.append(self.learning_rate())
-                keys.append(get_key())
             rescale = self._optimizer.rescale_grad / batch_size
-            return (jnp.stack(keys), jnp.asarray(ts, jnp.float32),
-                    jnp.asarray(lrs, jnp.float32), jnp.float32(rescale))
+            return (self._step_key(),
+                    _np.asarray(ts if k else ts[0], _np.int32),
+                    _np.asarray(lrs if k else lrs[0], _np.float32),
+                    _np.asarray(rescale, _np.float32))
 
     def _dispatch(self, site, program, fn, scalars, arrays, fresh, k=1,
                   declared_warmup=False):
@@ -890,8 +911,10 @@ class SPMDTrainer:
         The batch is reused for all ``k`` steps (callers feeding real data
         should call once per batch; the win is for dispatch-bound
         programs).  Numerically identical to ``k`` successive ``step()``
-        calls with the same batch (same per-step num_update/lr/PRNG-key
-        schedule); returns the LAST step's mean loss as an NDArray.
+        calls with the same batch: step ``t`` of either takes num_update
+        ``t``, the scheduler's lr at ``t`` and the PRNG key ``fold_in(base
+        key, t)`` (``_step_args``).  Returns the LAST step's mean loss as
+        an NDArray.
         """
         if k < 1:
             raise ValueError(f"step_bulk needs k >= 1, got {k}")
@@ -909,7 +932,7 @@ class SPMDTrainer:
                 self._step_cache[sig] = fn
             loss = self._dispatch(
                 "spmd.step_bulk", f"step_bulk[{k}]", fn,
-                self._scan_args(k, batch_size), arrays, fresh, k=k)
+                self._step_args(batch_size, k), arrays, fresh, k=k)
         return NDArray(loss)
 
     def _build_bulk(self, example_arrays, k):
@@ -966,11 +989,12 @@ class SPMDTrainer:
         (collectives, codec buckets and all) becomes a ``lax.scan`` body,
         consuming one row of the ``[K, batch, ...]`` stacked window
         (``io.DataPipeline.stage_window(k)``) per iteration.  Numerically
-        identical to K successive ``step()`` calls on the K rows (same
-        num_update/lr/PRNG-key schedule); returns the LAST step's mean
-        loss.  K rides the window's leading axis — an epoch tail simply
-        dispatches a shorter program (registered as a declared warmup,
-        not a steady-state recompile)."""
+        identical to K successive ``step()`` calls on the K rows (the same
+        num_update / lr / ``fold_in(base key, num_update)`` key at every
+        step, see ``_step_args``); returns the LAST step's mean loss.  K
+        rides the window's leading axis — an epoch tail simply dispatches
+        a shorter program (registered as a declared warmup, not a
+        steady-state recompile)."""
         inputs = data if isinstance(data, (list, tuple)) else (data,)
         shape = _np.shape(inputs[0])
         if len(shape) < 2:
@@ -992,7 +1016,7 @@ class SPMDTrainer:
                 self._step_cache[sig] = fn
             loss = self._dispatch(
                 "spmd.step_window", f"step_window[{k}]", fn,
-                self._scan_args(k, batch_size), arrays, fresh, k=k,
+                self._step_args(batch_size, k), arrays, fresh, k=k,
                 declared_warmup=k != self._window_k)
         return NDArray(loss)
 
@@ -1042,7 +1066,18 @@ class SPMDTrainer:
     def _jit_wrapped(self, step_fn):
         """jit a (keys, t(s), lr(s), rescale, params, states[, comm],
         *batch) step with param/state (and error-feedback residual)
-        donation and the trainer's output shardings."""
+        donation and the trainer's output shardings.  The compiled program
+        takes ``_step_args``'s base key and int32 num_update(s) in place
+        of the first two and derives them itself: step ``t``'s key is
+        ``fold_in(base key, t)``, one key a step of a scan."""
+        def step(base_key, steps, *rest):
+            fold = jax.random.fold_in
+            keys = (jax.vmap(fold, (None, 0))(base_key, steps) if steps.ndim
+                    else fold(base_key, steps))
+            return step_fn(keys, steps.astype(jnp.float32), *rest)
+
+        # the device trace names the program jit_<__name__>
+        step.__name__ = step_fn.__name__
         comm = self._comm_state is not None
         out_shardings = [
             list(self._param_shardings),
@@ -1060,7 +1095,7 @@ class SPMDTrainer:
         donate = ((4, 5, 6) if comm else (4, 5)) if self._donate else ()
         with self._mesh:
             return jax.jit(
-                step_fn, donate_argnums=donate,
+                step, donate_argnums=donate,
                 out_shardings=tuple(out_shardings)
             )
 
@@ -1626,7 +1661,10 @@ class SPMDTrainer:
         from ..checkpoint import atomic_write_bytes
 
         flat = jax.tree_util.tree_map(_np.asarray, self._opt_states)
-        payload = {"states": flat, "num_update": self._t}
+        # the dropout stream is a function of (base key, num_update): with
+        # both in the snapshot a resumed run draws the unbroken run's masks
+        payload = {"states": flat, "num_update": self._t,
+                   "base_key": _np.asarray(self._step_key())}
         if self._comm_state is not None:
             # error-feedback residuals are step state: dropping them at
             # restore re-injects one step's quantization error.  Each
@@ -1649,6 +1687,8 @@ class SPMDTrainer:
             self._state_shardings,
         )
         self._t = payload["num_update"]
+        if payload.get("base_key") is not None:
+            self._set_base_key(payload["base_key"], seed_epoch())
         cr = payload.get("comm_residual")
         if self._comm_state is not None:
             # expected per-process shape from shard METADATA — snapshots

@@ -6,6 +6,12 @@ functional, so we keep ONE process-level key that is split per sampling call
 (eager mode), plus a stack of *traced* keys pushed by jitted callables
 (hybridized blocks / train steps) so dropout & samplers stay deterministic and
 trace-safe under ``jax.jit``.
+
+``SPMDTrainer`` does not split this key every step: it draws ONE base key with
+``get_key()`` and derives step ``t``'s key inside the compiled step as
+``fold_in(base, t)``, so a step dispatches no PRNG program.  ``seed`` bumps
+the thread's *seed epoch* (``seed_epoch()``, a host ``int``); a holder of such
+a drawn-once key compares it and draws again after a re-seed.
 """
 from __future__ import annotations
 
@@ -14,7 +20,7 @@ import threading
 import jax
 import jax.numpy as jnp
 
-__all__ = ["seed", "get_key", "push_traced_key", "pop_traced_key", "uniform", "normal", "randint", "randn"]
+__all__ = ["seed", "seed_epoch", "get_key", "push_traced_key", "pop_traced_key", "uniform", "normal", "randint", "randn"]
 
 _state = threading.local()
 
@@ -23,6 +29,7 @@ def _ensure():
     if not hasattr(_state, "key"):
         _state.key = jax.random.PRNGKey(0)
         _state.traced = []
+        _state.epoch = 0
     return _state
 
 
@@ -31,6 +38,14 @@ def seed(seed_state, ctx="all"):
     are device-agnostic)."""
     s = _ensure()
     s.key = jax.random.PRNGKey(int(seed_state))
+    s.epoch += 1
+
+
+def seed_epoch():
+    """How many times this thread has called ``seed``.  Whoever keeps a key
+    drawn once from ``get_key()`` (``SPMDTrainer``'s base key) compares
+    this, an ``int`` and no device work, to see that the user re-seeded."""
+    return _ensure().epoch
 
 
 def get_key():

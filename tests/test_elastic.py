@@ -253,6 +253,45 @@ class TestRunCheckpoint:
         part2 = _train(net2, tr2, 3, it2)
         np.testing.assert_allclose(part1 + part2, ref, rtol=0, atol=1e-7)
 
+    def test_spmd_exact_resume_with_dropout(self, tmp_path):
+        """SPMDTrainer's dropout stream is fold_in(base key, num_update):
+        both ride the snapshot, so 3 steps, save, rebuild from ANOTHER
+        seed, restore, 3 more give the unbroken run's six losses."""
+        from incubator_mxnet_tpu.parallel import SPMDTrainer, make_mesh
+
+        x = np.random.RandomState(0).randn(16, 5).astype(np.float32)
+        y = np.random.RandomState(1).randn(16, 1).astype(np.float32)
+
+        def build(seed):
+            mx.random.seed(seed)
+            net = gluon.nn.HybridSequential()
+            net.add(gluon.nn.Dense(16, activation="relu"),
+                    gluon.nn.Dropout(0.1), gluon.nn.Dense(1))
+            net.initialize()
+            net(mx.nd.array(x[:4]))
+            return net, SPMDTrainer(
+                net, gluon.loss.L2Loss(), "sgd",
+                {"learning_rate": 0.05, "momentum": 0.9}, mesh=make_mesh())
+
+        def train(tr, steps):
+            return [float(tr.step(x, y).asnumpy()) for _ in range(steps)]
+
+        ref = train(build(0)[1], 6)
+        assert len(set(ref)) == 6
+
+        net1, tr1 = build(0)
+        part1 = train(tr1, 3)
+        prefix = str(tmp_path / "run")
+        elastic.RunCheckpoint(prefix, net=net1, trainer=tr1,
+                              rank=0, world=1).save(3)
+
+        net2, tr2 = build(99)                    # resume must overwrite this
+        payload = elastic.RunCheckpoint(prefix, net=net2, trainer=tr2,
+                                        rank=0, world=1).restore()
+        assert payload is not None and payload["step"] == 3
+        part2 = train(tr2, 3)
+        np.testing.assert_allclose(part1 + part2, ref, rtol=0, atol=1e-7)
+
     def test_restore_refuses_uncommitted_snapshot(self, tmp_path):
         prefix = str(tmp_path / "run")
         ck = elastic.RunCheckpoint(prefix, rank=0, world=1)

@@ -22,10 +22,13 @@ import jax
 import jax.numpy as jnp
 
 
-def _mlp(seed=7, in_dim=12):
+def _mlp(seed=7, in_dim=12, dropout=None):
     mx.random.seed(seed)
     net = nn.HybridSequential()
-    net.add(nn.Dense(32, activation="relu"), nn.Dense(16, activation="relu"), nn.Dense(4))
+    net.add(nn.Dense(32, activation="relu"))
+    if dropout is not None:
+        net.add(nn.Dropout(dropout))
+    net.add(nn.Dense(16, activation="relu"), nn.Dense(4))
     net.initialize()
     net(mx.nd.zeros((2, in_dim)))  # materialize deferred shapes
     return net
@@ -106,15 +109,17 @@ class TestSPMDTrainer:
 
         _assert_params_close(net_a, net_b)
 
-    def test_step_bulk_matches_sequential(self):
+    @pytest.mark.parametrize("dropout", [0.0, 0.1])
+    def test_step_bulk_matches_sequential(self, dropout):
         """k bulked steps (one lax.scan dispatch — the engine-bulking
         analog) must equal k sequential step() calls: same params, same
-        num_update, same key schedule."""
+        num_update, same key schedule (step t's dropout key is
+        fold_in(base key, t) in both)."""
         x, y = _data()
         loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
 
-        net_a = _mlp(seed=23)
-        net_b = _mlp(seed=23)
+        net_a = _mlp(seed=23, dropout=dropout)
+        net_b = _mlp(seed=23, dropout=dropout)
         xa, ya = mx.nd.array(x), mx.nd.array(y)
 
         mx.random.seed(5)
@@ -132,6 +137,32 @@ class TestSPMDTrainer:
         blk.sync_to_block()
 
         assert blk.num_update == seq.num_update == 6
+        _assert_params_close(net_a, net_b)
+
+    @pytest.mark.parametrize("dropout", [0.0, 0.1])
+    def test_step_window_matches_sequential(self, dropout):
+        """One step_window over K different rows equals K step() calls on
+        the rows, dropout masks included."""
+        rows = [_data(seed=s) for s in (3, 4, 5)]
+        loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+        net_a = _mlp(seed=29, dropout=dropout)
+        net_b = _mlp(seed=29, dropout=dropout)
+
+        mx.random.seed(6)
+        seq = SPMDTrainer(net_a, loss_fn, "adam", {"learning_rate": 0.01},
+                          mesh=make_mesh())
+        for x, y in rows:
+            seq.step(x, y)
+        seq.sync_to_block()
+
+        mx.random.seed(6)
+        win = SPMDTrainer(net_b, loss_fn, "adam", {"learning_rate": 0.01},
+                          mesh=make_mesh())
+        win.step_window(np.stack([x for x, _ in rows]),
+                        np.stack([y for _, y in rows]))
+        win.sync_to_block()
+
+        assert win.num_update == seq.num_update == 3
         _assert_params_close(net_a, net_b)
 
     def test_adam_bias_correction_not_frozen(self):
@@ -241,6 +272,114 @@ class TestSPMDTrainer:
         spmd.sync_to_block()
         after = params[rm_name].data().asnumpy()
         assert not np.allclose(before, after)
+
+
+def _dropout_trainer(seed, optimizer_params=None):
+    net = _mlp(seed=31, dropout=0.1)
+    mx.random.seed(seed)
+    return net, SPMDTrainer(
+        net, gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
+        optimizer_params or {"learning_rate": 0.1}, mesh=make_mesh())
+
+
+def _losses(tr, steps):
+    x, y = _data()
+    return [float(tr.step(x, y).asnumpy()) for _ in range(steps)]
+
+
+class TestStepArguments:
+    """What a step hands its compiled program before the parameters: the
+    base key on the mesh and host values — making them dispatches nothing,
+    and step t's key is fold_in(base key, t) inside the program."""
+
+    @pytest.mark.parametrize("entry", ["step", "step_bulk", "step_window"])
+    def test_leading_arguments_are_host_values_or_the_base_key(self, entry):
+        from jax.sharding import NamedSharding
+
+        _, tr = _dropout_trainer(1)
+        x, y = _data()
+        k = {"step": None, "step_bulk": 2, "step_window": 3}[entry]
+
+        def call():
+            if entry == "step_window":
+                return tr.step_window(np.stack([x] * k), np.stack([y] * k))
+            return tr.step(x, y) if k is None else tr.step_bulk(x, y, k)
+
+        call()                                   # builds and caches fn
+        (sig, fn), = tr._step_cache.items()
+        seen = []
+        tr._step_cache[sig] = lambda *a: seen.append(a) or fn(*a)
+        call()
+        call()
+        assert len(seen) == 2
+        for args in seen:
+            n_lead = next(i for i, a in enumerate(args)
+                          if isinstance(a, list))    # the parameters
+            key, steps, lrs, rescale = args[:n_lead]
+            assert key is tr._base_key
+            assert key.sharding == NamedSharding(tr.mesh, P())
+            assert type(steps) is np.ndarray and steps.dtype == np.int32
+            for host in (lrs, rescale):
+                assert type(host) is np.ndarray and host.dtype == np.float32
+            assert steps.shape == lrs.shape == (() if k is None else (k,))
+            np.testing.assert_allclose(lrs, 0.1)
+            np.testing.assert_allclose(rescale, 1 / 64)
+        assert int(np.max(seen[-1][1])) == tr.num_update
+        assert fn._cache_size() == 1
+
+    def test_lr_schedule_does_not_recompile(self):
+        from incubator_mxnet_tpu import profiler
+        from incubator_mxnet_tpu.lr_scheduler import FactorScheduler
+
+        _, tr = _dropout_trainer(1, {
+            "learning_rate": 0.1,
+            "lr_scheduler": FactorScheduler(step=1, factor=0.5, base_lr=0.1)})
+        _losses(tr, 1)
+        fn, = tr._step_cache.values()
+        before = profiler.counters()
+        lrs = []
+        for _ in range(5):
+            _losses(tr, 1)
+            lrs.append(tr.learning_rate())
+        after = profiler.counters()
+        assert len(set(lrs)) == 5
+        for name in ("compile_total", "recompile_steady_state"):
+            assert after[name] == before[name], name
+        assert fn._cache_size() == 1
+
+    @pytest.mark.parametrize("other_seed,same", [(7, True), (8, False)])
+    def test_dropout_stream_follows_the_seed(self, other_seed, same):
+        net_a, a = _dropout_trainer(7)
+        _losses(a, 3)
+        a.sync_to_block()
+        net_b, b = _dropout_trainer(other_seed)
+        _losses(b, 3)
+        b.sync_to_block()
+        equal = all(
+            np.array_equal(p.data().asnumpy(), q.data().asnumpy())
+            for p, q in zip(net_a.collect_params().values(),
+                            net_b.collect_params().values()))
+        assert equal == same
+
+    def test_consecutive_steps_draw_different_masks(self):
+        # lr 0: the parameters stand still, so the loss moves with the
+        # dropout mask alone
+        _, tr = _dropout_trainer(7, {"learning_rate": 0.0})
+        assert len(set(_losses(tr, 4))) == 4
+
+    def test_reseed_between_steps_changes_the_stream_from_there(self):
+        def run(reseed):
+            _, tr = _dropout_trainer(5)
+            first = _losses(tr, 2)
+            if reseed is not None:
+                mx.random.seed(reseed)
+            return first, _losses(tr, 3)
+
+        plain, reseeded, again = run(None), run(9), run(9)
+        assert plain[0] == reseeded[0] == again[0]
+        assert reseeded[1] == again[1]
+        # the step right after the re-seed already draws from the new key
+        assert plain[1][0] != reseeded[1][0]
 
 
 class TestRingAttention:

@@ -1035,8 +1035,6 @@ def _tiny_spmd(builder):
 @pytest.mark.parametrize("builder", ["plain", "compressed",
                                      "compressed_sharded", "pipeline"])
 def test_compiled_step_carries_phase_and_block_names(builder):
-    import jax.numpy as jnp
-
     net, tr, x, y = _tiny_spmd(builder)
     assert (tr._stages is not None) == (builder == "pipeline")
     assert (tr._comm_cfg is not None) == builder.startswith("compressed")
@@ -1047,8 +1045,8 @@ def test_compiled_step_carries_phase_and_block_names(builder):
     fn = tr._build_step(arrays)
     assert fn.__name__ == "pure_step"   # the trace reads jit_pure_step(
     comm = () if tr._comm_state is None else (tr._comm_state,)
-    text = fn.lower(jax.random.PRNGKey(0), jnp.float32(1), jnp.float32(0.01),
-                    jnp.float32(1.0), tr._param_arrays, tr._opt_states,
+    text = fn.lower(tr._step_key(), np.int32(1), np.float32(0.01),
+                    np.float32(1.0), tr._param_arrays, tr._opt_states,
                     *comm, *arrays).as_text(debug_info=True)
     dense = net[0].name
     for needle in ("spmd.forward", "spmd.loss", "spmd.optimizer",

@@ -29,14 +29,12 @@ def main():
     args = ap.parse_args()
 
     import jax
-    import jax.numpy as jnp
 
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import amp
     from incubator_mxnet_tpu.gluon.model_zoo.bert import BERTModel, BERTForPretrain
     from incubator_mxnet_tpu.ndarray.ndarray import NDArray
     from incubator_mxnet_tpu.parallel import make_mesh, SPMDTrainer
-    from incubator_mxnet_tpu.random import get_key
 
     B, S = args.batch, 128
     amp.init("bfloat16")
@@ -62,8 +60,8 @@ def main():
     arrays = trainer.shard_batch(tok, seg, labels)
     fn = trainer._build_step(arrays)
     lowered = fn.lower(
-        get_key(), jnp.float32(1), jnp.float32(1e-4), jnp.float32(1.0 / B),
-        trainer._param_arrays, trainer._opt_states, *arrays,
+        *trainer._step_args(B), trainer._param_arrays, trainer._opt_states,
+        *arrays,
     )
     hlo = lowered.compile().as_text()
     if args.dump:
