@@ -18,6 +18,7 @@ TARGET_OPS = {
     "Convolution", "Deconvolution",
     "dot", "batch_dot", "linalg_gemm2",
     "fused_attention", "fused_qkv_attention", "fused_kv_attention",
+    "latent_attention", "swiglu_ffn",
     "RNN",
     # Embedding output feeds the transformer residual stream; emitting it
     # in the target dtype keeps that stream bf16 end-to-end (the norms

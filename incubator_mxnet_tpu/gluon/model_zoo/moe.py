@@ -36,6 +36,7 @@ __all__ = [
     "moe_loss_frame",
     "frame_loss",
     "frame_metrics",
+    "register_metrics",
 ]
 
 _tls = _threading.local()
@@ -79,6 +80,20 @@ def _register(loss, metrics):
     return True
 
 
+def register_metrics(metrics):
+    """Hand a layer's routing metrics (``tokens_dropped``,
+    ``expert_load_min``, ``expert_load_max`` and, from a layer that holds a
+    share of its experts, ``rows_routed_here``) to the innermost frame; a
+    layer with no auxiliary loss registers nothing else.  Call it from the
+    step's own trace: a value traced inside ``jax.checkpoint`` has to be
+    returned out of it first."""
+    st = _frames()
+    if not st or in_backward_trace():
+        return False
+    st[-1].metrics.append(metrics)
+    return True
+
+
 def frame_loss(frame):
     """Sum of the frame's weighted aux losses (None when no MoE ran)."""
     if not frame.losses:
@@ -107,6 +122,10 @@ def frame_metrics(frame):
             out["expert_load_min"] + mn - abs(out["expert_load_min"] - mn))
         out["expert_load_max"] = 0.5 * (
             out["expert_load_max"] + mx + abs(out["expert_load_max"] - mx))
+    rows = [m["rows_routed_here"] for m in frame.metrics
+            if "rows_routed_here" in m]
+    if rows:   # only layers that hold a share of their experts report it
+        out["rows_routed_here"] = sum(rows[1:], rows[0])
     return out
 
 
@@ -131,6 +150,12 @@ def moe_sharding_rules(base=None):
 
 class MoEBlock(HybridBlock):
     """Top-k routed mixture-of-experts FFN: [..., units] → [..., units].
+
+    Takes the CAPACITY path (``ops.moe.moe_ffn``): softmax gates, dense
+    one-hot dispatch, overflow dropped and counted.  The dropless path
+    (``ops.moe.moe_ffn_dropless``: sigmoid scores, sorted pairs, grouped
+    products over the experts a chip holds) is ``model_zoo.xing4
+    .SparseExperts``'.
 
     Parameters
     ----------

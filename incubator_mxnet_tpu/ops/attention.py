@@ -42,8 +42,17 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as _pl
+from jax.experimental.pallas import tpu as _pltpu
 
-__all__ = ["flash_attention", "attention_reference"]
+__all__ = ["flash_attention", "attention_reference", "latent_attention",
+           "yarn_rotary_tables", "apply_rotary"]
+
+# The kernels keep one head's whole K and V (backward: Q, dO, O and the
+# log-sum-exp) in VMEM beside their 512-wide tiles: 16.5 MB at S 4096 with
+# 192-wide keys, over the compiler's default scoped limit of 16 MiB by half a
+# megabyte in some programs and not in others (where XLA places an operand
+# decides); a v5e core has 128 MiB.
+_MOSAIC_PARAMS = _pltpu.CompilerParams(vmem_limit_bytes=48 << 20)
 
 # TPU lane width: row statistics (lse) are replicated across a 128-lane
 # trailing dim so their blocks satisfy Mosaic's (8, 128) tiling rule.
@@ -89,7 +98,9 @@ def online_softmax_update(o, m, l, s, v, matmul):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
     """One (batch·head, q-block) grid cell: stream K/V blocks, online
-    softmax in fp32.  Shapes: q_ref [1, Bq, D], k/v_ref [1, Sk, D].
+    softmax in fp32.  Shapes: q_ref [1, Bq, D], k_ref [1, Sk, D],
+    v_ref [1, Sk, Dv] (Dv may differ from D: latent attention has 192-wide
+    queries and keys and 128-wide values).
 
     Operands stay in their input dtype (bf16 rides the MXU at full rate)
     with fp32 accumulation via preferred_element_type; matmul precision is
@@ -97,7 +108,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
     request an fp32 contraction on bf16 operands, which Mosaic rejects."""
     i = _pl.program_id(1)
     block_q = q_ref.shape[1]
-    d = q_ref.shape[2]
     seq_k = k_ref.shape[1]
     nk = seq_k // block_k
     prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
@@ -106,7 +116,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
     q = q_ref[0]  # [Bq, D], native dtype
     m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
 
     def body(j, carry):
         m, l, acc = carry
@@ -152,10 +162,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
 
 def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=128,
                       with_lse=False):
-    """q/k/v: [BH, S, D] (batch·heads flattened).  ``with_lse=True`` also
-    returns the per-row log-sum-exp [BH, S] for the blockwise backward."""
+    """q/k: [BH, S, D], v: [BH, S, Dv] (batch·heads flattened).
+    ``with_lse=True`` also returns the per-row log-sum-exp [BH, S] for the
+    blockwise backward."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
+    o_shape = (bh, sq, dv)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     if sq % block_q or sk % block_k:
@@ -163,16 +175,16 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
     grid = (bh, sq // block_q)
     if with_lse:
         kernel = functools.partial(_fwd_kernel, block_k=block_k, causal=causal, scale=scale)
-        out_shape = (jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape = (jax.ShapeDtypeStruct(o_shape, q.dtype),
                      jax.ShapeDtypeStruct((bh, sq, _LANE), jnp.float32))
-        out_specs = (_pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        out_specs = (_pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
                      _pl.BlockSpec((1, block_q, _LANE), lambda b, i: (b, i, 0)))
     else:
         def kernel(q_ref, k_ref, v_ref, o_ref, **_):
             _fwd_kernel(q_ref, k_ref, v_ref, o_ref, None,
                         block_k=block_k, causal=causal, scale=scale)
-        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-        out_specs = _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+        out_shape = jax.ShapeDtypeStruct(o_shape, q.dtype)
+        out_specs = _pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0))
     return _pl.pallas_call(
         kernel,
         out_shape=out_shape,
@@ -180,10 +192,11 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
         in_specs=[
             _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             _pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            _pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
+            _pl.BlockSpec((1, sk, dv), lambda b, i: (b, 0, 0)),
         ],
         out_specs=out_specs,
         interpret=interpret,
+        compiler_params=_MOSAIC_PARAMS,
     )(q, k, v)
 
 
@@ -242,7 +255,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, *,
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                     dk_ref, dv_ref, *, block_q, causal, scale):
     i = _pl.program_id(1)
-    block_k, d = k_ref.shape[1], k_ref.shape[2]
+    block_k, d, d_v = k_ref.shape[1], k_ref.shape[2], v_ref.shape[2]
     seq_q = q_ref.shape[1]
     nq = seq_q // block_q
     prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
@@ -283,38 +296,40 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     j0 = (i * block_k) // block_q if causal else 0
     dk, dv = lax.fori_loop(
         j0, nq, body,
-        (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d), jnp.float32)))
+        (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d_v), jnp.float32)))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
                       block_q=128, block_k=128):
-    """q/k/v/do/o: [BH, S, D]; lse: [BH, Sq, _LANE] fp32 → (dq, dk, dv)."""
+    """q/k: [BH, S, D]; v/do/o: [BH, S, Dv]; lse: [BH, Sq, _LANE] fp32
+    → (dq, dk, dv)."""
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, d_v = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     grid_q = (bh, sq // block_q)
     grid_k = (bh, sk // block_k)
 
-    qkv_full = lambda s: _pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
-    qblk = _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
+    full = lambda s, w: _pl.BlockSpec((1, s, w), lambda b, i: (b, 0, 0))
+    blk = lambda rows, w: _pl.BlockSpec((1, rows, w), lambda b, i: (b, i, 0))
 
     dq = _pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=grid_q,
         in_specs=[
-            qblk,                                                     # q
-            qkv_full(sk),                                             # k
-            qkv_full(sk),                                             # v
-            qblk,                                                     # do
-            qblk,                                                     # o
-            _pl.BlockSpec((1, block_q, _LANE), lambda b, i: (b, i, 0)),  # lse
+            blk(block_q, d),                                          # q
+            full(sk, d),                                              # k
+            full(sk, d_v),                                            # v
+            blk(block_q, d_v),                                        # do
+            blk(block_q, d_v),                                        # o
+            blk(block_q, _LANE),                                      # lse
         ],
-        out_specs=qblk,
+        out_specs=blk(block_q, d),
         interpret=interpret,
+        compiler_params=_MOSAIC_PARAMS,
     )(q, k, v, do, o, lse)
 
     dk, dv = _pl.pallas_call(
@@ -323,16 +338,16 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         grid=grid_k,
         in_specs=[
-            qkv_full(sq),                                             # q
-            _pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),   # k
-            _pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),   # v
-            qkv_full(sq),                                             # do
-            qkv_full(sq),                                             # o
-            _pl.BlockSpec((1, sq, _LANE), lambda b, i: (b, 0, 0)),    # lse
+            full(sq, d),                                              # q
+            blk(block_k, d),                                          # k
+            blk(block_k, d_v),                                        # v
+            full(sq, d_v),                                            # do
+            full(sq, d_v),                                            # o
+            full(sq, _LANE),                                          # lse
         ],
-        out_specs=(_pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0)),
-                   _pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))),
+        out_specs=(blk(block_k, d), blk(block_k, d_v)),
         interpret=interpret,
+        compiler_params=_MOSAIC_PARAMS,
     )(q, k, v, do, o, lse)
     return dq, dk, dv
 
@@ -366,12 +381,23 @@ def attention_reference(q, k, v, causal=False, scale=None):
                       precision=prec).astype(v.dtype)
 
 
-def _pallas_blocks(sq, sk, block_q=128, block_k=128):
+# Largest block the Pallas kernels tile queries and keys by.  Measured on a
+# v5e at [32 heads, S 4096, 192-wide keys, 128-wide values], bf16, causal
+# (PERF.md, PR 28): forward / backward 7.42 / 21.44 ms at 128 x 128, 3.27 /
+# 7.56 at 256 x 256, 2.34 / 6.56 at 512 x 512, 2.57 / 6.59 at 1024 x 512;
+# 1024-wide key blocks do not fit the kernels' VMEM.
+_PALLAS_BLOCK_Q = 512
+_PALLAS_BLOCK_K = 512
+
+
+def _pallas_blocks(sq, sk, block_q=None, block_k=None):
     """Largest MXU-friendly blocks that evenly divide the sequence lengths,
     or None if none exists (→ fall back to the XLA path rather than crash
     on unpadded/bucketed lengths)."""
-    bq = next((b for b in (block_q, 64, 32, 16, 8) if sq % b == 0), None)
-    bk = next((b for b in (block_k, 64, 32, 16, 8) if sk % b == 0), None)
+    sizes = (1024, 512, 256, 128, 64, 32, 16, 8)
+    block_q, block_k = block_q or _PALLAS_BLOCK_Q, block_k or _PALLAS_BLOCK_K
+    bq = next((b for b in sizes if b <= block_q and sq % b == 0), None)
+    bk = next((b for b in sizes if b <= block_k and sk % b == 0), None)
     if bq is None or bk is None:
         return None
     return min(bq, sq), min(bk, sk)
@@ -406,10 +432,11 @@ def _flash(q, k, v, causal, scale):
     if use:
         b, h, s, d = q.shape
         out = _flash_fwd_pallas(
-            q.reshape(b * h, s, d), k.reshape(b * h, -1, d), v.reshape(b * h, -1, d),
+            q.reshape(b * h, s, d), k.reshape(b * h, -1, d),
+            v.reshape(b * h, -1, v.shape[-1]),
             causal, scale, interpret, block_q=blocks[0], block_k=blocks[1],
         )
-        return out.reshape(b, h, s, d)
+        return out.reshape(b, h, s, v.shape[-1])
     return attention_reference(q, k, v, causal, scale)
 
 
@@ -420,6 +447,11 @@ def _flash(q, k, v, causal, scale):
 # 809 vs 913 samples/s (XLA wins), S=2048 14.9 vs 11.6 ms, S=4096 16.6 vs
 # 14.9 ms, S=8192 25.9 vs 31.1 ms (blockwise wins).
 _PALLAS_BWD_MIN_SEQ = int(os.environ.get("MXNET_TPU_FLASH_BWD_MIN_SEQ", "8192"))
+# ... and whatever the length, above this many bytes of float32 scores
+# ([B, H, Sq, Sk]) the XLA backward's S×S temporaries (scores, probabilities
+# and their gradients, several copies) no longer fit beside a model: 32 heads
+# at S 4096 are 2 GiB a copy.  The blockwise backward keeps memory linear.
+_PALLAS_BWD_MIN_SCORE_BYTES = 1 << 30
 
 
 def _flash_fwd(q, k, v, causal, scale):
@@ -428,16 +460,19 @@ def _flash_fwd(q, k, v, causal, scale):
     use, interpret, blocks = _should_use_pallas(q, k)
     if use:
         b, h, s, d = q.shape
-        with_lse = max(s, k.shape[2]) >= _PALLAS_BWD_MIN_SEQ
+        sk, d_v = k.shape[2], v.shape[-1]
+        with_lse = (max(s, sk) >= _PALLAS_BWD_MIN_SEQ
+                    or 4 * b * h * s * sk >= _PALLAS_BWD_MIN_SCORE_BYTES)
         res = _flash_fwd_pallas(
-            q.reshape(b * h, s, d), k.reshape(b * h, -1, d), v.reshape(b * h, -1, d),
+            q.reshape(b * h, s, d), k.reshape(b * h, sk, d),
+            v.reshape(b * h, sk, d_v),
             causal, scale, interpret, block_q=blocks[0], block_k=blocks[1],
             with_lse=with_lse)
         if with_lse:
             out, lse = res
-            out = out.reshape(b, h, s, d)
+            out = out.reshape(b, h, s, d_v)
             return out, (q, k, v, out, lse, interpret)
-        return res.reshape(b, h, s, d), (q, k, v, None, None, False)
+        return res.reshape(b, h, s, d_v), (q, k, v, None, None, False)
     out = attention_reference(q, k, v, causal, scale)
     return out, (q, k, v, None, None, False)
 
@@ -446,12 +481,12 @@ def _flash_bwd(causal, scale, res, do):
     q, k, v, o, lse, interpret = res
     if lse is not None:
         b, h, s, d = q.shape
-        sk = k.shape[2]
+        sk, d_v = k.shape[2], v.shape[-1]
         blocks = _pallas_blocks(s, sk)
         dq, dk, dv = _flash_bwd_pallas(
             q.reshape(b * h, s, d), k.reshape(b * h, sk, d),
-            v.reshape(b * h, sk, d), do.reshape(b * h, s, d),
-            o.reshape(b * h, s, d), lse, causal, scale, interpret,
+            v.reshape(b * h, sk, d_v), do.reshape(b * h, s, d_v),
+            o.reshape(b * h, s, d_v), lse, causal, scale, interpret,
             block_q=blocks[0], block_k=blocks[1])
         return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
     return _flash_bwd_xla(causal, scale, (q, k, v), do)
@@ -634,20 +669,23 @@ from .registry import register  # noqa: E402
 
 @register("fused_attention")
 def fused_attention(q, k, v, num_heads=1, causal=False, scale=None):
-    """[B, S, D] convenience form: split heads → flash attention → merge.
-    Registered so it is reachable as ``nd.fused_attention`` /
+    """[B, S, D] convenience form: split heads → flash attention → merge
+    (``v`` [B, S, H·Dv] with its own head size).  Registered so it is reachable as ``nd.fused_attention`` /
     ``nd.contrib.fused_attention`` (the role cuDNN fused MHA plays for the
     reference's GPU builds)."""
     b, s, d = q.shape
     h = num_heads
-    if d % h:
-        raise ValueError(f"feature dim {d} not divisible by num_heads {h}")
+    if d % h or v.shape[-1] % h or k.shape[-1] != d:
+        raise ValueError(f"feature dims {d}/{k.shape[-1]}/{v.shape[-1]} do "
+                         f"not split into num_heads {h}")
 
     def split(x):
-        return x.reshape(b, x.shape[1], h, d // h)
+        return x.reshape(b, x.shape[1], h, x.shape[-1] // h)
 
+    # v may be narrower or wider a head than q and k (latent attention:
+    # 192-wide queries and keys, 128-wide values); the output has v's width
     out = _attend_bshd(split(q), split(k), split(v), causal, scale)
-    return out.reshape(b, s, d)
+    return out.reshape(b, s, v.shape[-1])
 
 
 @register("fused_qkv_attention")
@@ -750,3 +788,109 @@ def interleaved_matmul_encdec_valatt(keys_values, attention, heads=1):
     out = jnp.einsum("bhqk,kbhd->qbhd", att.astype(jnp.float32),
                      v.astype(jnp.float32))
     return out.reshape(out.shape[0], b, h * dh).astype(keys_values.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (DeepSeek-V2/V3 "MLA") with rotary positions under YaRN
+# ---------------------------------------------------------------------------
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention temperature, ``0.1 · mscale · ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_rotary_tables(seq, dim, theta=10000.0, factor=1.0, original=4096,
+                       beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                       mscale_all_dim=0.0):
+    """``(cos, sin)``, each float32 NumPy ``[seq, dim/2]``, for rotary
+    positions 0..seq-1 with the YaRN frequency blend of the DeepSeek-V3
+    modeling file: frequencies that turn more than ``beta_fast`` times in
+    the ``original`` context keep their base value, those that turn less
+    than ``beta_slow`` times are divided by ``factor``, a linear ramp lies
+    between.  ``factor`` 1 is plain RoPE.  Built at trace time: a constant
+    of the compiled program."""
+    import numpy as np
+
+    half = dim // 2
+    base = theta ** (np.arange(half, dtype=np.float64) * 2.0 / dim)
+    inv_freq = 1.0 / base
+    scale = 1.0
+    if factor > 1:
+        def correction_dim(rotations):
+            return (dim * math.log(original / (rotations * 2 * math.pi))
+                    / (2 * math.log(theta)))
+        low = max(math.floor(correction_dim(beta_fast)), 0)
+        high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        keep = 1.0 - ramp                     # 1: base frequency kept
+        inv_freq = inv_freq / factor * (1.0 - keep) + inv_freq * keep
+        scale = (yarn_mscale(factor, mscale)
+                 / yarn_mscale(factor, mscale_all_dim))
+    angles = np.arange(seq, dtype=np.float64)[:, None] * inv_freq[None, :]
+    return ((np.cos(angles) * scale).astype(np.float32),
+            (np.sin(angles) * scale).astype(np.float32))
+
+
+def apply_rotary(x, cos, sin):
+    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis of
+    ``x`` [B, S, H, dim] by the position's angles; the result is laid out as
+    the DeepSeek-V3 modeling file leaves it (first halves, then second
+    halves), which a dot product of two rotated vectors does not see."""
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate([a * c - b * s, b * c + a * s], -1).astype(x.dtype)
+
+
+@register("latent_attention")
+def latent_attention(x, w_qa, g_q, w_qb, w_kva, g_kv, w_kvb, w_o,
+                     num_heads=1, qk_nope_dim=128, qk_rope_dim=64, v_dim=128,
+                     eps=1e-6, causal=True, rope_theta=10000.0,
+                     yarn_factor=1.0, yarn_original=4096, yarn_beta_fast=32.0,
+                     yarn_beta_slow=1.0, yarn_mscale_=1.0,
+                     yarn_mscale_all_dim=0.0, scope="latent_attention"):
+    """Multi-head latent self-attention on ``x`` [B, S, d] (already normed).
+
+    ``c_q = RMSNorm(W_qa x)``; ``[q_nope | q_rope] = W_qb c_q`` per head;
+    ``[c_kv | k_rope] = W_kva x`` with ``k_rope`` shared by all heads;
+    ``[k_nope | v] = W_kvb RMSNorm(c_kv)`` per head; rotary (YaRN) on the
+    rope parts; softmax over ``(q·k) · (nope+rope)^-0.5 · mscale²``; ``W_o``.
+    Weights are ``[out, in]``; no bias.  Queries and keys are
+    ``qk_nope_dim + qk_rope_dim`` wide, values ``v_dim``: the core goes
+    through the attention dispatcher (``_attend_bshd``) as it is, values
+    unpadded.  ``scope`` names the ``jax.named_scope`` of the whole op and,
+    with ``.core``, of the score/softmax/value part."""
+    from .nn import rms_norm
+
+    b, s, _ = x.shape
+    h, dn, dr = int(num_heads), int(qk_nope_dim), int(qk_rope_dim)
+    kv_rank = w_kva.shape[0] - dr
+    prec = (jax.lax.Precision.HIGHEST if x.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+    def proj(a, w):
+        return jnp.einsum("...i,oi->...o", a, w.astype(a.dtype),
+                          precision=prec)
+
+    with jax.named_scope(scope):
+        q = proj(rms_norm(proj(x, w_qa), g_q, eps=eps), w_qb)
+        q = q.reshape(b, s, h, dn + dr)
+        kva = proj(x, w_kva)
+        c_kv, k_rope = kva[..., :kv_rank], kva[..., kv_rank:]
+        kv = proj(rms_norm(c_kv, g_kv, eps=eps), w_kvb)
+        kv = kv.reshape(b, s, h, dn + int(v_dim))
+        cos, sin = yarn_rotary_tables(
+            s, dr, rope_theta, yarn_factor, yarn_original, yarn_beta_fast,
+            yarn_beta_slow, yarn_mscale_, yarn_mscale_all_dim)
+        q_rope = apply_rotary(q[..., dn:], cos, sin)
+        k_rope = apply_rotary(k_rope[:, :, None, :], cos, sin)
+        qf = jnp.concatenate([q[..., :dn], q_rope], -1)
+        kf = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], -1)
+        scale = ((dn + dr) ** -0.5
+                 * yarn_mscale(yarn_factor, yarn_mscale_all_dim) ** 2)
+        with jax.named_scope(scope + ".core"):
+            out = _attend_bshd(qf, kf, kv[..., dn:], causal, scale)
+        return proj(out.reshape(b, s, h * int(v_dim)), w_o)

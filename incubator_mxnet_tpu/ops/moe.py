@@ -1,26 +1,39 @@
-"""Mixture-of-Experts dispatch/combine kernel (the 'ep' mesh axis payload).
+"""Mixture-of-Experts dispatch/combine kernels (the 'ep' mesh axis payload).
 
-One registered op, :func:`moe_ffn`, computes a full top-k-routed expert
-FFN layer: router logits → top-k gates → capacity-limited einsum
-dispatch → per-expert two-layer FFN → weighted combine.  The dispatch is
-the Mesh-TF/Switch formulation — dense one-hot [tokens, experts,
-capacity] tensors instead of gather/scatter — because it is pure MXU
-work, shards over 'ep' on the stacked expert dim with zero custom
-collectives (XLA derives the all-to-alls from the shardings), and its
-drop rule is exact and deterministic: slots are granted in (choice rank,
-token position) order by a cumsum, so token t's first choice always
-beats token t+1's first choice, which beats every second choice.
+Two registered ops, one family:
 
-Static knobs (``num_experts``/``top_k``/``capacity_factor``) arrive as
+:func:`moe_ffn` (the CAPACITY path; ``gluon.model_zoo.moe.MoEBlock`` takes
+it) computes a full top-k-routed expert FFN layer: router logits → top-k
+softmax gates → capacity-limited einsum dispatch → per-expert two-layer FFN
+→ weighted combine.  The dispatch is the Mesh-TF/Switch formulation — dense
+one-hot [tokens, experts, capacity] tensors instead of gather/scatter —
+because it is pure MXU work, shards over 'ep' on the stacked expert dim with
+zero custom collectives (XLA derives the all-to-alls from the shardings),
+and its drop rule is exact and deterministic: slots are granted in (choice
+rank, token position) order by a cumsum, so token t's first choice always
+beats token t+1's first choice, which beats every second choice.  "Pure MXU
+work" has a price that grows with T·E·C: the two dispatch/combine einsums
+are 2 · 2·T·E·C·d FLOPs, which at T 4096, E 64, k 4, C 320 and d 3584 is
+1.2 TFLOP a layer, 27 times the 0.045 TFLOP of the expert matmuls that one
+chip holding 8 of the 64 experts needs — and overflow is DROPPED.  It is
+the path for few experts and short batches.
+
+:func:`moe_ffn_dropless` (``gluon.model_zoo.xing4`` takes it) drops nothing:
+sigmoid scores with a selection bias, the (token, choice) pairs sorted by
+expert, grouped matrix products (``jax.lax.ragged_dot``) over the experts
+this chip HOLDS, a weighted combine.  Its cost follows the rows routed here.
+
+Static knobs (``num_experts``/``top_k``/``capacity_factor``...) arrive as
 kwargs → part of the dispatch-cache/compile signature; capacity derives
 from the static token count, so a fixed batch shape never recompiles.
 
-Returns ``(y, aux_loss, z_loss, tokens_dropped, load_min, load_max)`` —
-losses raw (callers weight them), metrics ``stop_gradient``-ed float32
-so the tuple is vjp-safe end to end.
+:func:`moe_ffn` returns ``(y, aux_loss, z_loss, tokens_dropped, load_min,
+load_max)`` — losses raw (callers weight them), metrics ``stop_gradient``-ed
+float32 so the tuple is vjp-safe end to end.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -28,7 +41,7 @@ import jax.numpy as jnp
 
 from .registry import register
 
-__all__ = ["moe_ffn", "moe_capacity"]
+__all__ = ["moe_ffn", "moe_capacity", "moe_ffn_dropless", "dropless_row_buckets"]
 
 
 def moe_capacity(n_tokens, num_experts, top_k, capacity_factor):
@@ -98,3 +111,165 @@ def moe_ffn(x, router_w, w1, b1, w2, b2, num_experts=1, top_k=1,
     tokens_dropped = sg(float(k * T) - kept.sum())
     return (y, aux_loss, z_loss, tokens_dropped,
             sg(load.min()), sg(load.max()))
+
+
+# ---------------------------------------------------------------------------
+# The dropless path
+# ---------------------------------------------------------------------------
+
+
+def dropless_row_buckets(n_pairs, count, num_experts):
+    """The static row counts the dropless layer is compiled for.  Shapes are
+    static and routing is not, so the work on the sorted rows (gather,
+    grouped products, combine) is compiled at a few sizes and the step takes
+    the smallest that holds the rows routed here: a thirty-second of the
+    expected share T·k·count/E (hardly any row is ours), 1.5 × and 3 × the
+    expected share, and every pair (the worst case: nothing is dropped)."""
+    expected = max(1, n_pairs * count // num_experts)
+    sizes = {min(n_pairs, max(8, -(-r // 8) * 8))
+             for r in (expected // 32, expected * 3 // 2, expected * 3)}
+    return sorted(sizes | {n_pairs})
+
+
+def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
+                     w_gate_up, w_down, top_k):
+    """The held experts' SwiGLU on the first ``rows`` sorted pairs, summed
+    into their tokens: ``[T, d]`` float32.  Pairs past ``n_here`` belong to
+    experts held elsewhere: they add nothing (and ``ragged_dot`` leaves
+    their rows undefined, so they are masked, not trusted)."""
+    cdt = xt.dtype
+    pair = order[:rows]
+    token = pair // top_k
+    ours = (jnp.arange(rows) < n_here)[:, None]
+    # named, not None: the package's global default is 'highest', which the
+    # TPU's grouped-product kernel refuses for bf16 operands
+    prec = (jax.lax.Precision.HIGHEST if cdt == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+    def grouped(a, w):
+        """``ragged_dot`` with the rows past the last group zero on both
+        sides: on the TPU it leaves them UNDEFINED, in its result and (through
+        its transpose) in the cotangent of ``a``; masking the operand masks
+        that cotangent before it is scattered back into real tokens, and
+        masking the result keeps whatever lay there (NaN bit patterns
+        included) out of everything downstream and its derivative."""
+        a = jnp.where(ours, a, 0)
+        out = jax.lax.ragged_dot(a, w.astype(cdt), group_sizes, precision=prec)
+        return jnp.where(ours, out, 0)
+
+    gu = grouped(xt[token], w_gate_up)                         # [rows, 2h]
+    h = w_down.shape[1]
+    act = (jax.nn.silu(gu[:, :h]) * gu[:, h:]).astype(cdt)
+    ys = grouped(act, w_down)                                  # [rows, d]
+    ys = ys.astype(jnp.float32) * gate_flat[pair][:, None]
+    return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts_in_bucket(buckets, top_k, xt, gate_flat, w_gate_up, w_down,
+                       order, group_sizes, n_here):
+    """:func:`_experts_on_rows` at the smallest of ``buckets`` that holds
+    ``n_here`` rows.  The derivative is its own rule and not that of
+    ``lax.switch``: branches of different row counts keep intermediates of
+    different shapes, and differentiating the switch makes EVERY branch write
+    (zeros for) every other branch's, the weights included — 1.9 ms a pass
+    at 64 rows on the v5e.  So the forward keeps only what it was given, and
+    the backward switches again and runs the chosen branch's forward and
+    derivative together: one more forward of the held experts' products."""
+    branches = [functools.partial(_experts_on_rows, rows, top_k=top_k)
+                for rows in buckets]
+    return jax.lax.switch(_bucket_of(buckets, n_here), branches, xt, order,
+                          gate_flat, group_sizes, n_here, w_gate_up, w_down)
+
+
+def _bucket_of(buckets, n_here):
+    return jnp.searchsorted(jnp.asarray(buckets, jnp.int32), n_here)
+
+
+def _experts_in_bucket_fwd(buckets, top_k, *args):
+    return _experts_in_bucket(buckets, top_k, *args), args
+
+
+def _experts_in_bucket_bwd(buckets, top_k, args, ct):
+    from .nn import _zero_cotangent
+
+    xt, gate_flat, w_gate_up, w_down, order, group_sizes, n_here = args
+
+    def branch(rows):
+        def run(xt, gate_flat, w_gate_up, w_down, ct):
+            _, pullback = jax.vjp(
+                lambda xt, gate_flat, w_gate_up, w_down: _experts_on_rows(
+                    rows, xt, order, gate_flat, group_sizes, n_here,
+                    w_gate_up, w_down, top_k),
+                xt, gate_flat, w_gate_up, w_down)
+            return pullback(ct)
+        return run
+
+    grads = jax.lax.switch(_bucket_of(buckets, n_here),
+                           [branch(rows) for rows in buckets],
+                           xt, gate_flat, w_gate_up, w_down, ct)
+    return tuple(grads) + tuple(
+        _zero_cotangent(a) for a in (order, group_sizes, n_here))
+
+
+_experts_in_bucket.defvjp(_experts_in_bucket_fwd, _experts_in_bucket_bwd)
+
+
+@register("moe_ffn_dropless")
+def moe_ffn_dropless(x, router_w, select_bias, w_gate_up, w_down,
+                     num_experts=1, top_k=1, first_expert=0,
+                     routed_scaling=1.0, norm_topk=True, scope="moe"):
+    """Dropless top-k routed SwiGLU experts over the last axis of ``x``: the
+    part of the layer's sum that the experts HELD HERE give.
+
+    Shapes: ``x`` [..., d]; ``router_w`` [E, d] over ALL ``num_experts``;
+    ``select_bias`` [E] (the ``noaux_tc`` selection bias: it moves the
+    choice, never the gate, and carries no gradient); ``w_gate_up``
+    [count, d, 2·h] (gate | up) and ``w_down`` [count, h, d] for the experts
+    ``first_expert .. first_expert + count - 1``.
+
+    ``s = sigmoid(W_r x)`` in float32; the ``top_k`` largest ``s + bias``;
+    gates ``routed_scaling · s_i / Σ_selected s_j`` (``norm_topk``) — over
+    all selected experts, held here or not, so the shares of a layer add up
+    to the whole layer.  Pairs routed to experts held elsewhere add nothing.
+    No capacity, no auxiliary loss, no token dropped.
+
+    Returns ``(y, rows_routed_here, load_min, load_max, load_all)``; the
+    metrics are ``stop_gradient``-ed float32: three scalars (loads over the
+    experts held) and the pairs routed to each of ALL the experts, ``[E]``,
+    which is what the ``noaux_tc`` balancing rule moves the bias by.
+    ``scope`` names the ``jax.named_scope``s ``<scope>.route`` (scores,
+    choice, sort) and ``<scope>.experts`` (gather, grouped products, combine).
+    """
+    E, k, first = int(num_experts), int(top_k), int(first_expert)
+    count, d = w_gate_up.shape[0], x.shape[-1]
+    xt = x.reshape(-1, d)
+    T = xt.shape[0]
+    sg = jax.lax.stop_gradient
+
+    with jax.named_scope(scope + ".route"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,ed->te", xt.astype(jnp.float32), router_w.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))              # [T, E]
+        _, idx = jax.lax.top_k(
+            sg(scores + select_bias.astype(jnp.float32)), k)
+        chosen = jnp.take_along_axis(scores, idx, axis=-1)     # [T, k]
+        gates = chosen * float(routed_scaling)
+        if norm_topk:
+            gates = gates / (chosen.sum(-1, keepdims=True) + 1e-20)
+        # sort the (token, choice) pairs by expert, ours first
+        local = idx.reshape(-1) - first
+        key = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)  # [T·k]
+        load_all = jnp.zeros((E,), jnp.int32).at[idx.reshape(-1)].add(1)
+        group_sizes = load_all[first:first + count]
+        n_here = group_sizes.sum()
+
+    with jax.named_scope(scope + ".experts"):
+        buckets = tuple(dropless_row_buckets(T * k, count, E))
+        y = _experts_in_bucket(buckets, k, xt, gates.reshape(-1), w_gate_up,
+                               w_down, order, group_sizes, n_here)
+        y = y.astype(x.dtype).reshape(x.shape)
+    load = group_sizes.astype(jnp.float32)
+    return (y, sg(n_here.astype(jnp.float32)), sg(load.min()), sg(load.max()),
+            sg(load_all.astype(jnp.float32)))

@@ -393,6 +393,21 @@ def rms_norm(data, gamma, axis=-1, eps=1e-6):
     return out.astype(data.dtype)
 
 
+@register("swiglu_ffn")
+def swiglu_ffn(data, w_gate_up, w_down):
+    """TPU-era extension: ``W_down (silu(W_gate x) ⊙ W_up x)`` over the last
+    axis.  ``w_gate_up`` [2·h, d] (gate rows, then up rows) and ``w_down``
+    [d, h] are ``[out, in]`` like ``FullyConnected``'s; no bias."""
+    prec = (lax.Precision.HIGHEST if data.dtype == jnp.float32
+            else lax.Precision.DEFAULT)
+    h = w_down.shape[1]
+    gu = jnp.einsum("...i,oi->...o", data, w_gate_up.astype(data.dtype),
+                    precision=prec)
+    act = jax.nn.silu(gu[..., :h]) * gu[..., h:]
+    return jnp.einsum("...i,oi->...o", act, w_down.astype(data.dtype),
+                      precision=prec)
+
+
 # ---------------------------------------------------------------------------
 # Activations / softmax
 # ---------------------------------------------------------------------------

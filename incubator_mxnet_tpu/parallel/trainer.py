@@ -117,11 +117,22 @@ def _moe_extras(metrics):
     def raw(v):
         return v._data if isinstance(v, NDArray) else v
 
-    return {
+    out = {
         "moe_tokens_dropped": raw(metrics["tokens_dropped"]),
         "moe_expert_load_min": raw(metrics["expert_load_min"]),
         "moe_expert_load_max": raw(metrics["expert_load_max"]),
     }
+    if "rows_routed_here" in metrics:   # layers that hold a share of experts
+        out["moe_rows_routed_here"] = raw(metrics["rows_routed_here"])
+    return out
+
+
+def _reduce_moe_extras(extras, axes):
+    """The shards' routing metrics as the global-batch build reports them:
+    counts summed, the load extremes taken over the shards."""
+    ops = {"moe_expert_load_min": jax.lax.pmin,
+           "moe_expert_load_max": jax.lax.pmax}
+    return {k: ops.get(k, jax.lax.psum)(v, axes) for k, v in extras.items()}
 
 
 def _release_pipeline_observers(name):
@@ -745,11 +756,16 @@ class SPMDTrainer:
         lmax = float(vals["moe_expert_load_max"].max())
         if dropped:
             _profiler.incr("moe_tokens_dropped", dropped)
+        _profiler.incr("moe_step")
         self._moe_last = {
             "moe_tokens_dropped": dropped,
             "moe_expert_load_min": lmin,
             "moe_expert_load_max": lmax,
         }
+        if "moe_rows_routed_here" in vals:
+            rows = int(round(float(vals["moe_rows_routed_here"].sum())))
+            _profiler.incr("moe_rows_routed_here", rows)
+            self._moe_last["moe_rows_routed_here"] = rows
         if self._stages is not None:
             self._pipe_last.update(self._moe_last)
         if _profiler._active:
@@ -1230,15 +1246,7 @@ class SPMDTrainer:
             # surface matches the global-batch build
             loss_mean = jax.lax.pmean(loss_mean, AX)
             aux_vals = tuple(jax.lax.pmean(a, AX) for a in aux_vals)
-            if extras:
-                extras = {
-                    "moe_tokens_dropped":
-                        jax.lax.psum(extras["moe_tokens_dropped"], AX),
-                    "moe_expert_load_min":
-                        jax.lax.pmin(extras["moe_expert_load_min"], AX),
-                    "moe_expert_load_max":
-                        jax.lax.pmax(extras["moe_expert_load_max"], AX),
-                }
+            extras = _reduce_moe_extras(extras, AX)
             new_resid = resid_out[None, :] if ef else None
             return tuple(new_grads), new_resid, loss_mean, aux_vals, extras
 
@@ -1382,15 +1390,7 @@ class SPMDTrainer:
                         (shape[0] // F,) + tuple(shape[1:]))
             loss_mean = jax.lax.pmean(loss_mean, AX)
             aux_vals = tuple(jax.lax.pmean(a, AX) for a in aux_vals)
-            if extras:
-                extras = {
-                    "moe_tokens_dropped":
-                        jax.lax.psum(extras["moe_tokens_dropped"], AX),
-                    "moe_expert_load_min":
-                        jax.lax.pmin(extras["moe_expert_load_min"], AX),
-                    "moe_expert_load_max":
-                        jax.lax.pmax(extras["moe_expert_load_max"], AX),
-                }
+            extras = _reduce_moe_extras(extras, AX)
             new_resid = resid[None, :] if ef else None
             return tuple(new_grads), new_resid, loss_mean, aux_vals, extras
 

@@ -251,6 +251,8 @@ _counters = {
     "pipeline_microbatch": 0,         # microbatches retired by those steps
     "pipeline_bubble_ms": 0,          # modeled schedule bubble ms (rounded per step)
     "moe_tokens_dropped": 0,          # token-choice slots dropped at expert capacity
+    "moe_rows_routed_here": 0,        # (token, choice) pairs routed to experts this chip holds
+    "moe_step": 0,                    # compiled steps whose routing metrics were read
     "elastic_restart": 0,             # supervisor job re-formations
     "collective_timeout": 0,          # collective-watchdog expiries
     "snapshot_commit_ms": 0,          # two-phase run-snapshot commit wall ms
