@@ -367,10 +367,9 @@ def main(argv=None):
     t_start = time.perf_counter()
 
     if dry_run:
-        # asked for by name: the flash kernels run in the Pallas
-        # interpreter, and the backward kernel engages at the tiny length
+        # asked for by name: the flash kernels, forward and backward, run
+        # in the Pallas interpreter whatever the length
         os.environ["MXNET_TPU_FLASH"] = "interpret"
-        os.environ["MXNET_TPU_FLASH_BWD_MIN_SEQ"] = "128"
     import jax
     import jaxlib
 

@@ -25,9 +25,11 @@ MXNET_PROFILER_AUTOSTART          1 → start an xprof trace at import
                                   (profiler.py)
 MXNET_ENFORCE_DETERMINISM         1 → ``jax_threefry_partitionable`` off +
                                   deterministic reductions where offered
-MXNET_TPU_FLASH                   flash-attention dispatch (ops/attention.py)
-MXNET_TPU_FLASH_FWD_MIN_SEQ,      Pallas crossover thresholds
-MXNET_TPU_FLASH_BWD_MIN_SEQ
+MXNET_TPU_FLASH                   flash-attention dispatch (ops/attention.py):
+                                  ``auto`` (kernels on a TPU) and ``on``
+                                  (compiled wherever the call lands) choose
+                                  by shape at the measured crossover, which
+                                  has no override; ``off``; ``interpret``
 MXNET_TPU_FAST_DROPOUT            u8-mask dropout RNG (ops/nn.py)
 MXNET_TPU_MATMUL_PRECISION        fp32 matmul precision (package __init__)
 MXNET_TPU_PRNG                    PRNG impl: ``rbg`` (default — hardware
@@ -161,8 +163,7 @@ def describe():
                 "MXNET_GPU_MEM_POOL_TYPE", "MXNET_CPU_WORKER_NTHREADS",
                 "MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN",
                 "MXNET_PROFILER_AUTOSTART", "MXNET_ENFORCE_DETERMINISM",
-                "MXNET_TPU_FLASH", "MXNET_TPU_FLASH_FWD_MIN_SEQ",
-                "MXNET_TPU_FLASH_BWD_MIN_SEQ", "MXNET_TPU_FAST_DROPOUT",
+                "MXNET_TPU_FLASH", "MXNET_TPU_FAST_DROPOUT",
                 "MXNET_TPU_MATMUL_PRECISION", "MXNET_TPU_PRNG",
                 "MXNET_TEST_CTX"):
         rows.append((var, os.environ.get(var, "<unset>"),

@@ -3,46 +3,63 @@ attention (the reference has no fused attention at all; its transformer
 support lived out-of-repo in GluonNLP.  SURVEY.md §5 marks this as the one
 area where this framework intentionally EXCEEDS the reference).
 
-Three tiers, chosen by :func:`flash_attention`:
+Four paths; the dispatchers (:func:`flash_attention`, ``_attend_bshd``,
+:func:`fused_qkv_attention`) choose ONE for a call from what its operands'
+shapes say (``_kernel_path``, crossovers measured on a v5e: PERF.md §6, PR
+29), and the forward, the VJP forward and the backward of that call all
+belong to it:
 
-1. **Pallas flash kernel** (compiled by Mosaic on TPU; the Pallas
-   interpreter only when asked for by name, ``MXNET_TPU_FLASH=interpret``
-   — the CPU test tier does): blockwise online-softmax forward — queries tiled over the grid, K/V
-   streamed through VMEM in ``block_k`` chunks, so the S×S score matrix is
-   never materialized in HBM.  Accumulation in fp32 on the MXU
-   (``preferred_element_type``), inputs may be bf16.
-2. **XLA reference path** (non-TPU backends / ``MXNET_TPU_FLASH=off``):
-   same math as one fused jnp expression; XLA fuses adequately for short
-   sequences.
-3. **Ring attention** (``parallel/ring.py``) for sequence-parallel long
+1. **One-tile Pallas kernels that read the fused QKV projection in place**
+   (``_flash_qkv_tile``): self-attention whose whole S×S tile fits VMEM (S
+   up to 512) and whose heads fill 128-lane columns of ``[B, S, 3·H·Dh]``
+   (Dh 32, 64 or 128).  No head transpose; scores, probabilities and their
+   gradients live and die in VMEM; the backward computes the scores and the
+   exponentials once.
+2. **Blockwise Pallas flash kernels** (``_flash_kernels``; compiled by
+   Mosaic on TPU; the Pallas interpreter only when asked for by name,
+   ``MXNET_TPU_FLASH=interpret`` — the CPU test tier does): online-softmax
+   forward — queries tiled over the grid, K/V streamed through VMEM in
+   ``block_k`` chunks — and the two-pass backward (`_flash_bwd_pallas`), so
+   the S×S score matrix is never materialized in HBM and memory stays
+   linear in S.  Accumulation in fp32 on the MXU
+   (``preferred_element_type``), inputs may be bf16.  Want ``[B·H, S, Dh]``
+   physically, so ``[B, S, H, Dh]`` callers pay two transposes.
+3. **XLA path** (short sequences, float16, lengths no block divides,
+   non-TPU backends, ``MXNET_TPU_FLASH=off``): same math as one fused jnp
+   expression with a rematerialized backward; in the ``[B, S, H, Dh]``
+   layout (``_flash_bshd``) the head split/merge is a free reshape of the
+   QKV matmul output and XLA folds the remaining dimension shuffles into
+   the attention dot_generals (docs/PERF_NOTES.md round-3 win).  Its S×S
+   float32 temporaries make a round trip through HBM each, which is what
+   the crossover weighs against the kernels' fixed costs.
+4. **Ring attention** (``parallel/ring.py``) for sequence-parallel long
    context — built on the same online-softmax update.
 
-Gradients: ``jax.custom_vjp`` — backward recomputes attention probabilities
-from the saved (q, k, v), so no S×S residual is stored *between* fwd and
-bwd.  The backward is seq-length gated (thresholds below): short sequences
-take a rematerialized XLA backward (one fused S×S program — faster when
-S×S fits comfortably), long sequences take the two-pass blockwise Pallas
-backward (`_flash_bwd_pallas`) whose memory stays linear in S.
+Gradients: ``jax.custom_vjp`` on every path — the backward recomputes
+attention probabilities from the saved (q, k, v) (the kernels: and the
+log-sum-exp), so no S×S residual is stored *between* fwd and bwd.
 
-Layout: :func:`fused_qkv_attention` / :func:`fused_kv_attention` keep the
-``[B, S, H, Dh]`` layout end-to-end on the short-sequence XLA path so the
-head split/merge is a free reshape of the QKV matmul output and XLA folds
-the remaining dimension shuffles into the attention dot_generals — no
-materialized head transposes (docs/PERF_NOTES.md round-3 win).  The Pallas
-kernels want ``[B·H, S, Dh]`` physically, so the long-context path pays
-the two transposes (negligible against O(S²) attention work there).
+On a mesh: no compiler partitions a Mosaic kernel, so where the trace is
+for several devices (``parallel.mesh_scope``, which ``SPMDTrainer`` opens
+round a step's trace) the dispatchers launch the kernels under a
+``shard_map`` over the batch axes, and leave a mesh that splits the model
+(tp, sp, pp, ep) to the XLA path (``_rows_split``).  A jit over several
+devices that publishes no mesh gets JAX's lowering error for the kernels
+from S 256 up; ``mesh_scope(mesh)`` round the call is the way out.
 """
 from __future__ import annotations
 
 import functools
 import math
 import os
+import typing
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as _pl
 from jax.experimental.pallas import tpu as _pltpu
+from jax.sharding import PartitionSpec
 
 __all__ = ["flash_attention", "attention_reference", "latent_attention",
            "yarn_rotary_tables", "apply_rotary"]
@@ -74,6 +91,60 @@ def _use_pallas(x=None):
     from ..util import resolve_platform
 
     return resolve_platform(x) == "tpu", False  # auto
+
+
+_BATCH_AXES = ("dp", "fsdp")  # parallel/sharding.py: the axes a batch is split over
+
+
+class _Launch(typing.NamedTuple):
+    """How the kernels of one attention call are launched; static for the
+    call's ``custom_vjp``, so its forward and backward agree."""
+    interpret: bool
+    blocks: tuple | None = None   # the blockwise kernels' (block_q, block_k)
+    mesh: typing.Any = None       # launch under a shard_map over ...
+    axes: tuple = ()              # ... these batch axes of it
+
+
+def _axis_bound(name):
+    try:
+        lax.axis_size(name)
+        return True
+    except NameError:
+        return False
+
+
+def _rows_split(batch):
+    """Where a kernel launch has to be placed on the mesh this trace is for
+    (``parallel.mesh_scope``; ``SPMDTrainer`` scopes its own while it traces
+    a step).  The compiler cannot partition a Mosaic kernel by itself — a
+    jit over several devices fails to lower one ("wrap the call in a
+    shard_map") — but attention works on each batch row alone, so:
+    ``(None, ())``: launch as it is (no mesh, one device, or already
+    inside a ``shard_map`` over the mesh); ``(mesh, axes)``: launch under a
+    ``shard_map`` over the batch axes; ``None``: the kernels cannot run
+    (other axes split the model, or the batch does not divide): XLA path."""
+    from ..parallel.mesh import current_mesh
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None, ()
+    split = [a for a in mesh.axis_names if mesh.shape[a] > 1]
+    bound = [a for a in split if _axis_bound(a)]
+    if bound:
+        return (None, ()) if bound == split else None
+    if set(split) <= set(_BATCH_AXES) and batch % mesh.size == 0:
+        return mesh, tuple(split)
+    return None
+
+
+def _on_mesh(launch, fn, *arrays):
+    """``fn(*arrays)`` — arrays and results all lead with the batch (or
+    batch·heads) dimension — where ``launch`` places it."""
+    if launch.mesh is None:
+        return fn(*arrays)
+    rows = PartitionSpec(launch.axes)
+    return jax.shard_map(fn, mesh=launch.mesh, in_specs=(rows,) * len(arrays),
+                         out_specs=rows, check_vma=False)(*arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -353,6 +424,204 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
 
 
 # ---------------------------------------------------------------------------
+# One-tile kernels on the fused QKV projection, read in place.  Up to S 512 a
+# head's whole S×S tile is 1 MB of float32: scores, probabilities and their
+# gradients are computed and consumed in VMEM, the backward computes the scores
+# and the exponentials ONCE (5 matmuls, where the two-pass backward above
+# spends 7), and nothing is transposed: ``[B, S, 3·H·Dh]`` is a whole number of
+# 128-lane columns a head GROUP (two 64-wide heads), so a (1, S, 128) block of
+# the projection's output hands a grid cell its heads where they lie, and the
+# result is written as ``[B, S, H·Dh]``.  Inside a column the heads are told
+# apart by lane masks, not slices: Q·Kᵀ contracts over all 128 lanes with the
+# other heads' lanes of K zeroed, P·V and the three gradient products yield all
+# 128 lanes and a select keeps the head's own — the MXU passes a 64-wide slice
+# would need, and no lane shuffles.
+# ---------------------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+_TN = (((0,), (0,)), ((), ()))   # aᵀ · b
+
+
+def _group_heads(ref_shape, dh):
+    """[(index in the column, lane mask or None)] of the heads that share
+    one ``ref_shape`` = [S, 128] column of the projection."""
+    group = ref_shape[1] // dh
+    if group == 1:
+        return [(0, None)]
+    lane = lax.broadcasted_iota(jnp.int32, ref_shape, 1)
+    return [(g, (lane >= g * dh) & (lane < (g + 1) * dh)) for g in range(group)]
+
+
+def _only(x, mine):
+    return x if mine is None else jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _tile_scores(q, k, mine, causal, scale, prec):
+    """float32 [S, S] scores of the head whose lanes ``mine`` marks."""
+    s = lax.dot_general(q, _only(k, mine), _NT, precision=prec,
+                        preferred_element_type=jnp.float32) * scale
+    if causal:
+        row = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(row >= col, s, -jnp.inf)  # the diagonal keeps every row alive
+    return s
+
+
+def _qkv_tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, dh, causal, scale):
+    """One (batch row, 128-lane column of heads) grid cell.  q/k/v_ref: the
+    column's (1, S, 128) blocks of the three parts of the projection.
+    ``lse_ref`` (1, S, 128) float32 holds the row's log-sum-exp of head h in
+    lane h, for ALL heads of the batch row: it stays in VMEM across the
+    row's columns (the grid's inner axis) and each cell fills its lanes."""
+    col = _pl.program_id(1)
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    prec = (lax.Precision.HIGHEST if q.dtype == jnp.float32
+            else lax.Precision.DEFAULT)
+    heads = _group_heads(q.shape, dh)
+    lane = lax.broadcasted_iota(jnp.int32, q.shape, 1)
+    if lse_ref is not None:
+        @_pl.when(col == 0)
+        def _():
+            lse_ref[0] = jnp.zeros(lse_ref.shape[1:], jnp.float32)
+        lse_row = lse_ref[0]
+    out = None
+    for g, mine in heads:
+        s = _tile_scores(q, k, mine, causal, scale, prec)
+        m = s.max(axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = p.sum(axis=-1, keepdims=True)
+        o = lax.dot_general(p.astype(v.dtype), v, _NN, precision=prec,
+                            preferred_element_type=jnp.float32) / l
+        out = o if out is None else jnp.where(mine, o, out)
+        if lse_ref is not None:
+            lse_row = jnp.where(lane == col * len(heads) + g, m + jnp.log(l), lse_row)
+    o_ref[0] = out.astype(o_ref.dtype)
+    if lse_ref is not None:
+        lse_ref[0] = lse_row
+
+
+def _qkv_tile_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dq_ref, dk_ref, dv_ref, *, dh, causal, scale):
+    col = _pl.program_id(1)
+    q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    prec = (lax.Precision.HIGHEST if q.dtype == jnp.float32
+            else lax.Precision.DEFAULT)
+    mm = functools.partial(lax.dot_general, precision=prec,
+                           preferred_element_type=jnp.float32)
+    heads = _group_heads(q.shape, dh)
+    lane = lax.broadcasted_iota(jnp.int32, q.shape, 1)
+    lse_row = lse_ref[0]
+    do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    dq = dk = dv = None
+    for g, mine in heads:
+        s = _tile_scores(q, k, mine, causal, scale, prec)
+        lse = jnp.sum(jnp.where(lane == col * len(heads) + g, lse_row, 0.0),
+                      axis=-1, keepdims=True)
+        p = jnp.exp(s - lse)                       # [S, S]; masked → 0
+        delta = jnp.sum(_only(do_o, mine), axis=-1, keepdims=True)
+        dv_g = mm(p.astype(do.dtype), do, _TN)      # [S, 128], own lanes valid
+        dp = mm(do, _only(v, mine), _NT)
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dq_g, dk_g = mm(ds, k, _NN), mm(ds, q, _TN)
+        if dq is None:
+            dq, dk, dv = dq_g, dk_g, dv_g
+        else:
+            dq, dk, dv = (jnp.where(mine, new, old) for new, old in
+                          ((dq_g, dq), (dk_g, dk), (dv_g, dv)))
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
+# longest sequence whose tile the kernels above take whole: the backward holds
+# about five float32 S×S tiles, 5 MB at 512 and 20 MB at 1024
+_TILE_MAX_SEQ = 512
+
+_TILE_PARAMS = _pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=48 << 20)
+
+
+def _qkv_tile_fits(s, heads, dh, dtype, interpret=False):
+    """Whether self-attention from a fused ``[B, S, 3·heads·dh]`` projection
+    can run in the one-tile kernels: heads that fill 128-lane columns, one
+    log-sum-exp lane a head, a sequence that is one lane-aligned tile."""
+    return (dh in (32, 64, 128) and (heads * dh) % _LANE == 0
+            and heads <= _LANE and s % _LANE == 0 and s <= _TILE_MAX_SEQ
+            and (interpret or dtype in (jnp.bfloat16, jnp.float32)))
+
+
+def _qkv_tile_grid(qkv):
+    """For a fused projection ``[B, S, 3·D]``: the (batch row, column of
+    heads) grid, the BlockSpecs of the column of part 0/1/2 (q/k/v), of a
+    column of a ``[B, S, D]`` array and of the batch row's log-sum-exp
+    block, and the shape of a ``[B, S, D]`` array of the projection's dtype."""
+    b, s, d3 = qkv.shape
+    cols = d3 // 3 // _LANE
+
+    def part(n):
+        return _pl.BlockSpec((1, s, _LANE), lambda b, c: (b, 0, n * cols + c))
+    return ((b, cols), [part(0), part(1), part(2)],
+            _pl.BlockSpec((1, s, _LANE), lambda b, c: (b, 0, c)),
+            _pl.BlockSpec((1, s, _LANE), lambda b, c: (b, 0, 0)),
+            jax.ShapeDtypeStruct((b, s, d3 // 3), qkv.dtype))
+
+
+def _qkv_tile_fwd(qkv, heads, causal, scale, interpret, with_lse):
+    grid, qkv_specs, col_spec, lse_spec, out_shape = _qkv_tile_grid(qkv)
+    static = dict(dh=out_shape.shape[2] // heads, causal=causal, scale=scale)
+    if with_lse:
+        kernel = functools.partial(_qkv_tile_fwd_kernel, **static)
+        out_shape = (out_shape, jax.ShapeDtypeStruct(
+            out_shape.shape[:2] + (_LANE,), jnp.float32))
+        out_specs = (col_spec, lse_spec)
+    else:
+        def kernel(q_ref, k_ref, v_ref, o_ref):
+            _qkv_tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, None, **static)
+        out_specs = col_spec
+    return _pl.pallas_call(
+        kernel, out_shape=out_shape, grid=grid, in_specs=qkv_specs,
+        out_specs=out_specs, interpret=interpret, compiler_params=_TILE_PARAMS,
+    )(qkv, qkv, qkv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_qkv_tile(qkv, heads, causal, scale, launch):
+    """Self-attention ``[B, S, 3·H·Dh]`` → ``[B, S, H·Dh]`` in the one-tile
+    kernels (callers check :func:`_qkv_tile_fits`)."""
+    return _on_mesh(launch, lambda x: _qkv_tile_fwd(
+        x, heads, causal, scale, launch.interpret, with_lse=False), qkv)
+
+
+def _flash_qkv_tile_fwd(qkv, heads, causal, scale, launch):
+    out, lse = _on_mesh(launch, lambda x: _qkv_tile_fwd(
+        x, heads, causal, scale, launch.interpret, with_lse=True), qkv)
+    return out, (qkv, out, lse)
+
+
+def _qkv_tile_bwd(qkv, do, out, lse, heads, causal, scale, interpret):
+    grid, qkv_specs, col_spec, lse_spec, grad_shape = _qkv_tile_grid(qkv)
+    return _pl.pallas_call(
+        functools.partial(_qkv_tile_bwd_kernel, dh=grad_shape.shape[2] // heads,
+                          causal=causal, scale=scale),
+        out_shape=(grad_shape,) * 3, grid=grid,
+        in_specs=qkv_specs + [col_spec, col_spec, lse_spec],
+        out_specs=(col_spec,) * 3,
+        interpret=interpret, compiler_params=_TILE_PARAMS,
+    )(qkv, qkv, qkv, do, out, lse)
+
+
+def _flash_qkv_tile_bwd(heads, causal, scale, launch, res, do):
+    qkv, out, lse = res
+    grads = _on_mesh(launch, lambda *arrays: _qkv_tile_bwd(
+        *arrays, heads, causal, scale, launch.interpret), qkv, do, out, lse)
+    return (jnp.concatenate(grads, axis=-1),)
+
+
+_flash_qkv_tile.defvjp(_flash_qkv_tile_fwd, _flash_qkv_tile_bwd)
+
+
+# ---------------------------------------------------------------------------
 # Reference path (XLA-fused) + custom VJP
 # ---------------------------------------------------------------------------
 
@@ -403,93 +672,127 @@ def _pallas_blocks(sq, sk, block_q=None, block_k=None):
     return min(bq, sq), min(bk, sk)
 
 
-# Below this sequence length the XLA attention (batched matmuls + fused
-# softmax over a small S×S) beats the Pallas kernel: at S=128 the grid
-# degenerates to one K block per cell and Mosaic per-cell overhead
-# dominates (profiled on v5e @ BERT-base: 3.9 ms pallas vs ~1 ms XLA fwd).
-# The kernel's job is long context, where S×S cannot exist in HBM.
-_PALLAS_FWD_MIN_SEQ = int(os.environ.get("MXNET_TPU_FLASH_FWD_MIN_SEQ", "1024"))
+# Where the kernels take over from the XLA path: measured on a v5e
+# (tools/bench_longcontext.py; PERF.md §6, PR 29) on 8,192 tokens of twelve
+# 64-wide bf16 heads read from a fused QKV projection, one layer's forward +
+# backward in ms, head transposes included:
+#
+#        S    XLA   blockwise, blocks of 512 / 256 / 128   one tile in place
+#      128   0.40        —    /   —   / 1.85                     0.60
+#      256   0.92        —    / 1.49  / 2.59                     0.64
+#      384   1.76        —    /   —   / 3.39                     0.67
+#      512   2.41      1.32   / 2.33  / 4.31                     0.70
+#     1024   5.24      2.33   / 3.95  / 7.85                      —
+#     2048  10.15      3.94   / 7.16  / 14.89                     —
+#
+# The XLA path's float32 S×S temporaries make a round trip through HBM each,
+# so its cost grows with S for the same tokens; a kernel's does not, but its
+# fixed costs (transposes, the two-pass backward's recomputation, per-block
+# overheads) only pay from a length on, and only with blocks the MXU fills.
+# Earlier thresholds (forward from 1024, backward from 8192) dated from 128 ×
+# 128 blocks, which lose to XLA at every length above.
+_TILE_MIN_SEQ = 256      # the one-tile kernels on a fused QKV projection
+_KERNEL_MIN_SEQ = 512    # the blockwise kernels ...
+_KERNEL_MIN_BLOCK = 256  # ... where a block at least this long divides the lengths
+# ... and whatever the length, above this many bytes of float32 scores
+# ([B, H, Sq, Sk]) the XLA path's S×S temporaries (scores, probabilities and
+# their gradients, several copies) no longer fit beside a model: 32 heads at S
+# 4096 are 2 GiB a copy.  The kernels keep memory linear.
+_KERNEL_MIN_SCORE_BYTES = 1 << 30
 
 
-def _should_use_pallas(q, k, seq_axis=2):
-    """One predicate for the primal AND the VJP forward — custom_vjp needs
-    both to pick the same kernel path or eval/train numerics diverge.
-    ``seq_axis`` lets bshd-layout callers gate without materializing a
-    transpose.  Returns (use, interpret, blocks)."""
+def _kernel_path(q, k, seq_axis=2, qkv_heads=None):
+    """THE predicate: which path takes this attention call — ``"tile"`` (the
+    one-tile kernels; asked only by :func:`fused_qkv_attention`, which passes
+    its head count as ``qkv_heads``), ``"blockwise"`` or ``"xla"``?  Made
+    once a call by the dispatchers below from what the operands show —
+    lengths, head width and count, float32 score bytes, dtype, the block
+    that divides the lengths — and from the mesh the trace is for
+    (:func:`_rows_split`); the forward, the VJP forward and the
+    backward all follow it (each path is a ``custom_vjp`` of its own), so
+    evaluation and training numerics cannot part.  ``seq_axis`` lets
+    bshd-layout callers ask without materializing a transpose.
+    Returns (path, the kernels' :class:`_Launch` or None)."""
     sq, sk = q.shape[seq_axis], k.shape[seq_axis]
     use, interpret = _use_pallas(q)
     if q.dtype == jnp.float16 and not interpret:
-        use = False  # Mosaic has no f16; XLA reference path handles it
-    if use and not interpret and max(sq, sk) < _PALLAS_FWD_MIN_SEQ:
-        use = False
+        use = False  # Mosaic has no f16; the XLA path handles it
     blocks = _pallas_blocks(sq, sk) if use else None
-    return blocks is not None, interpret, blocks
+    where = _rows_split(q.shape[0]) if blocks is not None else None
+    if where is None:
+        return "xla", None
+    rows = q.size // (sq * q.shape[-1])  # batch · heads
+    # the interpreter is asked for by name, to run the kernels: whatever the length
+    forced = interpret or 4 * rows * sq * sk >= _KERNEL_MIN_SCORE_BYTES
+    if (qkv_heads is not None and (forced or sq >= _TILE_MIN_SEQ)
+            and _qkv_tile_fits(sq, qkv_heads, q.shape[-1], q.dtype, interpret)):
+        return "tile", _Launch(interpret, None, *where)
+    if forced or (max(sq, sk) >= _KERNEL_MIN_SEQ
+                  and min(blocks) >= _KERNEL_MIN_BLOCK):
+        return "blockwise", _Launch(interpret, blocks, *where)
+    return "xla", None
+
+
+def _count_dispatch(kernels):
+    """One count a traced call site: dispatch is decided at trace time, so
+    after a step has compiled the two counters say which path every
+    attention call of the program took."""
+    from .. import profiler
+
+    if kernels:
+        profiler.incr("attention_dispatch_pallas")
+    else:
+        profiler.incr("attention_dispatch_xla")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_kernels(q, k, v, causal, scale, launch):
+    """[B, H, S, D] attention in the blockwise kernels, forward and backward."""
+    b, h, s, d = q.shape
+    out = _on_mesh(
+        launch, lambda *qkv: _flash_fwd_pallas(
+            *qkv, causal, scale, launch.interpret, *launch.blocks),
+        q.reshape(b * h, s, d), k.reshape(b * h, -1, d),
+        v.reshape(b * h, -1, v.shape[-1]))
+    return out.reshape(b, h, s, v.shape[-1])
+
+
+def _flash_kernels_fwd(q, k, v, causal, scale, launch):
+    """VJP forward: also save (o, lse) so the backward runs blockwise
+    without ever materializing S×S."""
+    b, h, s, d = q.shape
+    sk, d_v = k.shape[2], v.shape[-1]
+    out, lse = _on_mesh(
+        launch, lambda *qkv: _flash_fwd_pallas(
+            *qkv, causal, scale, launch.interpret, *launch.blocks, with_lse=True),
+        q.reshape(b * h, s, d), k.reshape(b * h, sk, d), v.reshape(b * h, sk, d_v))
+    out = out.reshape(b, h, s, d_v)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_kernels_bwd(causal, scale, launch, res, do):
+    q, k, v, o, lse = res
+    b, h, s, d = q.shape
+    sk, d_v = k.shape[2], v.shape[-1]
+    dq, dk, dv = _on_mesh(
+        launch, lambda *arrays: _flash_bwd_pallas(
+            *arrays, causal, scale, launch.interpret, *launch.blocks),
+        q.reshape(b * h, s, d), k.reshape(b * h, sk, d),
+        v.reshape(b * h, sk, d_v), do.reshape(b * h, s, d_v),
+        o.reshape(b * h, s, d_v), lse)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+_flash_kernels.defvjp(_flash_kernels_fwd, _flash_kernels_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash(q, k, v, causal, scale):
-    use, interpret, blocks = _should_use_pallas(q, k)
-    if use:
-        b, h, s, d = q.shape
-        out = _flash_fwd_pallas(
-            q.reshape(b * h, s, d), k.reshape(b * h, -1, d),
-            v.reshape(b * h, -1, v.shape[-1]),
-            causal, scale, interpret, block_q=blocks[0], block_k=blocks[1],
-        )
-        return out.reshape(b, h, s, v.shape[-1])
+def _flash_xla(q, k, v, causal, scale):
     return attention_reference(q, k, v, causal, scale)
 
 
-# Below this query length the XLA backward (one fused S×S program) beats
-# the two-pass blockwise kernel, and above it the blockwise kernel wins on
-# both time and (crucially) memory — the XLA path's S×S residuals grow
-# quadratically.  Measured on v5e (bf16, causal, D=64): S=128 BERT step
-# 809 vs 913 samples/s (XLA wins), S=2048 14.9 vs 11.6 ms, S=4096 16.6 vs
-# 14.9 ms, S=8192 25.9 vs 31.1 ms (blockwise wins).
-_PALLAS_BWD_MIN_SEQ = int(os.environ.get("MXNET_TPU_FLASH_BWD_MIN_SEQ", "8192"))
-# ... and whatever the length, above this many bytes of float32 scores
-# ([B, H, Sq, Sk]) the XLA backward's S×S temporaries (scores, probabilities
-# and their gradients, several copies) no longer fit beside a model: 32 heads
-# at S 4096 are 2 GiB a copy.  The blockwise backward keeps memory linear.
-_PALLAS_BWD_MIN_SCORE_BYTES = 1 << 30
-
-
-def _flash_fwd(q, k, v, causal, scale):
-    """VJP forward: on the Pallas path, also save (o, lse) so the backward
-    can run blockwise without ever materializing S×S."""
-    use, interpret, blocks = _should_use_pallas(q, k)
-    if use:
-        b, h, s, d = q.shape
-        sk, d_v = k.shape[2], v.shape[-1]
-        with_lse = (max(s, sk) >= _PALLAS_BWD_MIN_SEQ
-                    or 4 * b * h * s * sk >= _PALLAS_BWD_MIN_SCORE_BYTES)
-        res = _flash_fwd_pallas(
-            q.reshape(b * h, s, d), k.reshape(b * h, sk, d),
-            v.reshape(b * h, sk, d_v),
-            causal, scale, interpret, block_q=blocks[0], block_k=blocks[1],
-            with_lse=with_lse)
-        if with_lse:
-            out, lse = res
-            out = out.reshape(b, h, s, d_v)
-            return out, (q, k, v, out, lse, interpret)
-        return res.reshape(b, h, s, d_v), (q, k, v, None, None, False)
-    out = attention_reference(q, k, v, causal, scale)
-    return out, (q, k, v, None, None, False)
-
-
-def _flash_bwd(causal, scale, res, do):
-    q, k, v, o, lse, interpret = res
-    if lse is not None:
-        b, h, s, d = q.shape
-        sk, d_v = k.shape[2], v.shape[-1]
-        blocks = _pallas_blocks(s, sk)
-        dq, dk, dv = _flash_bwd_pallas(
-            q.reshape(b * h, s, d), k.reshape(b * h, sk, d),
-            v.reshape(b * h, sk, d_v), do.reshape(b * h, s, d_v),
-            o.reshape(b * h, s, d_v), lse, causal, scale, interpret,
-            block_q=blocks[0], block_k=blocks[1])
-        return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
-    return _flash_bwd_xla(causal, scale, (q, k, v), do)
+def _flash_xla_fwd(q, k, v, causal, scale):
+    return attention_reference(q, k, v, causal, scale), (q, k, v)
 
 
 def _flash_bwd_xla(causal, scale, res, do):
@@ -519,14 +822,18 @@ def _flash_bwd_xla(causal, scale, res, do):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_flash_xla.defvjp(_flash_xla_fwd, _flash_bwd_xla)
 
 
 def flash_attention(q, k, v, causal=False, scale=None):
     """Fused attention on [B, H, S, D] arrays; differentiable; bf16-safe."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, causal, float(scale))
+    path, launch = _kernel_path(q, k)
+    _count_dispatch(path != "xla")
+    if path == "blockwise":
+        return _flash_kernels(q, k, v, causal, float(scale), launch)
+    return _flash_xla(q, k, v, causal, float(scale))
 
 
 # ---------------------------------------------------------------------------
@@ -648,20 +955,21 @@ _flash_bshd.defvjp(_flash_bshd_fwd, _flash_bshd_bwd)
 
 
 def _attend_bshd(q, k, v, causal, scale):
-    """Dispatch [B, S, H, Dh] attention: bshd XLA path at short sequence
-    lengths, transpose + Pallas flash kernel at long ones (where the two
-    transposes are noise against O(S²) attention)."""
+    """Dispatch [B, S, H, Dh] attention: the bshd XLA path, or transpose +
+    the blockwise kernels where ``_kernel_path`` says they win (the two
+    transposes are in the measurements it rests on).  Traced under the
+    ``attn.core`` scope, which separates attention from the projections
+    round it in a device trace."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
-    # one shared gate with the bhsd path (seq_axis=1 in this layout) so
-    # interpret-mode/f16/threshold behavior cannot drift; transposes only
-    # happen on the Pallas branch
-    use, _, _ = _should_use_pallas(q, k, seq_axis=1)
-    if use:
-        t = lambda x: x.transpose(0, 2, 1, 3)
-        out = _flash(t(q), t(k), t(v), causal, float(scale))
-        return out.transpose(0, 2, 1, 3)
-    return _flash_bshd(q, k, v, causal, float(scale))
+    path, launch = _kernel_path(q, k, seq_axis=1)
+    _count_dispatch(path != "xla")
+    with jax.named_scope("attn.core"):
+        if path == "blockwise":
+            t = lambda x: x.transpose(0, 2, 1, 3)
+            out = _flash_kernels(t(q), t(k), t(v), causal, float(scale), launch)
+            return out.transpose(0, 2, 1, 3)
+        return _flash_bshd(q, k, v, causal, float(scale))
 
 
 from .registry import register  # noqa: E402
@@ -693,13 +1001,22 @@ def fused_qkv_attention(qkv, num_heads=1, causal=False, scale=None):
     """Self-attention straight from the fused QKV projection output
     [B, S, 3·D]: the q/k/v split AND the head split are one free reshape
     ([B, S, 3, H, Dh] decomposes the projection's output columns exactly),
-    and the bshd attention core never materializes a head transpose."""
+    and neither the one-tile kernels (which read the projection in place)
+    nor the bshd XLA core materializes a head transpose."""
     b, s, d3 = qkv.shape
     h = num_heads
     d = d3 // 3
     if d % h or d3 % 3:
         raise ValueError(f"qkv dim {d3} not divisible into 3 heads×{h}")
+    if scale is None:
+        scale = 1.0 / math.sqrt(d // h)
     x = qkv.reshape(b, s, 3, h, d // h)
+    path, launch = _kernel_path(x[:, :, 0], x[:, :, 1], seq_axis=1, qkv_heads=h)
+    if path == "tile":
+        # the whole S×S tile in VMEM, the projection read where it lies
+        _count_dispatch(True)
+        with jax.named_scope("attn.core"):
+            return _flash_qkv_tile(qkv, h, causal, float(scale), launch)
     out = _attend_bshd(x[:, :, 0], x[:, :, 1], x[:, :, 2], causal, scale)
     return out.reshape(b, s, d)
 

@@ -33,7 +33,7 @@ from ..ndarray.ndarray import NDArray
 from ..random import get_key, seed_epoch, push_traced_key, pop_traced_key
 from ..gluon.block import _tls as _block_tls
 from ..gluon.parameter import ParameterDict
-from .mesh import current_mesh, local_mesh
+from .mesh import current_mesh, local_mesh, mesh_scope
 from .sharding import ShardingRules, default_rules, batch_pspec, param_sharding
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1090,7 +1090,10 @@ class SPMDTrainer:
             fold = jax.random.fold_in
             keys = (jax.vmap(fold, (None, 0))(base_key, steps) if steps.ndim
                     else fold(base_key, steps))
-            return step_fn(keys, steps.astype(jnp.float32), *rest)
+            # traced under the trainer's mesh: ops that have to place
+            # themselves on it (the attention kernels) read it there
+            with mesh_scope(self._mesh):
+                return step_fn(keys, steps.astype(jnp.float32), *rest)
 
         # the device trace names the program jit_<__name__>
         step.__name__ = step_fn.__name__

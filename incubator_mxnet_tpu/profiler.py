@@ -253,6 +253,8 @@ _counters = {
     "moe_tokens_dropped": 0,          # token-choice slots dropped at expert capacity
     "moe_rows_routed_here": 0,        # (token, choice) pairs routed to experts this chip holds
     "moe_step": 0,                    # compiled steps whose routing metrics were read
+    "attention_dispatch_pallas": 0,   # attention call sites traced onto the Pallas kernels
+    "attention_dispatch_xla": 0,      # attention call sites traced onto the XLA path
     "elastic_restart": 0,             # supervisor job re-formations
     "collective_timeout": 0,          # collective-watchdog expiries
     "snapshot_commit_ms": 0,          # two-phase run-snapshot commit wall ms

@@ -103,6 +103,160 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
+def _spec(*shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _self(heads, causal=False):
+    return lambda qkv: att.fused_qkv_attention(qkv, num_heads=heads, causal=causal)
+
+
+# what one attention call traces to: (Pallas kernels in the primal, in the VJP
+# forward, in the backward)
+XLA, TILE, BLOCKWISE = (0, 0, 0), (1, 1, 1), (1, 1, 2)
+
+DISPATCH_CASES = {
+    # BERT-base heads from a fused QKV projection: the rule of PERF.md §6, PR 29
+    "s128": (_self(12), [_spec(2, 128, 2304)], XLA),
+    "s256": (_self(12), [_spec(2, 256, 2304)], TILE),
+    "s384": (_self(12), [_spec(2, 384, 2304)], TILE),
+    "s512": (_self(12), [_spec(2, 512, 2304)], TILE),
+    "s512-causal": (_self(12, True), [_spec(2, 512, 2304)], TILE),
+    "s512-float32": (_self(12), [_spec(2, 512, 2304, dtype=jnp.float32)], TILE),
+    "s1024": (_self(12), [_spec(2, 1024, 2304)], BLOCKWISE),
+    # three 64-wide heads do not fill 128-lane columns: transposes + blockwise,
+    # from S 512 and where a block of 256 or more divides the length
+    "s512-odd-heads": (_self(3), [_spec(2, 512, 576)], BLOCKWISE),
+    "s768-odd-heads": (_self(3), [_spec(2, 768, 576)], BLOCKWISE),
+    "s256-odd-heads": (_self(3), [_spec(2, 256, 576)], XLA),
+    "s640-odd-heads-block-128": (_self(3), [_spec(2, 640, 576)], XLA),
+    "s512-float16": (_self(12), [_spec(2, 512, 2304, dtype=jnp.float16)], XLA),
+    # 500 = 4 · 125: no block divides it; 264 = 8 · 33: only an 8-wide one
+    "s500-no-block": (_self(12), [_spec(2, 500, 2304)], XLA),
+    "s264-narrow-block": (_self(12), [_spec(2, 264, 2304)], XLA),
+    # S 128 whose float32 scores are 1.03 GiB: the kernels, for the memory
+    "s128-gigabyte-of-scores": (_self(12), [_spec(1400, 128, 2304)], TILE),
+    "cross-256x512": (lambda q, kv: att.fused_kv_attention(q, kv, num_heads=2),
+                      [_spec(2, 256, 128), _spec(2, 512, 256)], BLOCKWISE),
+    "cross-one-query": (lambda q, kv: att.fused_kv_attention(q, kv, num_heads=2),
+                        [_spec(2, 1, 128), _spec(2, 512, 256)], XLA),
+    # latent attention's widths: 192-wide queries and keys, 128-wide values
+    "values-narrower-than-keys": (
+        lambda q, k, v: att.fused_attention(q, k, v, num_heads=2, causal=True),
+        [_spec(1, 512, 384), _spec(1, 512, 384), _spec(1, 512, 256)], BLOCKWISE),
+    "bhsd-s128": (lambda q: att.flash_attention(q, q, q), [_spec(2, 4, 128, 64)], XLA),
+    "bhsd-s2048": (lambda q: att.flash_attention(q, q, q, causal=True),
+                   [_spec(1, 4, 2048, 64)], BLOCKWISE),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH_CASES))
+def test_attention_dispatch_by_shape(case, monkeypatch):
+    """``_kernel_path`` by shape: the primal, the VJP forward and the backward
+    of a call take the same path, and the counters say which.  Traced with
+    the kernels ``on`` (nothing is lowered, so the CPU can)."""
+    from incubator_mxnet_tpu import profiler
+
+    fn, specs, (primal, vjp_forward, backward) = DISPATCH_CASES[case]
+    monkeypatch.setenv("MXNET_TPU_FLASH", "on")
+
+    def kernels(f):
+        return str(jax.make_jaxpr(f)(*specs)).count("pallas_call")
+
+    before = profiler.counters()
+    assert kernels(fn) == primal
+    after = profiler.counters()
+    counted = {name: after[name] - before[name]
+               for name in ("attention_dispatch_pallas", "attention_dispatch_xla")}
+    assert counted == {"attention_dispatch_pallas": int(primal > 0),
+                       "attention_dispatch_xla": int(primal == 0)}
+    assert kernels(lambda *a: jax.vjp(fn, *a)[0]) == vjp_forward
+    loss = lambda *a: fn(*a).astype(jnp.float32).sum()
+    assert kernels(jax.grad(loss, argnums=tuple(range(len(specs))))) \
+        == vjp_forward + backward
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 4e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_one_tile_kernels_at_s512_match_reference(causal, dtype, tol, monkeypatch):
+    """S 512, two 64-wide heads in one 128-lane column of the fused QKV
+    projection, through ``fused_qkv_attention`` (interpreter): forward and
+    gradient against ``attention_reference`` in float32."""
+    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
+    b, s, h, dh = 1, 512, 2, 64
+    assert att._qkv_tile_fits(s, h, dh, dtype)
+    keys = jax.random.split(jax.random.PRNGKey(29), 2)
+    qkv = jax.random.normal(keys[0], (b, s, 3 * h * dh), dtype)
+    weights = jax.random.normal(keys[1], (b, s, h * dh), jnp.float32)
+
+    def plain(x):
+        x = x.astype(jnp.float32).reshape(b, s, 3, h, dh).transpose(2, 0, 3, 1, 4)
+        out = att.attention_reference(x[0], x[1], x[2], causal=causal)
+        return out.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
+
+    system = lambda x: att.fused_qkv_attention(x, num_heads=h, causal=causal)
+    out = system(qkv)
+    assert out.dtype == dtype and out.shape == (b, s, h * dh)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(plain(qkv)),
+                               atol=tol)
+    got = jax.grad(lambda x: (system(x).astype(jnp.float32) * weights).sum())(qkv)
+    want = jax.grad(lambda x: (plain(x) * weights).sum())(qkv)
+    assert got.dtype == dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol)
+
+
+@pytest.mark.parametrize("axes,kernels", [({"dp": 4}, True), ({"dp": 2, "fsdp": 2}, True),
+                                          ({"dp": 2, "tp": 2}, False), ({"dp": 3}, False)],
+                         ids=["dp4", "dp2-fsdp2", "dp2-tp2", "dp3-of-batch-4"])
+@pytest.mark.parametrize("path,shape", [("tile", (4, 128, 3 * 2 * 64)),
+                                        ("blockwise", (4, 64, 3 * 2 * 16))])
+def test_kernels_are_placed_on_the_mesh_of_the_trace(path, shape, axes, kernels, monkeypatch):
+    """No compiler partitions a Mosaic kernel, so under ``mesh_scope`` (which
+    ``SPMDTrainer`` opens round a step's trace) the dispatcher launches the
+    kernels in a ``shard_map`` over the batch axes — the gradient equals the
+    one-device one, stays split by rows, and no chip gathers another's — and
+    leaves a mesh that splits the model, or a batch it does not divide, to
+    the XLA path."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from incubator_mxnet_tpu.parallel import make_mesh, mesh_scope
+
+    n = int(np.prod(list(axes.values())))
+    if len(jax.devices()) < n:
+        pytest.skip(f"needs {n} devices")
+    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
+    mesh = make_mesh(devices=jax.devices()[:n], **axes)
+    x = jax.random.normal(jax.random.PRNGKey(0), shape, jnp.float32)
+
+    def attend(x):
+        return att.fused_qkv_attention(x, num_heads=2, causal=True)
+
+    def loss(x):
+        return jnp.sum(jnp.square(attend(x)))
+
+    want = jax.grad(loss)(x)
+    rows = NamedSharding(mesh, P(("dp", "fsdp")) if kernels else P())
+
+    def on_mesh(fn):
+        def scoped(x):
+            with mesh_scope(mesh):
+                return fn(x)
+        return jax.jit(scoped, out_shardings=rows)
+
+    q = x.reshape(shape[0], shape[1], 3, 2, -1)[:, :, 0]
+    with mesh_scope(mesh):
+        assert att._kernel_path(q, q, seq_axis=1, qkv_heads=2)[0] == (path if kernels else "xla")
+    split = jax.device_put(x, rows)
+    traced = str(jax.make_jaxpr(on_mesh(attend))(split))
+    assert traced.count("shard_map") == traced.count("pallas_call") == int(kernels)
+    got = on_mesh(jax.grad(loss))(split)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5)
+    if kernels:
+        assert "all-gather" not in on_mesh(jax.grad(loss)).lower(split).compile().as_text()
+
+
 class TestTransformerLayers:
     def test_encoder_cell_shapes_and_grad(self):
         mx.random.seed(0)

@@ -246,15 +246,15 @@ def _plain_attention(q, k, v, scale):
     return t(attn_ops.attention_reference(t(q), t(k), t(v), True, scale))
 
 
-@pytest.mark.parametrize("path", ["xla", "pallas-forward", "pallas-both"])
+@pytest.mark.parametrize("path", ["xla", "pallas-one-block", "pallas-2x2-blocks"])
 def test_attention_dispatcher_takes_values_narrower_than_keys(path, monkeypatch):
-    """d_qk 24, d_v 16 through ``_attend_bshd``: the XLA path, the Pallas
-    forward with the XLA backward, and the Pallas forward and backward
-    (interpreter), against ``attention_reference``, forward and backward."""
+    """d_qk 24, d_v 16 through ``_attend_bshd``: the XLA path, and the Pallas
+    forward and backward (interpreter) with the 256 rows in one block and in
+    2 x 2 blocks of 128, against ``attention_reference``, forward and backward."""
     monkeypatch.setenv("MXNET_TPU_FLASH", "off" if path == "xla" else "interpret")
-    monkeypatch.setattr(attn_ops, "_PALLAS_BWD_MIN_SEQ",
-                        0 if path == "pallas-both" else 1 << 30)
-    monkeypatch.setattr(attn_ops, "_PALLAS_BWD_MIN_SCORE_BYTES", 1 << 60)
+    if path == "pallas-2x2-blocks":
+        monkeypatch.setattr(attn_ops, "_PALLAS_BLOCK_Q", 128)
+        monkeypatch.setattr(attn_ops, "_PALLAS_BLOCK_K", 128)
     q, k, v = _qkv()
     scale = 0.2
     weights = jax.random.normal(jax.random.PRNGKey(7), (1, 256, 2, 16))
@@ -264,6 +264,9 @@ def test_attention_dispatcher_takes_values_narrower_than_keys(path, monkeypatch)
 
     system = lambda q, k, v: attn_ops._attend_bshd(q, k, v, True, scale)
     plain = lambda q, k, v: _plain_attention(q, k, v, scale)
+    kernels = str(jax.make_jaxpr(jax.grad(through(system), argnums=(0, 1, 2)))(q, k, v)
+                  ).count("pallas_call")
+    assert kernels == (0 if path == "xla" else 3)  # forward, dq pass, dk/dv pass
     out = system(q, k, v)
     assert out.shape == (1, 256, 2, 16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(plain(q, k, v)), atol=2e-5)
@@ -274,18 +277,24 @@ def test_attention_dispatcher_takes_values_narrower_than_keys(path, monkeypatch)
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
 
 
-def test_large_scores_take_the_blockwise_backward(monkeypatch):
-    """Above ``_PALLAS_BWD_MIN_SCORE_BYTES`` of float32 scores the forward
-    saves the log-sum-exp for the blockwise backward, whatever the length."""
-    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
-    monkeypatch.setattr(attn_ops, "_PALLAS_BWD_MIN_SCORE_BYTES", 4 * 2 * 256 * 256)
-    q, k, v = _qkv()
-    t = lambda x: x.transpose(0, 2, 1, 3)
-    _, saved = attn_ops._flash_fwd(t(q), t(k), t(v), True, 0.2)
-    assert saved[4] is not None and saved[4].shape == (2, 256, 128)
-    monkeypatch.setattr(attn_ops, "_PALLAS_BWD_MIN_SCORE_BYTES", 1 << 60)
-    _, saved = attn_ops._flash_fwd(t(q), t(k), t(v), True, 0.2)
-    assert saved[4] is None
+def test_large_scores_take_the_blockwise_kernels(monkeypatch):
+    """Above ``_KERNEL_MIN_SCORE_BYTES`` of float32 scores the kernels are
+    taken, forward and backward, whatever the length: S 128 here, which the
+    crossover alone leaves on the XLA path."""
+    monkeypatch.setenv("MXNET_TPU_FLASH", "on")  # traced only, nothing lowered
+    q, k, v = _qkv(s=128)
+
+    def kernels():  # a new function each time: a trace is cached by its function
+        grad = jax.grad(lambda q, k, v: attn_ops._attend_bshd(q, k, v, True, 0.2).sum(),
+                        argnums=(0, 1, 2))
+        return str(jax.make_jaxpr(grad)(q, k, v)).count("pallas_call")
+
+    assert attn_ops._kernel_path(q, k, seq_axis=1) == ("xla", None)
+    assert kernels() == 0
+    monkeypatch.setattr(attn_ops, "_KERNEL_MIN_SCORE_BYTES", 4 * 2 * 128 * 128)
+    assert attn_ops._kernel_path(q, k, seq_axis=1) == (
+        "blockwise", attn_ops._Launch(False, (128, 128)))
+    assert kernels() == 3  # forward, dq pass, dk/dv pass
 
 
 def test_fused_attention_merges_heads_at_the_values_width():

@@ -144,19 +144,15 @@ class TestFlashOnChip:
         import jax.numpy as jnp
         from incubator_mxnet_tpu.ops import attention as att
 
-        monkeypatch.setenv("MXNET_TPU_FLASH_BWD_MIN_SEQ", "512")
-        monkeypatch.setenv("MXNET_TPU_FLASH_FWD_MIN_SEQ", "512")
-        # thresholds are read at import; reload-free override via direct attr
-        monkeypatch.setattr(att, "_PALLAS_BWD_MIN_SEQ", 512)
-        monkeypatch.setattr(att, "_PALLAS_FWD_MIN_SEQ", 512)
+        # S 512 takes the blockwise kernels, forward and backward, by the
+        # dispatcher's own rule
         q = jnp.asarray(_r(1, 1, 512, 64)).astype(jnp.bfloat16)
+        assert att._kernel_path(q, q) == ("blockwise", att._Launch(False, (512, 512)))
 
         def loss_flash(x):
-            monkeypatch.setenv("MXNET_TPU_FLASH", "on")
             return (att.flash_attention(x, x, x, causal=True) ** 2).sum().astype(jnp.float32)
 
         g_flash = jax.grad(loss_flash)(q)
-        monkeypatch.setenv("MXNET_TPU_FLASH", "off")
 
         def loss_ref(x):
             return (att.attention_reference(x, x, x, causal=True) ** 2).sum().astype(jnp.float32)
@@ -164,6 +160,42 @@ class TestFlashOnChip:
         g_ref = jax.grad(loss_ref)(q)
         np.testing.assert_allclose(
             np.asarray(g_flash, dtype=np.float32), np.asarray(g_ref, dtype=np.float32),
+            rtol=5e-2, atol=5e-2)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_one_tile_kernels_match_float32_reference_on_tpu(self, causal):
+        """BERT-base's heads at S 512 through ``fused_qkv_attention``: the
+        dispatcher takes the one-tile kernels by itself (two Mosaic calls in
+        the gradient's lowering: forward, backward); forward and gradient
+        against the float32 reference at bf16 tolerances."""
+        import jax
+        import jax.numpy as jnp
+        from incubator_mxnet_tpu.ops import attention as att
+
+        b, s, h, dh = 2, 512, 12, 64
+        qkv32 = jnp.asarray(_r(b, s, 3 * h * dh))
+        weights = jnp.asarray(_r(b, s, h * dh))
+        qkv = qkv32.astype(jnp.bfloat16)
+
+        def system(x):
+            return att.fused_qkv_attention(x, num_heads=h, causal=causal)
+
+        def plain(x):
+            x = x.astype(jnp.float32).reshape(b, s, 3, h, dh).transpose(2, 0, 3, 1, 4)
+            out = att.attention_reference(x[0], x[1], x[2], causal=causal)
+            return out.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
+
+        def loss(fn):
+            return lambda x: (fn(x).astype(jnp.float32) * weights).sum()
+
+        grad = jax.jit(jax.grad(loss(system)))
+        assert grad.lower(qkv).as_text().count("tpu_custom_call") == 2
+        np.testing.assert_allclose(
+            np.asarray(jax.jit(system)(qkv), dtype=np.float32),
+            np.asarray(plain(qkv)), rtol=2e-2, atol=2e-2)
+        np.testing.assert_allclose(
+            np.asarray(grad(qkv), dtype=np.float32),
+            np.asarray(jax.grad(loss(plain))(qkv), dtype=np.float32),
             rtol=5e-2, atol=5e-2)
 
 
