@@ -254,26 +254,9 @@ def bench_resnet50(run):
 
 
 def _run_spmd(trainer, inputs, labels, warmup, steps):
-    """Time `steps` optimizer steps.  MXNET_TPU_BENCH_BULK=k (default 1)
-    dispatches k steps per device call via SPMDTrainer.step_bulk — the
-    engine-bulking analog; use for dispatch-bound tiny models (MNIST)
-    where the host dispatch, not the chip, is the bottleneck."""
+    """Time `steps` optimizer steps, fenced at both ends."""
     import time as _t
 
-    bulk = int(os.environ.get("MXNET_TPU_BENCH_BULK", "1"))
-    if bulk > 1:
-        n = max(1, steps // bulk)          # dispatches; actual steps = n*bulk
-        for _ in range(max(1, warmup // bulk)):
-            loss = trainer.step_bulk(inputs, labels, bulk)
-        _fence(trainer, loss)
-        t0 = _t.perf_counter()
-        for _ in range(n):
-            loss = trainer.step_bulk(inputs, labels, bulk)
-        _fence(trainer, loss)
-        dt = _t.perf_counter() - t0
-        # normalize so the caller's `B*steps/dt` reflects the true rate
-        # even when bulk does not divide steps
-        return dt * steps / (n * bulk)
     for _ in range(warmup):
         loss = trainer.step(inputs, labels)
     _fence(trainer, loss)

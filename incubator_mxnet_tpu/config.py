@@ -30,7 +30,6 @@ MXNET_TPU_FLASH                   flash-attention dispatch (ops/attention.py):
                                   (compiled wherever the call lands) choose
                                   by shape at the measured crossover, which
                                   has no override; ``off``; ``interpret``
-MXNET_TPU_FAST_DROPOUT            u8-mask dropout RNG (ops/nn.py)
 MXNET_TPU_MATMUL_PRECISION        fp32 matmul precision (package __init__)
 MXNET_TPU_PRNG                    PRNG impl: ``rbg`` (default — hardware
                                   RNG, +11% BERT step, PERF_NOTES) or
@@ -163,9 +162,8 @@ def describe():
                 "MXNET_GPU_MEM_POOL_TYPE", "MXNET_CPU_WORKER_NTHREADS",
                 "MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN",
                 "MXNET_PROFILER_AUTOSTART", "MXNET_ENFORCE_DETERMINISM",
-                "MXNET_TPU_FLASH", "MXNET_TPU_FAST_DROPOUT",
-                "MXNET_TPU_MATMUL_PRECISION", "MXNET_TPU_PRNG",
-                "MXNET_TEST_CTX"):
+                "MXNET_TPU_FLASH", "MXNET_TPU_MATMUL_PRECISION",
+                "MXNET_TPU_PRNG", "MXNET_TEST_CTX"):
         rows.append((var, os.environ.get(var, "<unset>"),
                      _APPLIED.get(var, "")))
     width = max(len(r[0]) for r in rows) + 2

@@ -86,23 +86,7 @@ class MultiHeadAttention(HybridBlock):
 
 
 class PositionwiseFFN(HybridBlock):
-    """FFN sublayer: Dense→act→(dropout)→Dense (one MXU GEMM each).
-
-    Optional rematerialization under jit tracing (SPMDTrainer / hybridize,
-    no imperative tape), selected by ``MXNET_TPU_REMAT_FFN``:
-
-    * ``none`` (DEFAULT): no checkpoint.  Measured on-chip (BERT-base
-      B=64 S=128) every checkpoint variant lost ~10% end-to-end — the
-      boundary breaks XLA's cross-sublayer fusion — so remat is opt-in.
-    * ``policy``: ``jax.checkpoint`` saving ONLY the pre-activation
-      (recomputes the ALU-cheap activation in backward, halving the
-      [B, S, hidden] activation-pair HBM round-trip; the ffn_1 matmul is
-      NOT recomputed).
-    * ``drop_pre_act``: the complementary policy (saves everything except
-      the pre-activation) — an A/B knob.
-    * ``full``: recompute the whole sublayer in backward (long-context
-      memory mode: trades an extra GEMM for linear-in-S residency).
-    """
+    """FFN sublayer: Dense→act→(dropout)→Dense (one MXU GEMM each)."""
 
     def __init__(self, units, hidden_size, activation="gelu", dropout=0.0,
                  dtype="float32", prefix=None, params=None):
@@ -115,13 +99,10 @@ class PositionwiseFFN(HybridBlock):
         if self._dropout is not None:
             self.register_child(self._dropout, "dropout")
 
-    def _body(self, x, mark=None):
+    def forward(self, x):
         from ... import ndarray as F
-        from ...ndarray.ndarray import NDArray
 
         h = self.ffn_1(x)
-        if mark is not None:
-            h = NDArray(mark(h._data))
         if self._activation == "gelu":
             h = F.LeakyReLU(h, act_type="gelu")
         else:
@@ -129,39 +110,6 @@ class PositionwiseFFN(HybridBlock):
         if self._dropout is not None:
             h = self._dropout(h)
         return self.ffn_2(h)
-
-    def forward(self, x):
-        import os
-
-        # default "none": measured on-chip (BERT-base B=64 S=128) the
-        # checkpoint boundary cost ~10% throughput — XLA loses cross-
-        # sublayer fusion — outweighing the 1.2 GB/step of saved
-        # activation writes at this scale.  "policy"/"full" remain for
-        # long-context configs where residency, not bandwidth, binds.
-        mode = os.environ.get("MXNET_TPU_REMAT_FFN", "none")
-        if mode not in ("none", "0"):
-            import jax
-            from jax.ad_checkpoint import checkpoint_name
-
-            from ... import autograd
-            from ...ndarray.ndarray import NDArray
-
-            if isinstance(x._data, jax.core.Tracer) and not autograd.is_recording():
-                if mode == "full":
-                    ckpt = jax.checkpoint(
-                        lambda xd: self._body(NDArray(xd))._data)
-                else:
-                    ckpt = jax.checkpoint(
-                        lambda xd: self._body(
-                            NDArray(xd),
-                            mark=lambda h: checkpoint_name(h, "ffn_pre_act"),
-                        )._data,
-                        policy=jax.checkpoint_policies.save_anything_except_these_names(
-                            "ffn_pre_act") if mode == "drop_pre_act" else
-                        jax.checkpoint_policies.save_only_these_names("ffn_pre_act"),
-                    )
-                return NDArray(ckpt(x._data))
-        return self._body(x)
 
 
 class TransformerEncoderCell(HybridBlock):

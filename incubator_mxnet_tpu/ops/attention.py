@@ -849,24 +849,6 @@ def _causal_mask(s):
     return jnp.where(mask, s, -jnp.inf)
 
 
-# Score-tensor layout for the bshd XLA path: 'bhqk' (default — heads on
-# the major axes) or 'bqhk' (heads inboard; an A/B candidate for the
-# profiled head-split relayout copies on TPU — numerically identical,
-# pinned by test).  Fixed at import; ONE code path parameterized by the
-# einsum subscript so the math cannot diverge between layouts.
-_SL = ("bqhk" if os.environ.get("MXNET_TPU_ATTN_SCORE_LAYOUT", "bhqk")
-       == "bqhk" else "bhqk")
-
-
-def _causal_mask_bqhk(s):
-    sq, sk = s.shape[1], s.shape[-1]
-    mask = (jnp.arange(sq)[:, None, None] >= jnp.arange(sk)[None, None, :])
-    return jnp.where(mask, s, -jnp.inf)
-
-
-_SCORE_MASK = _causal_mask_bqhk if _SL == "bqhk" else _causal_mask
-
-
 def attention_reference_bshd(q, k, v, causal=False, scale=None):
     """Plain jnp attention over [B, S, H, Dh] operands (head axis stays in
     place; same fp32-accumulate / fp32-softmax policy as
@@ -875,26 +857,14 @@ def attention_reference_bshd(q, k, v, causal=False, scale=None):
         scale = 1.0 / math.sqrt(q.shape[-1])
     prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
-    s = jnp.einsum(f"bqhd,bkhd->{_SL}", q, k,
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32, precision=prec) * scale
     if causal:
-        s = _SCORE_MASK(s)
+        s = _causal_mask(s)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum(f"{_SL},bkhd->bqhd", p.astype(v.dtype), v,
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32,
                       precision=prec).astype(v.dtype)
-
-
-# Probs-saving backward: below this many elements in the [B, H, Sq, Sk]
-# score tensor, the fwd saves bf16 probabilities and the backward reuses
-# them instead of recomputing scores+softmax.  Default 0 = ALWAYS
-# rematerialize: measured on-chip (BERT-base B=64 S=128) saving probs
-# LOST ~3% end-to-end (1367 vs 1407 samples/s) — the saved tensor's
-# write+read broke XLA's fusion of the recompute into the backward
-# matmuls, costing more than the recompute it avoided.  The knob stays
-# for configs where the trade flips.
-_SAVE_PROBS_MAX_ELEMS = int(os.environ.get(
-    "MXNET_TPU_ATTN_SAVE_PROBS_MAX_ELEMS", "0"))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -902,52 +872,33 @@ def _flash_bshd(q, k, v, causal, scale):
     return attention_reference_bshd(q, k, v, causal, scale)
 
 
-def _save_probs(q, k):
-    b, sq, h, _ = q.shape
-    return b * h * sq * k.shape[1] <= _SAVE_PROBS_MAX_ELEMS
-
-
 def _flash_bshd_fwd(q, k, v, causal, scale):
-    if not _save_probs(q, k):
-        return attention_reference_bshd(q, k, v, causal, scale), (q, k, v, None)
-    prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
-    mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32,
-                           precision=prec)
-    s = mm(f"bqhd,bkhd->{_SL}", q, k) * scale
-    if causal:
-        s = _SCORE_MASK(s)
-    pc = jax.nn.softmax(s, axis=-1).astype(v.dtype)  # bf16 probs, saved
-    o = mm(f"{_SL},bkhd->bqhd", pc, v).astype(v.dtype)
-    return o, (q, k, v, pc)
+    return attention_reference_bshd(q, k, v, causal, scale), (q, k, v)
 
 
 def _flash_bshd_bwd(causal, scale, res, do):
-    """bshd attention backward.  With saved probs (short seq): classic
-    gradient algebra, delta via the flash identity rowsum(dp∘p) — no
-    recompute, no fp32 S×S round-trips, and ``o`` need not be saved.
-    Without (long seq): rematerialize, the bshd twin of
-    :func:`_flash_bwd_xla`."""
-    q, k, v, pc = res
+    """bshd attention backward: rematerialize scores and softmax, the bshd
+    twin of :func:`_flash_bwd_xla`.  (Saving bf16 probabilities instead
+    LOST ~3 % end to end on BERT-base B=64 S=128: the saved tensor's
+    write and read broke XLA's fusion of the recompute into the backward
+    matmuls, docs/PERF_NOTES.md.)"""
+    q, k, v = res
     prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
     mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32,
                            precision=prec)
-    if pc is None:
-        s = mm(f"bqhd,bkhd->{_SL}", q, k) * scale
-        if causal:
-            s = _SCORE_MASK(s)
-        p = jax.nn.softmax(s, axis=-1)               # fp32, _SL layout
-        pc = p.astype(v.dtype)
-    else:
-        p = pc
-    dv = mm(f"{_SL},bqhd->bkhd", pc, do)
-    dp = mm(f"bqhd,bkhd->{_SL}", do, v)
+    s = mm("bqhd,bkhd->bhqk", q, k) * scale
+    if causal:
+        s = _causal_mask(s)
+    p = jax.nn.softmax(s, axis=-1)                   # fp32 [B, H, Sq, Sk]
+    pc = p.astype(v.dtype)
+    dv = mm("bhqk,bqhd->bkhd", pc, do)
+    dp = mm("bqhd,bkhd->bhqk", do, v)
     # delta_q = Σ_k dp∘p  (== Σ_d do∘o, the flash identity — saves o)
     delta = jnp.sum(dp * p, axis=-1, keepdims=True)
     ds = (p * (dp - delta)).astype(q.dtype)
-    dq = mm(f"{_SL},bkhd->bqhd", ds, k) * scale
-    dk = mm(f"{_SL},bqhd->bkhd", ds, q) * scale
+    dq = mm("bhqk,bkhd->bqhd", ds, k) * scale
+    dk = mm("bhqk,bqhd->bkhd", ds, q) * scale
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

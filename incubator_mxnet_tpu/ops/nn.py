@@ -277,7 +277,12 @@ def batch_norm(
     return out.astype(data.dtype), mean, var
 
 
-def _layer_norm_ref(data, gamma, beta, axis, eps):
+@register("LayerNorm")
+def layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
+    """Parity: [U:src/operator/nn/layer_norm.cc].  fp32 statistics with the
+    output in the input dtype: under bf16 AMP the activations never leave
+    bf16 at the op boundary (the internal converts fuse into the reduction
+    and the normalize loop — no materialized cast copies)."""
     x32 = data.astype(jnp.float32)
     mean = jnp.mean(x32, axis=axis, keepdims=True)
     # one-pass stats: see batch_norm's E[x²]−E[x]² note
@@ -289,68 +294,6 @@ def _layer_norm_ref(data, gamma, beta, axis, eps):
     out = (out * gamma.astype(jnp.float32).reshape(bshape)
            + beta.astype(jnp.float32).reshape(bshape))
     return out.astype(data.dtype)
-
-
-@functools.lru_cache(maxsize=None)
-def _make_ln_custom_bwd(eps, g_dtype, b_dtype):
-    """Hand-written LayerNorm VJP (axis=-1): saves x̂ in the INPUT dtype
-    and expresses backward in the closed form
-    ``dx = inv·(dŷ − mean(dŷ) − x̂·mean(dŷ·x̂))`` — an A/B lever for the
-    profiled lane-dimension convert_reduce cost in the BERT/transformer
-    backward (docs/PERF_NOTES.md); enabled by MXNET_TPU_LN_CUSTOM_BWD=1."""
-
-    @jax.custom_vjp
-    def f(x, gamma, beta):
-        return _layer_norm_ref(x, gamma, beta, -1, eps)
-
-    def fwd(x, gamma, beta):
-        x32 = x.astype(jnp.float32)
-        mean = jnp.mean(x32, axis=-1, keepdims=True)
-        var = jnp.maximum(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-                          - jnp.square(mean), 0.0)
-        inv = lax.rsqrt(var + eps)
-        xhat = (x32 - mean) * inv
-        g32 = gamma.astype(jnp.float32)
-        out = (xhat * g32 + beta.astype(jnp.float32)).astype(x.dtype)
-        # x̂ saved in the compute dtype (bf16 under AMP): halves the
-        # residual's HBM footprint vs saving x+mean+var in fp32
-        return out, (xhat.astype(x.dtype), inv, g32)
-
-    def bwd(res, dy):
-        xhat_c, inv, g32 = res
-        xdtype = xhat_c.dtype  # == the input dtype by construction
-        xhat = xhat_c.astype(jnp.float32)
-        dyg = dy.astype(jnp.float32) * g32
-        m1 = jnp.mean(dyg, axis=-1, keepdims=True)
-        m2 = jnp.mean(dyg * xhat, axis=-1, keepdims=True)
-        dx = (inv * (dyg - m1 - xhat * m2)).astype(xdtype)
-        batch_axes = tuple(range(dy.ndim - 1))
-        dy32 = dy.astype(jnp.float32)
-        # grads must come back in the PRIMAL dtypes or the knob changes
-        # grad-buffer dtypes (an A/B artifact, not a kernel effect)
-        dgamma = jnp.sum(dy32 * xhat, axis=batch_axes).astype(g_dtype)
-        dbeta = jnp.sum(dy32, axis=batch_axes).astype(b_dtype)
-        return dx, dgamma, dbeta
-
-    f.defvjp(fwd, bwd)
-    return f
-
-
-@register("LayerNorm")
-def layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
-    """Parity: [U:src/operator/nn/layer_norm.cc].  fp32 statistics with the
-    output in the input dtype: under bf16 AMP the activations never leave
-    bf16 at the op boundary (the internal converts fuse into the reduction
-    and the normalize loop — no materialized cast copies).
-
-    ``MXNET_TPU_LN_CUSTOM_BWD=1`` switches axis=-1 calls to a hand-written
-    VJP (see ``_make_ln_custom_bwd``) — an on-chip A/B knob; default off."""
-    ax = axis % data.ndim
-    if (os.environ.get("MXNET_TPU_LN_CUSTOM_BWD") == "1"
-            and ax == data.ndim - 1):
-        return _make_ln_custom_bwd(float(eps), jnp.dtype(gamma.dtype).name,
-                                   jnp.dtype(beta.dtype).name)(data, gamma, beta)
-    return _layer_norm_ref(data, gamma, beta, axis, eps)
 
 
 @register("GroupNorm")
@@ -631,22 +574,17 @@ def dropout(data, p=0.5, mode="training", axes=(), key=None, training=None):
         for ax in axes:
             shape[ax] = 1
     keep = 1.0 - p
-    import os as _os
-
-    if _os.environ.get("MXNET_TPU_FAST_DROPOUT", "1") == "1":
-        # 8-bit mask draw: 4× fewer threefry blocks than bernoulli's
-        # uint32-per-element (dropout RNG was 12% of the BERT step —
-        # docs/PERF_NOTES.md).  keep is quantized to n/256 (≤1/512 absolute
-        # error); the rescale uses the quantized keep, so E[out] == data
-        # exactly.  MXNET_TPU_FAST_DROPOUT=0 restores exact-probability
-        # bernoulli — NOTE the flag is read at TRACE time: flipping it
-        # after a hybridize/jit cache is built requires
-        # base.invalidate_jit_caches() (as amp.init does) to take effect.
-        thresh = int(round(keep * 256))
-        if 0 < thresh < 256:
-            bits = jax.random.bits(key, tuple(shape), dtype=jnp.uint8)
-            mask = (bits < thresh).astype(data.dtype)
-            return data * mask * (256.0 / thresh)
+    # 8-bit mask draw: 4× fewer threefry blocks than bernoulli's
+    # uint32-per-element (dropout RNG was 12% of the BERT step —
+    # docs/PERF_NOTES.md).  keep is quantized to n/256 (≤1/512 absolute
+    # error); the rescale uses the quantized keep, so E[out] == data
+    # exactly.  A keep that rounds to 0 or 256 draws exact-probability
+    # bernoulli.
+    thresh = int(round(keep * 256))
+    if 0 < thresh < 256:
+        bits = jax.random.bits(key, tuple(shape), dtype=jnp.uint8)
+        mask = (bits < thresh).astype(data.dtype)
+        return data * mask * (256.0 / thresh)
     mask = jax.random.bernoulli(key, keep, tuple(shape)).astype(data.dtype)
     return data * mask / keep
 

@@ -18,7 +18,6 @@ use — same Block, same loss, same Optimizer subclass.
 """
 from __future__ import annotations
 
-import contextlib
 from time import perf_counter as _perf
 
 import jax
@@ -210,7 +209,6 @@ class SPMDTrainer:
         mesh=None,
         rules: ShardingRules | None = None,
         sp_axis: int | None = None,
-        donate: bool = True,
         stages=None,
         pipeline=None,
         compression=None,
@@ -224,7 +222,6 @@ class SPMDTrainer:
         self._mesh = mesh or current_mesh() or local_mesh()
         self._rules = rules or default_rules()
         self._sp_axis = sp_axis
-        self._donate = donate
 
         params = block.collect_params()
         if isinstance(params, (dict, ParameterDict)):
@@ -274,9 +271,6 @@ class SPMDTrainer:
         self._base_key = None
         self._key_epoch = None
         self._step_cache = {}
-        self._window_k = None       # step_window's steady width (first
-                                    # width seen; shorter tails are
-                                    # declared-warmup programs)
         self._guard_armed = False   # steady-state compile guard armed after
                                     # the first compiled step completes
         # device-memory ledger: the trainer owns its donated param/state
@@ -660,7 +654,7 @@ class SPMDTrainer:
             sig[f"input{i}"] = _profiler.sig_array(a)
         return sig
 
-    def _record_step_obs(self, extras, tw, k=1):
+    def _record_step_obs(self, extras, tw):
         """Host-side pipeline/MoE observability for one dispatched step:
         declared counters (always on, like every repo counter), the
         ``pipeline.step``/``pipeline.stage``/``moe.step`` trace spans, and
@@ -678,14 +672,14 @@ class SPMDTrainer:
             # opt-out groups' fp32)
             from ..comm import compression as comp_mod
 
-            comp_mod.account(self._comm_cfg["bytes_raw"] * k,
-                             self._comm_cfg["bytes_wire"] * k)
+            comp_mod.account(self._comm_cfg["bytes_raw"],
+                             self._comm_cfg["bytes_wire"])
             if self._comm_cfg["hops"]:
-                _profiler.incr("comms_ring_hops", self._comm_cfg["hops"] * k)
+                _profiler.incr("comms_ring_hops", self._comm_cfg["hops"])
         if self._stages is not None:
             sim = self._pipe_sim
-            _profiler.incr("pipeline_step", k)
-            _profiler.incr("pipeline_microbatch", self._pipe_micro * k)
+            _profiler.incr("pipeline_step")
+            _profiler.incr("pipeline_microbatch", self._pipe_micro)
             bubble_ms = sim["bubble_fraction"] * wall_ms
             _profiler.incr("pipeline_bubble_ms", int(round(bubble_ms)))
             last = {"wall_ms": round(wall_ms, 3)}
@@ -790,9 +784,13 @@ class SPMDTrainer:
 
         ``batch_size`` defaults to the global batch (axis 0 of data); grads
         are rescaled by 1/batch_size like ``Trainer.step``.
+
+        ``spmd.step.enqueue`` is the jitted call (enqueue plus the wait for
+        the donated buffers), ``spmd.step.obs`` the counters, gauges and
+        ``step_boundary`` after it.
         """
         inputs = data if isinstance(data, (list, tuple)) else (data,)
-        with self._step_span("spmd.step"):
+        with self._step_span():
             arrays = self.shard_batch(*inputs, label)
             if batch_size is None:
                 batch_size = arrays[0].shape[0]
@@ -802,24 +800,62 @@ class SPMDTrainer:
             if fresh:
                 fn = self._build_step(arrays)
                 self._step_cache[sig] = fn
-            loss = self._dispatch("spmd.step", "step", fn,
-                                  self._step_args(batch_size), arrays, fresh)
+            comm = self._comm_state is not None
+            call_args = (*self._step_args(batch_size), self._param_arrays,
+                         self._opt_states,
+                         *((self._comm_state,) if comm else ()), *arrays)
+            lowered = text = None
+            if fresh:
+                text = _hlo_text_thunk(fn, call_args)
+                if _profiler.compile_cost_enabled():
+                    try:  # AOT lowering for XLA cost accounting (opt-in: the
+                        lowered = fn.lower(*call_args)  # real call compiles again)
+                    except Exception:
+                        lowered = None
+            tw = _perf()
+            # the fused step is one XLA program whose collectives block on
+            # every peer — the watchdog turns a dead peer into a clean exit
+            _elastic.watchdog_arm("spmd.step")
+            extras, done = None, False
+            try:
+                try:
+                    with _profiler.span("spmd.step.enqueue", "trainer"):
+                        out = fn(*call_args)
+                except Exception as e:
+                    # the fused step is THE training-tier OOM choke point:
+                    # a RESOURCE_EXHAUSTED here gets one postmortem naming
+                    # the top ledger owners before it surfaces
+                    _profiler.maybe_oom_postmortem(e, "spmd.step")
+                    raise
+                if comm:
+                    self._comm_state = out[2]
+                self._param_arrays, self._opt_states = out[0], out[1]
+                loss, extras = out[-2:]
+                if fresh:
+                    _profiler.record_compile(
+                        "spmd.step", self._compile_sig(arrays, "step"),
+                        (_perf() - tw) * 1e3, lowered=lowered, text=text)
+                done = True
+            finally:
+                with _profiler.span("spmd.step.obs", "trainer"):
+                    try:
+                        if done:
+                            self._record_step_obs(extras, tw)
+                    finally:
+                        _elastic.watchdog_disarm()
+                        _profiler.step_boundary()
+            self._post_step()
         return NDArray(loss)
 
-    def _step_span(self, name, k=None):
-        """The root span of one ``step*`` call: ``step=`` is the first
-        optimizer step it runs (the identifier shared with the device
-        trace), ``k=`` the steps in the dispatch, and under gradient
-        compression the payload args scaled by ``k`` so the trace sums to
-        the bytes the counters account (trace_report's comms table)."""
+    def _step_span(self):
+        """The root span of one ``step`` call: ``step=`` is the optimizer
+        step it runs (the identifier shared with the device trace), and
+        under gradient compression the payload args, the bytes the
+        counters account (trace_report's comms table)."""
         args = {"step": self._t + 1}
-        if k is not None:
-            args["k"] = k
         if self._comm_span_args:
             args.update(self._comm_span_args)
-            for key in ("bytes_raw", "bytes_wire"):
-                args[key] *= k or 1
-        return _profiler.span(name, "trainer", args)
+        return _profiler.span("spmd.step", "trainer", args)
 
     def _step_key(self):
         """The base key, replicated over the mesh and never donated: drawn
@@ -835,265 +871,40 @@ class SPMDTrainer:
             _np.asarray(key), NamedSharding(self._mesh, P()))
         self._key_epoch = epoch
 
-    def _step_args(self, batch_size, k=None):
-        """What a compiled step takes before the parameters (see
+    def _step_args(self, batch_size):
+        """What the compiled step takes before the parameters (see
         ``_jit_wrapped``): the base key and three HOST values, so that
         making them dispatches no program and nothing travels from one
-        chip to the others — the int32 num_update and the float32 lr
-        (``[k]`` each for a ``k``-step scan) and the float32 rescale.  A
-        scan runs the same num_update / lr schedule as ``k`` calls of
-        ``step``, and the same PRNG keys, ``fold_in(base key,
-        num_update)``.  (One packed float32 array was tried: slicing it in
-        the program cost BERT-base's step 0.03 ms, PERF.md, PR 27.)"""
+        chip to the others — the int32 num_update, the float32 lr and the
+        float32 rescale.  (One packed float32 array was tried: slicing it
+        in the program cost BERT-base's step 0.03 ms, PERF.md, PR 27.)"""
         with _profiler.span("spmd.step.args", "trainer"):
-            ts, lrs = [], []
-            for _ in range(k or 1):
-                self._t += 1
-                self._optimizer.num_update = self._t
-                ts.append(self._t)
-                lrs.append(self.learning_rate())
+            self._t += 1
+            self._optimizer.num_update = self._t
             rescale = self._optimizer.rescale_grad / batch_size
             return (self._step_key(),
-                    _np.asarray(ts if k else ts[0], _np.int32),
-                    _np.asarray(lrs if k else lrs[0], _np.float32),
+                    _np.asarray(self._t, _np.int32),
+                    _np.asarray(self.learning_rate(), _np.float32),
                     _np.asarray(rescale, _np.float32))
-
-    def _dispatch(self, site, program, fn, scalars, arrays, fresh, k=1,
-                  declared_warmup=False):
-        """Run one compiled step program and close its telemetry step: the
-        host side shared by ``step`` / ``step_bulk`` / ``step_window``.
-        ``spmd.step.enqueue`` is the jitted call (enqueue plus the wait for
-        the donated buffers), ``spmd.step.obs`` the counters, gauges and
-        ``step_boundary`` after it."""
-        comm = self._comm_state is not None
-        call_args = (*scalars, self._param_arrays, self._opt_states,
-                     *((self._comm_state,) if comm else ()), *arrays)
-        lowered = text = None
-        if fresh:
-            text = _hlo_text_thunk(fn, call_args)
-            if _profiler.compile_cost_enabled():
-                try:  # AOT lowering for XLA cost accounting (opt-in: the
-                    lowered = fn.lower(*call_args)  # real call compiles again)
-                except Exception:
-                    lowered = None
-        tw = _perf()
-        # the fused step is one XLA program whose collectives block on
-        # every peer — the watchdog turns a dead peer into a clean exit
-        _elastic.watchdog_arm(site)
-        extras, done = None, False
-        try:
-            try:
-                with _profiler.span("spmd.step.enqueue", "trainer"):
-                    out = fn(*call_args)
-            except Exception as e:
-                # the fused step is THE training-tier OOM choke point:
-                # a RESOURCE_EXHAUSTED here gets one postmortem naming
-                # the top ledger owners before it surfaces
-                _profiler.maybe_oom_postmortem(e, site)
-                raise
-            if comm:
-                self._comm_state = out[2]
-            self._param_arrays, self._opt_states = out[0], out[1]
-            loss, extras = out[-2:]
-            if fresh:
-                # a tail width is its own program, built once — a declared
-                # warmup, never a steady-state violation
-                with (_profiler.compile_guard_paused() if declared_warmup
-                      else contextlib.nullcontext()):
-                    _profiler.record_compile(
-                        "spmd.step", self._compile_sig(arrays, program),
-                        (_perf() - tw) * 1e3, lowered=lowered, text=text)
-            done = True
-        finally:
-            with _profiler.span("spmd.step.obs", "trainer"):
-                try:
-                    if done:
-                        self._record_step_obs(extras, tw, k=k)
-                finally:
-                    _elastic.watchdog_disarm()
-                    _profiler.step_boundary()
-        self._post_step()
-        return loss
-
-    # ------------------------------------------------------------------
-    def step_bulk(self, data, label, k, batch_size=None):
-        """Run ``k`` fused optimizer steps in ONE device dispatch
-        (``lax.scan`` over the jitted step) — the TPU-native analog of the
-        reference engine's bulked execution (``MXNET_EXEC_BULK_EXEC_TRAIN``
-        and CachedOp's bulking segments, [U:src/imperative/cached_op.cc]):
-        for small programs the per-dispatch host→device round trip
-        dominates, and queueing k steps as one program amortizes it.
-
-        The batch is reused for all ``k`` steps (callers feeding real data
-        should call once per batch; the win is for dispatch-bound
-        programs).  Numerically identical to ``k`` successive ``step()``
-        calls with the same batch: step ``t`` of either takes num_update
-        ``t``, the scheduler's lr at ``t`` and the PRNG key ``fold_in(base
-        key, t)`` (``_step_args``).  Returns the LAST step's mean loss as
-        an NDArray.
-        """
-        if k < 1:
-            raise ValueError(f"step_bulk needs k >= 1, got {k}")
-        k = int(k)
-        inputs = data if isinstance(data, (list, tuple)) else (data,)
-        with self._step_span("spmd.step_bulk", k):
-            arrays = self.shard_batch(*inputs, label)
-            if batch_size is None:
-                batch_size = arrays[0].shape[0]
-            sig = (tuple((a.shape, str(a.dtype)) for a in arrays), k)
-            fn = self._step_cache.get(sig)
-            fresh = fn is None
-            if fresh:
-                fn = self._build_bulk(arrays, k)
-                self._step_cache[sig] = fn
-            loss = self._dispatch(
-                "spmd.step_bulk", f"step_bulk[{k}]", fn,
-                self._step_args(batch_size, k), arrays, fresh, k=k)
-        return NDArray(loss)
-
-    def _build_bulk(self, example_arrays, k):
-        pure_step = self._build_pure(example_arrays)
-        if self._comm_state is not None:
-            def bulk_step(keys, ts, lrs, rescale, param_arrs, opt_states,
-                          comm_state, *batch):
-                def body(carry, xs):
-                    pa, os, cs = carry
-                    key, t, lr = xs
-                    pa, os, cs, loss, extras = pure_step(
-                        key, t, lr, rescale, pa, os, cs, *batch)
-                    return (pa, os, cs), (loss, extras)
-
-                (pa, os, cs), (losses, extras) = jax.lax.scan(
-                    body, (param_arrs, opt_states, comm_state),
-                    (keys, ts, lrs), length=k)
-                return pa, os, cs, losses[-1], extras
-
-            return self._jit_wrapped(bulk_step)
-
-        def bulk_step(keys, ts, lrs, rescale, param_arrs, opt_states, *batch):
-            def body(carry, xs):
-                pa, os = carry
-                key, t, lr = xs
-                pa, os, loss, extras = pure_step(
-                    key, t, lr, rescale, pa, os, *batch)
-                return (pa, os), (loss, extras)
-
-            (pa, os), (losses, extras) = jax.lax.scan(
-                body, (param_arrs, opt_states), (keys, ts, lrs), length=k
-            )
-            # extras leaves arrive stacked [k]; _record_step_obs reduces
-            return pa, os, losses[-1], extras
-
-        return self._jit_wrapped(bulk_step)
-
-    # ------------------------------------------------------------------
-    def shard_window(self, *arrays):
-        """``shard_batch`` for ``[K, batch, ...]`` stacked windows: the K
-        axis replicates, the per-step batch axis (axis 1) shards over
-        (dp, fsdp) — byte-identical to what ``io.DataPipeline``'s
-        ``stage_window`` builds, so windows arriving device-resident pass
-        through with zero host work."""
-        def spec_of(ndim):
-            return P(None, *batch_pspec(max(0, ndim - 1), self._sp_axis))
-
-        return tuple(self._place(a, spec_of) for a in arrays)
-
-    def step_window(self, data, label, batch_size=None):
-        """Run K fused optimizer steps over K DIFFERENT pre-staged batches
-        in ONE device dispatch — ``step_bulk``'s real-data twin and the
-        SPMD analog of ``gluon.Trainer.fold_steps``: the per-step program
-        (collectives, codec buckets and all) becomes a ``lax.scan`` body,
-        consuming one row of the ``[K, batch, ...]`` stacked window
-        (``io.DataPipeline.stage_window(k)``) per iteration.  Numerically
-        identical to K successive ``step()`` calls on the K rows (the same
-        num_update / lr / ``fold_in(base key, num_update)`` key at every
-        step, see ``_step_args``); returns the LAST step's mean loss.  K
-        rides the window's leading axis — an epoch tail simply dispatches
-        a shorter program (registered as a declared warmup, not a
-        steady-state recompile)."""
-        inputs = data if isinstance(data, (list, tuple)) else (data,)
-        shape = _np.shape(inputs[0])
-        if len(shape) < 2:
-            raise ValueError(
-                "step_window expects stacked [k, batch, ...] windows "
-                f"(pipeline.stage_window(k)); got {tuple(shape)}")
-        k = int(shape[0])
-        with self._step_span("spmd.step_window", k):
-            arrays = self.shard_window(*inputs, label)
-            if batch_size is None:
-                batch_size = arrays[0].shape[1]
-            if self._window_k is None:
-                self._window_k = k     # first width seen = the steady width
-            sig = (tuple((a.shape, str(a.dtype)) for a in arrays), "window")
-            fn = self._step_cache.get(sig)
-            fresh = fn is None
-            if fresh:
-                fn = self._build_window(arrays)
-                self._step_cache[sig] = fn
-            loss = self._dispatch(
-                "spmd.step_window", f"step_window[{k}]", fn,
-                self._step_args(batch_size, k), arrays, fresh, k=k,
-                declared_warmup=k != self._window_k)
-        return NDArray(loss)
-
-    def _build_window(self, example_arrays):
-        # the per-step body traces against one window ROW's avals
-        per_step = [jax.ShapeDtypeStruct(tuple(a.shape[1:]), a.dtype)
-                    for a in example_arrays]
-        pure_step = self._build_pure(per_step)
-        if self._comm_state is not None:
-            def window_step(keys, ts, lrs, rescale, param_arrs, opt_states,
-                            comm_state, *windows):
-                def body(carry, xs):
-                    pa, os, cs = carry
-                    key, t, lr = xs[0], xs[1], xs[2]
-                    pa, os, cs, loss, extras = pure_step(
-                        key, t, lr, rescale, pa, os, cs, *xs[3:])
-                    return (pa, os, cs), (loss, extras)
-
-                (pa, os, cs), (losses, extras) = jax.lax.scan(
-                    body, (param_arrs, opt_states, comm_state),
-                    (keys, ts, lrs) + tuple(windows))
-                return pa, os, cs, losses[-1], extras
-
-            return self._jit_wrapped(window_step)
-
-        def window_step(keys, ts, lrs, rescale, param_arrs, opt_states,
-                        *windows):
-            def body(carry, xs):
-                pa, os = carry
-                key, t, lr = xs[0], xs[1], xs[2]
-                pa, os, loss, extras = pure_step(
-                    key, t, lr, rescale, pa, os, *xs[3:])
-                return (pa, os), (loss, extras)
-
-            (pa, os), (losses, extras) = jax.lax.scan(
-                body, (param_arrs, opt_states), (keys, ts, lrs)
-                + tuple(windows))
-            # extras leaves arrive stacked [k]; _record_step_obs reduces
-            return pa, os, losses[-1], extras
-
-        return self._jit_wrapped(window_step)
 
     # ------------------------------------------------------------------
     def _build_step(self, example_arrays):
         return self._jit_wrapped(self._build_pure(example_arrays))
 
     def _jit_wrapped(self, step_fn):
-        """jit a (keys, t(s), lr(s), rescale, params, states[, comm],
-        *batch) step with param/state (and error-feedback residual)
-        donation and the trainer's output shardings.  The compiled program
-        takes ``_step_args``'s base key and int32 num_update(s) in place
-        of the first two and derives them itself: step ``t``'s key is
-        ``fold_in(base key, t)``, one key a step of a scan."""
+        """jit a (key, t, lr, rescale, params, states[, comm], *batch) step
+        with param/state (and error-feedback residual) donation and the
+        trainer's output shardings.  The compiled program takes
+        ``_step_args``'s base key and int32 num_update (``steps``: the
+        parameters' names are in the compiled text) in place of the first
+        two and derives them itself: step ``t``'s key is ``fold_in(base
+        key, t)``."""
         def step(base_key, steps, *rest):
-            fold = jax.random.fold_in
-            keys = (jax.vmap(fold, (None, 0))(base_key, steps) if steps.ndim
-                    else fold(base_key, steps))
+            key = jax.random.fold_in(base_key, steps)
             # traced under the trainer's mesh: ops that have to place
             # themselves on it (the attention kernels) read it there
             with mesh_scope(self._mesh):
-                return step_fn(keys, steps.astype(jnp.float32), *rest)
+                return step_fn(key, steps.astype(jnp.float32), *rest)
 
         # the device trace names the program jit_<__name__>
         step.__name__ = step_fn.__name__
@@ -1111,7 +922,7 @@ class SPMDTrainer:
             # (params, states, comm, loss, extras): the residual rides
             # between states and loss, sharded over the batch axes
             out_shardings.insert(2, self._comm_sharding)
-        donate = ((4, 5, 6) if comm else (4, 5)) if self._donate else ()
+        donate = (4, 5, 6) if comm else (4, 5)
         with self._mesh:
             return jax.jit(
                 step, donate_argnums=donate,
@@ -1271,11 +1082,13 @@ class SPMDTrainer:
             else:
                 comm_state, batch = None, rest
             train_arrs = [param_arrs[j] for j in trainable_idx]
-            # ring outputs are replicated by explicit relay, which the
-            # static replication checker cannot see through ppermute
+            # core sums the gradients itself: under check_vma=True the
+            # cotangent of a replicated (P()) operand is ALREADY summed
+            # over the mapped axes, and the step applied shards x the
+            # gradient; nor can the checker see the ring's relay
             mapped = jax.shard_map(shard_body, mesh=mesh,
                                    in_specs=in_specs, out_specs=out_specs,
-                                   check_vma=(algo != "ring"))
+                                   check_vma=False)
             if ef:
                 grads_t, new_comm, loss_mean, aux_vals, extras = mapped(
                     train_arrs, list(param_arrs), key, comm_state, *batch)

@@ -230,11 +230,10 @@ def test_dropout_eval_identity_train_random():
     assert int((d2.asnumpy() == 0).sum()) > 0
 
 
-def test_dropout_fast_path_unbiased(monkeypatch):
+def test_dropout_fast_path_unbiased():
     """The uint8-bits fast path rescales by its own quantized keep-prob, so
     surviving values are exactly data/keep_q and the empirical drop rate
     tracks p to the 1/256 quantization."""
-    monkeypatch.setenv("MXNET_TPU_FAST_DROPOUT", "1")
     mx.random.seed(7)
     n = 200_000
     with autograd.record():

@@ -292,8 +292,21 @@ class TestAutotune:
                             _device_pressure_fn=lambda frac: False)
         try:
             for _ in range(5):  # 5 epochs, each restarts with an empty buffer
-                for _ in pipe:
-                    time.sleep(0.002)  # consumer slower than producer
+                it, delivered = iter(pipe), 0
+                while True:
+                    if delivered >= pipe.depth:
+                        # the buffer has filled once this epoch: from here
+                        # the consumer is the slower side, whatever else
+                        # the machine runs — it asks when a batch is there
+                        _wait_until(
+                            lambda: pipe.stats()["buffer_occupancy"] > 0,
+                            msg="the producer's next batch")
+                    try:
+                        next(it)
+                    except StopIteration:
+                        break
+                    delivered += 1
+                assert delivered == len(src)
             assert pipe.depth == 2
             # phantom (epoch-refill) stalls are race-dependent; the
             # contract is that whatever occurred never fed the tuner

@@ -308,91 +308,78 @@ class TestSoftmaxOutputNormalization:
         assert_almost_equal(g, want / 2.0, rtol=1e-5, atol=1e-7)
 
 
-class TestLayerNormCustomBwd:
-    """MXNET_TPU_LN_CUSTOM_BWD=1: the hand-written VJP must match
-    autodiff of the reference form for value and all three gradients."""
-
-    @with_seed()
-    def test_matches_autodiff(self, monkeypatch):
-        import jax
-        import jax.numpy as jnp
-
-        from incubator_mxnet_tpu.ops.nn import layer_norm, _layer_norm_ref
-
-        monkeypatch.setenv("MXNET_TPU_LN_CUSTOM_BWD", "1")
-        rng = np.random.RandomState(0)
-        for dtype, tol in ((jnp.float32, 1e-5), (jnp.bfloat16, 3e-2)):
-            x = jnp.asarray(rng.randn(4, 6, 16).astype(np.float32)).astype(dtype)
-            g = jnp.asarray(rng.rand(16).astype(np.float32) + 0.5)
-            b = jnp.asarray(rng.randn(16).astype(np.float32))
-
-            def lc(x, g, b):
-                return jnp.sum(jnp.sin(layer_norm(x, g, b).astype(jnp.float32)))
-
-            def lr(x, g, b):
-                return jnp.sum(jnp.sin(
-                    _layer_norm_ref(x, g, b, -1, 1e-5).astype(jnp.float32)))
-
-            # value_and_grad: the value flows through the custom fwd (the
-            # primal alone would execute the reference), so this checks the
-            # hand-written forward AND backward
-            v1, g1 = jax.value_and_grad(lc, argnums=(0, 1, 2))(x, g, b)
-            v2, g2 = jax.value_and_grad(lr, argnums=(0, 1, 2))(x, g, b)
-            np.testing.assert_allclose(float(v1), float(v2), rtol=1e-5)
-            for a, c in zip(g1, g2):
-                np.testing.assert_allclose(np.asarray(a, np.float32),
-                                           np.asarray(c, np.float32),
-                                           rtol=tol, atol=tol)
-                # primal-dtype contract
-            assert g1[0].dtype == x.dtype
-            assert g1[1].dtype == g.dtype and g1[2].dtype == b.dtype
-
-    @with_seed()
-    def test_non_last_axis_falls_back(self, monkeypatch):
-        monkeypatch.setenv("MXNET_TPU_LN_CUSTOM_BWD", "1")
-        x = np.random.randn(3, 8, 5).astype(np.float32)
-        out = mx.nd.LayerNorm(_nd(x), _nd(np.ones(8, np.float32)),
-                              _nd(np.zeros(8, np.float32)), axis=1)
-        m = x.mean(axis=1, keepdims=True)
-        v = x.var(axis=1, keepdims=True)
-        assert_almost_equal(out.asnumpy(), (x - m) / np.sqrt(v + 1e-5),
-                            rtol=1e-4, atol=1e-5)
+def _layer_norm_f64(x, g, b, eps=1e-5):
+    """LayerNorm over the last axis and the gradients of
+    ``sum(sin(out))`` in NumPy float64, written from the closed form
+    (independent of the op and of autodiff)."""
+    mean = x.mean(-1, keepdims=True)
+    inv = 1.0 / np.sqrt(x.var(-1, keepdims=True) + eps)
+    xhat = (x - mean) * inv
+    out = xhat * g + b
+    dy = np.cos(out)
+    dyg = dy * g
+    dx = inv * (dyg - dyg.mean(-1, keepdims=True)
+                - xhat * (dyg * xhat).mean(-1, keepdims=True))
+    batch = tuple(range(x.ndim - 1))
+    return out, dx, (dy * xhat).sum(batch), dy.sum(batch)
 
 
-def test_attn_score_layout_ab_equivalence():
-    """MXNET_TPU_ATTN_SCORE_LAYOUT=bqhk (the TPU relayout A/B) is
-    numerically identical to the default bhqk — fwd and grads, causal."""
-    import subprocess
-    import sys
-    import os as os_mod
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5), ("bfloat16", 3e-2)])
+def test_layer_norm_grads_keep_primal_dtypes(dtype, tol):
+    """Value and the three gradients against the float64 closed form, and
+    the dtype contract under AMP: ``dx`` in the activation's dtype,
+    ``dgamma`` / ``dbeta`` in the parameters' own."""
+    import jax
+    import jax.numpy as jnp
 
-    script = r"""
-import numpy as np
-import jax, jax.numpy as jnp
-import incubator_mxnet_tpu.ops.attention as att
-rng = np.random.RandomState(0)
-q = jnp.asarray(rng.randn(2, 16, 4, 8).astype(np.float32))
-k = jnp.asarray(rng.randn(2, 16, 4, 8).astype(np.float32))
-v = jnp.asarray(rng.randn(2, 16, 4, 8).astype(np.float32))
-def f(q, k, v):
-    return (att._flash_bshd(q, k, v, True, 0.35) * jnp.arange(8)).sum()
-val, grads = jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
-for g in grads:
-    print(repr(float(np.abs(np.asarray(g)).sum())))
-print(repr(float(val)))
-"""
-    outs = {}
-    # also pin the saved-probs branch (MAX_ELEMS large enough to engage)
-    for layout in ("bhqk", "bqhk", "bhqk-save", "bqhk-save"):
-        env = dict(os_mod.environ)
-        env.update(JAX_PLATFORMS="cpu",
-                   MXNET_TPU_ATTN_SCORE_LAYOUT=layout.split("-")[0])
-        if layout.endswith("-save"):
-            env["MXNET_TPU_ATTN_SAVE_PROBS_MAX_ELEMS"] = "10000000"
-        r = subprocess.run([sys.executable, "-c", script], env=env,
-                           capture_output=True, text=True, timeout=300)
-        assert r.returncode == 0, r.stderr[-800:]
-        outs[layout] = [float(x) for x in r.stdout.strip().splitlines()]
-    for variant in ("bqhk", "bhqk-save", "bqhk-save"):
-        np.testing.assert_allclose(outs["bhqk"], outs[variant], rtol=1e-5,
-                                   err_msg=variant)
+    from incubator_mxnet_tpu.ops.nn import layer_norm
+
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(4, 6, 16).astype(np.float32)).astype(dtype)
+    g = jnp.asarray(rng.rand(16).astype(np.float32) + 0.5)
+    b = jnp.asarray(rng.randn(16).astype(np.float32))
+
+    def loss(x, g, b):
+        out = layer_norm(x, g, b)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+
+    (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(x, g, b)
+    want = _layer_norm_f64(*(np.asarray(a, np.float64) for a in (x, g, b)))
+    assert out.dtype == x.dtype
+    for got, ref in zip((out, *grads), want):
+        np.testing.assert_allclose(np.asarray(got, np.float64), ref,
+                                   rtol=tol, atol=tol)
+    assert grads[0].dtype == x.dtype
+    assert grads[1].dtype == g.dtype and grads[2].dtype == b.dtype
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 4e-2)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_bshd_attention_vjp_matches_autodiff(causal, dtype, tol):
+    """``_flash_bshd``'s value and its hand-written backward (the path the
+    S 128 cells take) against autodiff of ``attention_reference_bshd``."""
+    import jax
+    import jax.numpy as jnp
+
+    from incubator_mxnet_tpu.ops import attention as att
+
+    rng = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rng.randn(2, 16, 4, 8).astype(np.float32))
+               .astype(dtype) for _ in range(3))
+    weight = jnp.arange(8, dtype=jnp.float32)
+
+    def loss(attend):
+        def f(q, k, v):
+            out = attend(q, k, v, causal, 0.35)
+            return (out.astype(jnp.float32) * weight).sum()
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    val, grads = loss(att._flash_bshd)
+    want_val, want_grads = loss(att.attention_reference_bshd)
+    np.testing.assert_allclose(float(val), float(want_val), rtol=1e-5)
+    for got, ref, operand in zip(grads, want_grads, (q, k, v)):
+        assert got.dtype == operand.dtype
+        ref = np.asarray(ref, np.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32), ref,
+                                   rtol=tol, atol=tol * np.abs(ref).max())

@@ -109,62 +109,6 @@ class TestSPMDTrainer:
 
         _assert_params_close(net_a, net_b)
 
-    @pytest.mark.parametrize("dropout", [0.0, 0.1])
-    def test_step_bulk_matches_sequential(self, dropout):
-        """k bulked steps (one lax.scan dispatch — the engine-bulking
-        analog) must equal k sequential step() calls: same params, same
-        num_update, same key schedule (step t's dropout key is
-        fold_in(base key, t) in both)."""
-        x, y = _data()
-        loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-
-        net_a = _mlp(seed=23, dropout=dropout)
-        net_b = _mlp(seed=23, dropout=dropout)
-        xa, ya = mx.nd.array(x), mx.nd.array(y)
-
-        mx.random.seed(5)
-        seq = SPMDTrainer(net_a, loss_fn, "adam", {"learning_rate": 0.01},
-                          mesh=make_mesh())
-        for _ in range(6):
-            seq.step(xa, ya)
-        seq.sync_to_block()
-
-        mx.random.seed(5)
-        blk = SPMDTrainer(net_b, loss_fn, "adam", {"learning_rate": 0.01},
-                          mesh=make_mesh())
-        blk.step_bulk(xa, ya, 3)
-        blk.step_bulk(xa, ya, 3)
-        blk.sync_to_block()
-
-        assert blk.num_update == seq.num_update == 6
-        _assert_params_close(net_a, net_b)
-
-    @pytest.mark.parametrize("dropout", [0.0, 0.1])
-    def test_step_window_matches_sequential(self, dropout):
-        """One step_window over K different rows equals K step() calls on
-        the rows, dropout masks included."""
-        rows = [_data(seed=s) for s in (3, 4, 5)]
-        loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-        net_a = _mlp(seed=29, dropout=dropout)
-        net_b = _mlp(seed=29, dropout=dropout)
-
-        mx.random.seed(6)
-        seq = SPMDTrainer(net_a, loss_fn, "adam", {"learning_rate": 0.01},
-                          mesh=make_mesh())
-        for x, y in rows:
-            seq.step(x, y)
-        seq.sync_to_block()
-
-        mx.random.seed(6)
-        win = SPMDTrainer(net_b, loss_fn, "adam", {"learning_rate": 0.01},
-                          mesh=make_mesh())
-        win.step_window(np.stack([x for x, _ in rows]),
-                        np.stack([y for _, y in rows]))
-        win.sync_to_block()
-
-        assert win.num_update == seq.num_update == 3
-        _assert_params_close(net_a, net_b)
-
     def test_adam_bias_correction_not_frozen(self):
         """t must be traced, not baked: two Adam steps from zero state give
         different deltas than one (catches a constant-t recompile bug)."""
@@ -292,40 +236,87 @@ class TestStepArguments:
     base key on the mesh and host values — making them dispatches nothing,
     and step t's key is fold_in(base key, t) inside the program."""
 
-    @pytest.mark.parametrize("entry", ["step", "step_bulk", "step_window"])
-    def test_leading_arguments_are_host_values_or_the_base_key(self, entry):
+    def test_leading_arguments_are_host_values_or_the_base_key(self):
         from jax.sharding import NamedSharding
 
         _, tr = _dropout_trainer(1)
         x, y = _data()
-        k = {"step": None, "step_bulk": 2, "step_window": 3}[entry]
-
-        def call():
-            if entry == "step_window":
-                return tr.step_window(np.stack([x] * k), np.stack([y] * k))
-            return tr.step(x, y) if k is None else tr.step_bulk(x, y, k)
-
-        call()                                   # builds and caches fn
+        tr.step(x, y)                            # builds and caches fn
         (sig, fn), = tr._step_cache.items()
         seen = []
         tr._step_cache[sig] = lambda *a: seen.append(a) or fn(*a)
-        call()
-        call()
+        tr.step(x, y)
+        tr.step(x, y)
         assert len(seen) == 2
         for args in seen:
             n_lead = next(i for i, a in enumerate(args)
                           if isinstance(a, list))    # the parameters
-            key, steps, lrs, rescale = args[:n_lead]
+            key, t, lr, rescale = args[:n_lead]
             assert key is tr._base_key
             assert key.sharding == NamedSharding(tr.mesh, P())
-            assert type(steps) is np.ndarray and steps.dtype == np.int32
-            for host in (lrs, rescale):
+            assert type(t) is np.ndarray and t.dtype == np.int32
+            for host in (lr, rescale):
                 assert type(host) is np.ndarray and host.dtype == np.float32
-            assert steps.shape == lrs.shape == (() if k is None else (k,))
-            np.testing.assert_allclose(lrs, 0.1)
+            assert t.shape == lr.shape == ()
+            np.testing.assert_allclose(lr, 0.1)
             np.testing.assert_allclose(rescale, 1 / 64)
-        assert int(np.max(seen[-1][1])) == tr.num_update
+        assert int(seen[-1][1]) == tr.num_update
         assert fn._cache_size() == 1
+
+    @staticmethod
+    def _encoder_step_census():
+        """What a two-layer encoder's step (LayerNorm, dropout, attention,
+        FFN) traces: (primitive, its outputs) -> count, over the step's
+        jaxpr and every jaxpr nested in it.  Unlike the lowered text it
+        does not depend on which calls the tracing caches let share one
+        sub-jaxpr (a full run of the suite evicts them at its own times).
+        A new trainer builds a new function: nothing is served from an
+        earlier trace."""
+        import collections
+
+        mx.random.seed(3)
+        net = nn.HybridSequential()
+        for _ in range(2):
+            net.add(nn.TransformerEncoderCell(16, 32, 2, dropout=0.1))
+        net.initialize()
+        x = np.zeros((8, 4, 16), np.float32)
+        net(mx.nd.array(x))
+        tr = SPMDTrainer(net, gluon.loss.L2Loss(), "sgd",
+                         {"learning_rate": 0.1}, mesh=make_mesh())
+        arrays = tr.shard_batch(x, x)
+        census = collections.Counter()
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                outs = tuple(str(v.aval) for v in eqn.outvars)
+                census[eqn.primitive.name, outs] += 1
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jax.make_jaxpr(tr._build_step(arrays))(
+            *tr._step_args(8), tr._param_arrays, tr._opt_states,
+            *arrays).jaxpr)
+        return census
+
+    @pytest.fixture(scope="class")
+    def plain_step_census(self):
+        return self._encoder_step_census()
+
+    @pytest.mark.parametrize("name,value", [
+        ("MXNET_TPU_REMAT_FFN", "full"),
+        ("MXNET_TPU_LN_CUSTOM_BWD", "1"),
+        ("MXNET_TPU_ATTN_SAVE_PROBS_MAX_ELEMS", "10000000"),
+        ("MXNET_TPU_ATTN_SCORE_LAYOUT", "bqhk"),
+        ("MXNET_TPU_FAST_DROPOUT", "0")])
+    def test_traced_step_ignores_the_environment(self, monkeypatch,
+                                                 plain_step_census, name,
+                                                 value):
+        """The deleted A/B levers stay deleted: the step traces the same
+        operations with the lever's old non-default value in the
+        environment."""
+        assert sum(plain_step_census.values()) > 500
+        monkeypatch.setenv(name, value)
+        assert self._encoder_step_census() == plain_step_census
 
     def test_lr_schedule_does_not_recompile(self):
         from incubator_mxnet_tpu import profiler

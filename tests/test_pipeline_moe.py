@@ -270,31 +270,6 @@ class TestSPMDPipelineTrainer:
         # (no Python re-trace), so the forward trace above is the ONLY
         # place slot-dependent values enter — and they entered correctly
 
-    def test_step_bulk_matches_sequential_steps(self):
-        x, y = _data()
-        loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
-        xa, ya = mx.nd.array(x), mx.nd.array(y)
-
-        def make(seed):
-            net = _mlp4(seed=seed)
-            return net, SPMDTrainer(
-                net, loss_fn, "adam", {"learning_rate": 0.01},
-                mesh=make_mesh(), stages=net.split_stages([2, 2]),
-                pipeline={"schedule": "1f1b", "n_microbatches": 4})
-
-        mx.random.seed(5)
-        net_a, seq = make(23)
-        for _ in range(4):
-            seq.step(xa, ya)
-        seq.sync_to_block()
-
-        mx.random.seed(5)
-        net_b, blk = make(23)
-        blk.step_bulk(xa, ya, 4)
-        blk.sync_to_block()
-        assert blk.num_update == seq.num_update == 4
-        _assert_params_close(net_a, net_b)
-
     def test_batchnorm_aux_through_pipeline(self):
         mx.random.seed(3)
         net = nn.HybridSequential()

@@ -1079,28 +1079,17 @@ def test_block_names_are_entered_only_while_tracing():
         name_stack=True))
 
 
-@pytest.mark.parametrize("entry", ["step", "step_bulk", "step_window"])
-def test_step_spans_nest_once_per_call_in_both_sinks(clean_profiler, entry):
-    """Per ``trainer.step*`` call: one root span with ``step=``, and
+def test_step_spans_nest_once_per_call_in_both_sinks(clean_profiler):
+    """Per ``trainer.step`` call: one root span with ``step=``, and
     ``spmd.step.args`` / ``.enqueue`` / ``.obs`` once each inside it, on the
     xplane's host plane AND in the ring; ``spmd.shard_batch`` only where a
     host batch is transferred."""
     _, tr, x, y = _tiny_spmd("plain")
-    root = {"step": "spmd.step", "step_bulk": "spmd.step_bulk",
-            "step_window": "spmd.step_window"}[entry]
-    k = 1 if entry == "step" else 2
-
-    if entry == "step_window":
-        host = (np.stack([x, x]), np.stack([y, y]))
-        staged = tr.shard_window(*host)
-    else:
-        host, staged = (x, y), tr.shard_batch(x, y)
+    root = "spmd.step"
+    host, staged = (x, y), tr.shard_batch(x, y)
 
     def call(host_batch):
-        batch = host if host_batch else staged
-        if entry == "step_window":
-            return tr.step_window(*batch)
-        return tr.step(*batch) if entry == "step" else tr.step_bulk(*batch, 2)
+        return tr.step(*(host if host_batch else staged))
 
     call(True)                       # compile outside the session
     profiler.start()
@@ -1116,10 +1105,8 @@ def test_step_spans_nest_once_per_call_in_both_sinks(clean_profiler, entry):
     spans = host_spans(trace_dir, "spmd.")
     roots = sorted((s for s in spans if s[3] == root), key=lambda s: s[1])
     assert len(roots) == 3
-    assert [int(r[4]["step"]) for r in roots] == [first, first + k,
-                                                  first + 2 * k]
-    if k > 1:
-        assert all(r[4]["k"] == str(k) for r in roots)
+    assert [int(r[4]["step"]) for r in roots] == [first, first + 1,
+                                                  first + 2]
     for child in ("spmd.step.args", "spmd.step.enqueue", "spmd.step.obs"):
         inner = [s for s in spans if s[3] == child]
         assert len(inner) == 3, child
