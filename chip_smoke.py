@@ -149,8 +149,8 @@ def phase_kernels(cfg, dry_run):
             grad = jax.jit(jax.grad(loss(flash_attention), argnums=(0, 1, 2)))
             gref = jax.jit(jax.grad(loss(attention_reference), argnums=(0, 1, 2)))
             what = f"flash fwd+bwd S={s} causal={causal}"
-            # forward-with-lse, dq pass, dk/dv pass
-            check_lowering(grad, (q, k, v), 3, what)
+            # forward-with-lse, the one-pass backward
+            check_lowering(grad, (q, k, v), 2, what)
             errs = [close(g, r, f"{what} d{n}")
                     for g, r, n in zip(grad(q, k, v), gref(q, k, v), "qkv")]
             say(f"kernels: {what} ok (max err dq/dk/dv "

@@ -19,7 +19,8 @@ belong to it:
    Mosaic on TPU; the Pallas interpreter only when asked for by name,
    ``MXNET_TPU_FLASH=interpret`` — the CPU test tier does): online-softmax
    forward — queries tiled over the grid, K/V streamed through VMEM in
-   ``block_k`` chunks — and the two-pass backward (`_flash_bwd_pallas`), so
+   ``block_k`` chunks — and the one-pass backward (`_flash_bwd_pallas`: keys
+   tiled over the grid, the head's queries resident, dq summed in VMEM), so
    the S×S score matrix is never materialized in HBM and memory stays
    linear in S.  Accumulation in fp32 on the MXU
    (``preferred_element_type``), inputs may be bf16.  Want ``[B·H, S, Dh]``
@@ -64,16 +65,23 @@ from jax.sharding import PartitionSpec
 __all__ = ["flash_attention", "attention_reference", "latent_attention",
            "yarn_rotary_tables", "apply_rotary"]
 
-# The kernels keep one head's whole K and V (backward: Q, dO, O and the
-# log-sum-exp) in VMEM beside their 512-wide tiles: 16.5 MB at S 4096 with
-# 192-wide keys, over the compiler's default scoped limit of 16 MiB by half a
-# megabyte in some programs and not in others (where XLA places an operand
-# decides); a v5e core has 128 MiB.
+# The forward kernel keeps one head's whole K and V in VMEM beside its
+# 512-wide tiles, the backward the head's Q, dO, dq and dq's float32 sum: 22 MB
+# at S 4096 with 192-wide keys, 36 MB at S 16384 and 70 MB at S 32768 with
+# 64-wide ones.  The compiler's default scoped limit is 16 MiB; a v5e core has
+# 128 MiB.
 _MOSAIC_PARAMS = _pltpu.CompilerParams(vmem_limit_bytes=48 << 20)
+_BWD_PARAMS = _pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=100 << 20)
 
 # TPU lane width: row statistics (lse) are replicated across a 128-lane
 # trailing dim so their blocks satisfy Mosaic's (8, 128) tiling rule.
 _LANE = 128
+
+# dot_general dimension numbers of the kernels' 2-D products
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+_TN = (((0,), (0,)), ((), ()))   # aᵀ · b
 
 
 def _use_pallas(x=None):
@@ -153,8 +161,9 @@ def _on_mesh(launch, fn, *arrays):
 
 
 def online_softmax_update(o, m, l, s, v, matmul):
-    """One blockwise online-softmax accumulation step (shared by the Pallas
-    kernel below and parallel/ring.py).  ``m``/``l`` carry a trailing
+    """One blockwise online-softmax accumulation step (parallel/ring.py's,
+    whose blocks can be fully masked; the Pallas kernel below takes
+    :func:`_live_softmax_update`).  ``m``/``l`` carry a trailing
     keepdim; ``s`` may contain -inf for masked entries; fully-masked rows
     keep zero mass (caller fixes l==0 before the final divide)."""
     m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -167,6 +176,20 @@ def online_softmax_update(o, m, l, s, v, matmul):
     return o_new, m_new, l_new
 
 
+def _live_softmax_update(o, m, l, s, v, matmul):
+    """:func:`online_softmax_update` where every row has a live key among
+    the blocks seen so far, this one included — the kernels below: without
+    a mask no key is dead, and under the causal one every row sees key 0 in
+    its first block.  The new maximum is then finite, a masked score's
+    ``exp(-inf - m)`` and the first block's ``exp(-inf - m)`` correction are
+    exact zeros, and none of the guards is needed: the same bits for five
+    vector operations a score fewer."""
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    return o * corr + matmul(p, v), m_new, l * corr + p.sum(axis=-1, keepdims=True)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
     """One (batch·head, q-block) grid cell: stream K/V blocks, online
     softmax in fp32.  Shapes: q_ref [1, Bq, D], k_ref [1, Sk, D],
@@ -176,7 +199,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
     Operands stay in their input dtype (bf16 rides the MXU at full rate)
     with fp32 accumulation via preferred_element_type; matmul precision is
     pinned per-dtype because the package-global 'highest' default would
-    request an fp32 contraction on bf16 operands, which Mosaic rejects."""
+    request an fp32 contraction on bf16 operands, which Mosaic rejects.
+
+    Under the causal mask every block a cell computes is masked, those wholly
+    below the diagonal too: the compare and select hide under the MXU's
+    work, and a second, unmasked loop for those blocks measured SLOWER on a
+    v5e (PERF.md §6, PR 31)."""
     i = _pl.program_id(1)
     block_q = q_ref.shape[1]
     seq_k = k_ref.shape[1]
@@ -194,17 +222,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
         k = k_ref[0, _pl.ds(j * block_k, block_k), :]
         v = v_ref[0, _pl.ds(j * block_k, block_k), :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec,
+            q, k, _NT, preferred_element_type=jnp.float32, precision=prec,
         ) * scale  # [Bq, Bk], fp32 accumulate then scale
         if causal:
             q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        acc_new, m_new, l_new = online_softmax_update(
+        acc_new, m_new, l_new = _live_softmax_update(
             acc, m, l, s, v,
             lambda p, v: jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v, _NN,
                 preferred_element_type=jnp.float32, precision=prec,
             ),
         )
@@ -218,17 +245,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
     else:
         nk_bound = nk
     m, l, acc = lax.fori_loop(0, nk_bound, body, (m0, l0, acc0))
-    l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     if lse_ref is not None:
         # log-sum-exp per query row, saved for the blockwise backward:
         # p = exp(s - lse) reproduces softmax without re-running the
         # online rescaling.  Replicated across a 128-lane trailing dim to
         # satisfy TPU tiling (same layout as jax's reference TPU kernel).
-        # Fully-masked rows get lse = 0 (m_safe), so exp(-inf - 0) = 0
-        # keeps their gradient contributions zero.
-        m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
-        lse_ref[0] = jnp.broadcast_to(m_safe + jnp.log(l), lse_ref.shape[1:])
+        lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
 
 
 def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=128,
@@ -272,104 +295,82 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
 
 
 # ---------------------------------------------------------------------------
-# Pallas backward kernels (standard two-pass flash gradient: a dq pass
-# gridded over q blocks and a dk/dv pass gridded over k blocks, both
-# streaming the opposite operand — the S×S score matrix never exists in HBM)
+# Pallas backward kernel: one pass.  A (batch·head, key block) grid cell
+# loops over the query blocks the causal bound leaves it and computes each
+# block's scores, exponentials, dO·Vᵀ and dS ONCE, for all three gradients:
+# 5 matrix products a block (a dq pass beside a dk/dv pass spends 7, and two
+# passes of vector work).  dk and dv are the cell's own; dq is summed in a
+# float32 VMEM scratch that lives across the head's key blocks (the grid's
+# inner axis) and is scaled, cast and written once, so no partial dq goes
+# through HBM and nothing accumulates in bf16.  The score tile is held
+# TRANSPOSED, keys down and queries across (k·qᵀ): pᵀ·dO and dSᵀ·q are then
+# plain products and only dq = dS·k contracts over a transposed left
+# operand, and the query rows' statistics (log-sum-exp; delta = rowsum(dO ⊙
+# O), one small XLA reduction a call) are row vectors, ``[BH, Sq/Bq, Bq]``
+# float32, that broadcast down the tile.  The S×S score matrix never exists
+# in HBM.
 # ---------------------------------------------------------------------------
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref, *,
-                   block_k, causal, scale):
-    i = _pl.program_id(1)
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
-    seq_k = k_ref.shape[1]
-    nk = seq_k // block_k
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, causal, scale):
+    """q_ref [1, Sq, D], do_ref [1, Sq, Dv], lse_ref / delta_ref
+    [1, Sq/Bq, Bq] and dq_ref [1, Sq, D] are the head's, resident while its
+    key blocks go by; k_ref / dk_ref [1, Bk, D] and v_ref / dv_ref
+    [1, Bk, Dv] the cell's; dq_acc [Sq, D] float32."""
+    j = _pl.program_id(1)
+    block_k, d, d_v = k_ref.shape[1], k_ref.shape[2], v_ref.shape[2]
+    nq = q_ref.shape[1] // block_q
     prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
+    mm = functools.partial(jax.lax.dot_general, precision=prec,
+                           preferred_element_type=jnp.float32)
 
-    q = q_ref[0]                       # [Bq, D] native dtype
-    do = do_ref[0]                     # [Bq, D]
-    lse = lse_ref[0][:, :1]            # [Bq, 1] fp32 (lane-replicated buffer)
-    # delta = rowsum(do ⊙ o): cheap elementwise reduce done in-kernel so no
-    # extra HBM buffer/pass is needed
-    delta = jnp.sum(do.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
-                    axis=-1, keepdims=True)
+    k = k_ref[0]                       # [Bk, D] native dtype
+    v = v_ref[0]                       # [Bk, Dv]
 
-    def body(j, acc):
-        k = k_ref[0, _pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, _pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec) * scale
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse)           # [Bq, Bk]; masked → exp(-inf) = 0
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec)
-        ds = (p * (dp - delta)).astype(k.dtype)
-        return acc + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec)
+    @_pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     if causal:
-        nk_bound = jnp.minimum(nk, ((i + 1) * block_q + block_k - 1) // block_k)
-    else:
-        nk_bound = nk
-    acc = lax.fori_loop(0, nk_bound, body,
-                        jnp.zeros((block_q, d), jnp.float32))
-    dq_ref[0] = (acc * scale).astype(dq_ref.dtype)
+        # query column - key row inside a block: q_pos - k_pos less the
+        # blocks' offsets.  Every block is masked, those wholly past the
+        # diagonal too: the compare and select hide under the MXU's work,
+        # and an unmasked loop of its own for them measured slower
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+                 - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0))
 
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                    dk_ref, dv_ref, *, block_q, causal, scale):
-    i = _pl.program_id(1)
-    block_k, d, d_v = k_ref.shape[1], k_ref.shape[2], v_ref.shape[2]
-    seq_q = q_ref.shape[1]
-    nq = seq_q // block_q
-    prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
-
-    k = k_ref[0]                       # [Bk, D]
-    v = v_ref[0]                       # [Bk, D]
-
-    def body(j, carry):
+    def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, _pl.ds(j * block_q, block_q), :]
-        do = do_ref[0, _pl.ds(j * block_q, block_q), :]
-        lse = lse_ref[0, _pl.ds(j * block_q, block_q), :1]
-        delta = jnp.sum(
-            do.astype(jnp.float32)
-            * o_ref[0, _pl.ds(j * block_q, block_q), :].astype(jnp.float32),
-            axis=-1, keepdims=True)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec) * scale    # [Bq, Bk]
+        rows = _pl.ds(_pl.multiple_of(i * block_q, block_q), block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, _pl.ds(i, 1), :]          # [1, Bq] fp32
+        delta = delta_ref[0, _pl.ds(i, 1), :]
+        st = mm(k, q, _NT) * scale                 # [Bk, Bq]: sᵀ
         if causal:
-            q_pos = j * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = i * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.exp(s - lse)
-        dv = dv + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=prec)
+            st = jnp.where(ahead >= j * block_k - i * block_q, st, -jnp.inf)
+        pt = jnp.exp(st - lse)                     # masked → exp(-inf) = 0
+        dv = dv + mm(pt.astype(do.dtype), do, _NN)
+        dpt = mm(v, do, _NT)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk = dk + mm(dst, q, _NN)
+        dq_acc[rows, :] += mm(dst, k, _TN)         # [Bq, D]
         return dk, dv
 
-    j0 = (i * block_k) // block_q if causal else 0
+    # from the query block that holds this key block's first key on: the
+    # blocks before it lie wholly in the masked future
+    first = jnp.minimum(nq, (j * block_k) // block_q) if causal else 0
     dk, dv = lax.fori_loop(
-        j0, nq, body,
+        first, nq, body,
         (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d_v), jnp.float32)))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @_pl.when(j == _pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
@@ -380,55 +381,43 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
     sk, d_v = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    grid_q = (bh, sq // block_q)
-    grid_k = (bh, sk // block_k)
+    nq = sq // block_q
+    # the query rows' statistics as rows: the forward's lane-replicated
+    # log-sum-exp, and rowsum(dO ⊙ O) once a row where the kernels used to
+    # recompute it (and read o whole) for every key block
+    lse = lse[:, :, 0].reshape(bh, nq, block_q)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).reshape(bh, nq, block_q)
 
-    full = lambda s, w: _pl.BlockSpec((1, s, w), lambda b, i: (b, 0, 0))
-    blk = lambda rows, w: _pl.BlockSpec((1, rows, w), lambda b, i: (b, i, 0))
-
-    dq = _pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_k=block_k, causal=causal, scale=scale),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=grid_q,
-        in_specs=[
-            blk(block_q, d),                                          # q
-            full(sk, d),                                              # k
-            full(sk, d_v),                                            # v
-            blk(block_q, d_v),                                        # do
-            blk(block_q, d_v),                                        # o
-            blk(block_q, _LANE),                                      # lse
-        ],
-        out_specs=blk(block_q, d),
-        interpret=interpret,
-        compiler_params=_MOSAIC_PARAMS,
-    )(q, k, v, do, o, lse)
-
-    dk, dv = _pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, causal=causal, scale=scale),
-        out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+    head = lambda rows, w: _pl.BlockSpec((1, rows, w), lambda b, j: (b, 0, 0))
+    blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (b, j, 0))
+    return _pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block_q, causal=causal, scale=scale),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
-        grid=grid_k,
+        grid=(bh, sk // block_k),
         in_specs=[
-            full(sq, d),                                              # q
-            blk(block_k, d),                                          # k
-            blk(block_k, d_v),                                        # v
-            full(sq, d_v),                                            # do
-            full(sq, d_v),                                            # o
-            full(sq, _LANE),                                          # lse
+            head(sq, d),                                              # q
+            blk(d),                                                   # k
+            blk(d_v),                                                 # v
+            head(sq, d_v),                                            # do
+            head(nq, block_q),                                        # lse
+            head(nq, block_q),                                        # delta
         ],
-        out_specs=(blk(block_k, d), blk(block_k, d_v)),
+        out_specs=(head(sq, d), blk(d), blk(d_v)),
+        scratch_shapes=[_pltpu.VMEM((sq, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=_MOSAIC_PARAMS,
-    )(q, k, v, do, o, lse)
-    return dq, dk, dv
+        compiler_params=_BWD_PARAMS,
+    )(q, k, v, do, lse, delta)
 
 
 # ---------------------------------------------------------------------------
 # One-tile kernels on the fused QKV projection, read in place.  Up to S 512 a
 # head's whole S×S tile is 1 MB of float32: scores, probabilities and their
 # gradients are computed and consumed in VMEM, the backward computes the scores
-# and the exponentials ONCE (5 matmuls, where the two-pass backward above
-# spends 7), and nothing is transposed: ``[B, S, 3·H·Dh]`` is a whole number of
+# and the exponentials ONCE (5 matmuls, as the blockwise backward above), and
+# nothing is transposed: ``[B, S, 3·H·Dh]`` is a whole number of
 # 128-lane columns a head GROUP (two 64-wide heads), so a (1, S, 128) block of
 # the projection's output hands a grid cell its heads where they lie, and the
 # result is written as ``[B, S, H·Dh]``.  Inside a column the heads are told
@@ -437,10 +426,6 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
 # 128 lanes and a select keeps the head's own — the MXU passes a 64-wide slice
 # would need, and no lane shuffles.
 # ---------------------------------------------------------------------------
-
-_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
-_NN = (((1,), (0,)), ((), ()))   # a · b
-_TN = (((0,), (0,)), ((), ()))   # aᵀ · b
 
 
 def _group_heads(ref_shape, dh):
@@ -651,10 +636,11 @@ def attention_reference(q, k, v, causal=False, scale=None):
 
 
 # Largest block the Pallas kernels tile queries and keys by.  Measured on a
-# v5e at [32 heads, S 4096, 192-wide keys, 128-wide values], bf16, causal
-# (PERF.md, PR 28): forward / backward 7.42 / 21.44 ms at 128 x 128, 3.27 /
-# 7.56 at 256 x 256, 2.34 / 6.56 at 512 x 512, 2.57 / 6.59 at 1024 x 512;
-# 1024-wide key blocks do not fit the kernels' VMEM.
+# v5e at [32 heads, S 4096, 192-wide keys, 128-wide values], bf16, causal,
+# the kernels alone (PERF.md §6, PR 31): forward / backward 2.00 / 4.83 ms at
+# 512 x 512 (queries x keys), 3.07 / 5.21 at 256 x 256, 2.02 / 5.11 at 256 x
+# 512, 2.75 / 4.99 at 512 x 256, 2.27 / 4.90 at 1024 x 512, 2.11 / 5.08 at 512
+# x 1024, 2.13 / 4.87 at 1024 x 1024; 128 x 128 three times slower forward.
 _PALLAS_BLOCK_Q = 512
 _PALLAS_BLOCK_K = 512
 
@@ -673,22 +659,23 @@ def _pallas_blocks(sq, sk, block_q=None, block_k=None):
 
 
 # Where the kernels take over from the XLA path: measured on a v5e
-# (tools/bench_longcontext.py; PERF.md §6, PR 29) on 8,192 tokens of twelve
-# 64-wide bf16 heads read from a fused QKV projection, one layer's forward +
-# backward in ms, head transposes included:
+# (tools/bench_longcontext.py; PERF.md §6, PR 29, re-read by PR 31 with the
+# one-pass backward) on 8,192 tokens of twelve 64-wide bf16 heads read from a
+# fused QKV projection, one layer's forward + backward in ms, head transposes
+# included:
 #
 #        S    XLA   blockwise, blocks of 512 / 256 / 128   one tile in place
-#      128   0.40        —    /   —   / 1.85                     0.60
-#      256   0.92        —    / 1.49  / 2.59                     0.64
-#      384   1.76        —    /   —   / 3.39                     0.67
-#      512   2.41      1.32   / 2.33  / 4.31                     0.70
-#     1024   5.24      2.33   / 3.95  / 7.85                      —
-#     2048  10.15      3.94   / 7.16  / 14.89                     —
+#      128   0.40        —    /   —   / 1.60                     0.60
+#      256   0.93        —    / 1.32  / 1.99                     0.64
+#      384   1.76        —    /   —   / 2.55                     0.67
+#      512   2.41      1.24   / 1.80  / 3.22                     0.69
+#     1024   5.24      1.86   / 2.92  / 5.71                      —
+#     2048  10.14      3.00   / 5.11  / 10.66                     —
 #
 # The XLA path's float32 S×S temporaries make a round trip through HBM each,
 # so its cost grows with S for the same tokens; a kernel's does not, but its
-# fixed costs (transposes, the two-pass backward's recomputation, per-block
-# overheads) only pay from a length on, and only with blocks the MXU fills.
+# fixed costs (transposes, per-block overheads) only pay from a length on, and
+# only with blocks the MXU fills.
 # Earlier thresholds (forward from 1024, backward from 8192) dated from 128 ×
 # 128 blocks, which lose to XLA at every length above.
 _TILE_MIN_SEQ = 256      # the one-tile kernels on a fused QKV projection

@@ -113,7 +113,7 @@ def _self(heads, causal=False):
 
 # what one attention call traces to: (Pallas kernels in the primal, in the VJP
 # forward, in the backward)
-XLA, TILE, BLOCKWISE = (0, 0, 0), (1, 1, 1), (1, 1, 2)
+XLA, TILE, BLOCKWISE = (0, 0, 0), (1, 1, 1), (1, 1, 1)
 
 DISPATCH_CASES = {
     # BERT-base heads from a fused QKV projection: the rule of PERF.md §6, PR 29

@@ -266,7 +266,7 @@ def test_attention_dispatcher_takes_values_narrower_than_keys(path, monkeypatch)
     plain = lambda q, k, v: _plain_attention(q, k, v, scale)
     kernels = str(jax.make_jaxpr(jax.grad(through(system), argnums=(0, 1, 2)))(q, k, v)
                   ).count("pallas_call")
-    assert kernels == (0 if path == "xla" else 3)  # forward, dq pass, dk/dv pass
+    assert kernels == (0 if path == "xla" else 2)  # forward, one-pass backward
     out = system(q, k, v)
     assert out.shape == (1, 256, 2, 16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(plain(q, k, v)), atol=2e-5)
@@ -275,6 +275,150 @@ def test_attention_dispatcher_takes_values_narrower_than_keys(path, monkeypatch)
     for g, w in zip(got, want):
         assert g.shape == w.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 4e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("sq,sk,blocks", [(128, 128, (32, 32)), (64, 128, (32, 64)),
+                                          (128, 64, (64, 32))],
+                         ids=["four-blocks", "fewer-queries", "fewer-keys"])
+@pytest.mark.parametrize("d_qk,d_v", [(192, 128), (64, 64)], ids=["192-128", "64-64"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_one_pass_backward_matches_the_xla_backward(causal, d_qk, d_v, sq, sk, blocks,
+                                                    dtype, tol):
+    """The blockwise kernels' backward (interpreter) is ONE ``pallas_call``
+    whose dq, dk and dv equal ``_flash_bwd_xla``'s on the same operands:
+    query blocks on and past the diagonal, blocks of unlike lengths,
+    ``sq != sk`` (the causal mask stays aligned at position 0), keys wider
+    than values."""
+    ks = jax.random.split(jax.random.PRNGKey(sq + d_qk), 4)
+    q = jax.random.normal(ks[0], (1, 2, sq, d_qk), dtype)
+    k = jax.random.normal(ks[1], (1, 2, sk, d_qk), dtype)
+    v = jax.random.normal(ks[2], (1, 2, sk, d_v), dtype)
+    do = jax.random.normal(ks[3], (1, 2, sq, d_v), dtype)
+    scale = d_qk ** -0.5
+
+    def kernels(q, k, v):
+        return attn_ops._flash_kernels(q, k, v, causal, scale,
+                                       attn_ops._Launch(True, blocks))
+
+    out, pull = jax.vjp(kernels, q, k, v)
+    assert str(jax.make_jaxpr(pull)(do)).count("pallas_call") == 1
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32),
+        np.asarray(attn_ops.attention_reference(q, k, v, causal, scale), np.float32),
+        atol=2 * tol)
+    for got, want in zip(pull(do), attn_ops._flash_bwd_xla(causal, scale, (q, k, v), do)):
+        assert got.shape == want.shape and got.dtype == dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), atol=tol)
+
+
+def _fwd_kernel_of_pr30(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
+    """The blockwise forward kernel as PR 30 left it, kept here as what the
+    forward's bits are held to: the causal mask and
+    ``online_softmax_update``'s guards on EVERY block."""
+    pl = attn_ops._pl
+    i = pl.program_id(1)
+    block_q = q_ref.shape[1]
+    nk = k_ref.shape[1] // block_k
+    prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    q = q_ref[0]
+    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
+    acc0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
+
+    def body(j, carry):
+        m, l, acc = carry
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec) * scale
+        if causal:
+            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+        acc_new, m_new, l_new = attn_ops.online_softmax_update(
+            acc, m, l, s, v,
+            lambda p, v: jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec))
+        return m_new, l_new, acc_new
+
+    nk_bound = (jnp.minimum(nk, ((i + 1) * block_q + block_k - 1) // block_k)
+                if causal else nk)
+    m, l, acc = jax.lax.fori_loop(0, nk_bound, body, (m0, l0, acc0))
+    l = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
+    lse_ref[0] = jnp.broadcast_to(m_safe + jnp.log(l), lse_ref.shape[1:])
+
+
+class _EagerRef:
+    """A kernel's ``Ref`` outside a ``pallas_call``: under ``disable_jit`` the
+    body then runs operation by operation, so two bodies that do the same
+    operations give the same bits (inside one jitted program, the
+    interpreter's included, the CPU compiler's fusion decides the last one)."""
+
+    def __init__(self, value):
+        self.value = value
+
+    shape = property(lambda self: self.value.shape)
+    dtype = property(lambda self: self.value.dtype)
+
+    @staticmethod
+    def _index(index):
+        index = index if isinstance(index, tuple) else (index,)
+        return tuple(slice(int(i.start), int(i.start) + i.size)
+                     if isinstance(i, attn_ops._pl.Slice) else i for i in index)
+
+    def __getitem__(self, index):
+        return self.value[self._index(index)]
+
+    def __setitem__(self, index, value):
+        self.value = self.value.at[self._index(index)].set(value)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32), (32, 64)], ids=str)
+def test_forward_without_the_dead_row_guards_keeps_every_bit(blocks, dtype, monkeypatch):
+    """Causal, 128 rows in several blocks, 192-wide keys and 128-wide values:
+    the forward kernel, whose online-softmax update has dropped the guards
+    for rows with no live key (``_live_softmax_update``: under the causal
+    mask every row sees key 0 in its first block), gives PR 30's output and
+    log-sum-exp bit for bit, grid cell by grid cell; and through the
+    interpreter it equals ``attention_reference``."""
+    s, scale = 128, 192 ** -0.5
+    block_q, block_k = blocks
+    ks = jax.random.split(jax.random.PRNGKey(31), 3)
+    q = jax.random.normal(ks[0], (1, s, 192), dtype)
+    k = jax.random.normal(ks[1], (1, s, 192), dtype)
+    v = jax.random.normal(ks[2], (1, s, 128), dtype)
+
+    def cell(kernel, i):
+        monkeypatch.setattr(attn_ops._pl, "program_id", lambda axis: (0, i)[axis])
+        o = _EagerRef(jnp.zeros((1, block_q, 128), dtype))
+        lse = _EagerRef(jnp.zeros((1, block_q, attn_ops._LANE), jnp.float32))
+        with jax.disable_jit():
+            kernel(_EagerRef(q[:, i * block_q:(i + 1) * block_q]), _EagerRef(k),
+                   _EagerRef(v), o, lse, block_k=block_k, causal=True, scale=scale)
+        return np.asarray(o.value, np.float32), np.asarray(lse.value)
+
+    rows = []
+    for i in range(s // block_q):
+        got, want = cell(attn_ops._fwd_kernel, i), cell(_fwd_kernel_of_pr30, i)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+        rows.append(got[0])
+    monkeypatch.undo()
+    out = attn_ops._flash_fwd_pallas(q, k, v, True, scale, True, *blocks)
+    want = attn_ops.attention_reference(q, k, v, True, scale)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32),
+                               atol=tol)
+    np.testing.assert_allclose(np.concatenate(rows, 1), np.asarray(out, np.float32), atol=tol)
 
 
 def test_large_scores_take_the_blockwise_kernels(monkeypatch):
@@ -294,7 +438,7 @@ def test_large_scores_take_the_blockwise_kernels(monkeypatch):
     monkeypatch.setattr(attn_ops, "_KERNEL_MIN_SCORE_BYTES", 4 * 2 * 128 * 128)
     assert attn_ops._kernel_path(q, k, seq_axis=1) == (
         "blockwise", attn_ops._Launch(False, (128, 128)))
-    assert kernels() == 3  # forward, dq pass, dk/dv pass
+    assert kernels() == 2  # forward, one-pass backward
 
 
 def test_fused_attention_merges_heads_at_the_values_width():

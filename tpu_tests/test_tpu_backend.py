@@ -139,28 +139,31 @@ class TestFlashOnChip:
             np.asarray(out, dtype=np.float32), np.asarray(ref, dtype=np.float32),
             rtol=2e-2, atol=2e-2)
 
-    def test_pallas_bwd_matches_xla_on_tpu(self, monkeypatch):
+    @pytest.mark.parametrize("heads,s,d_qk,d_v", [(1, 512, 64, 64), (4, 4096, 192, 128)],
+                             ids=["s512", "xing-cell-4-heads"])
+    def test_pallas_bwd_matches_xla_on_tpu(self, heads, s, d_qk, d_v):
+        """The blockwise kernels by the dispatcher's own rule, forward and the
+        one-pass backward (two Mosaic calls in the gradient's lowering), at S
+        512 and at the decoder cell's shape cut in heads only: 192-wide
+        queries and keys, 128-wide values, S 4096, causal, bf16."""
         import jax
         import jax.numpy as jnp
         from incubator_mxnet_tpu.ops import attention as att
 
-        # S 512 takes the blockwise kernels, forward and backward, by the
-        # dispatcher's own rule
-        q = jnp.asarray(_r(1, 1, 512, 64)).astype(jnp.bfloat16)
-        assert att._kernel_path(q, q) == ("blockwise", att._Launch(False, (512, 512)))
+        q, k = (jnp.asarray(_r(1, heads, s, d_qk)).astype(jnp.bfloat16) for _ in range(2))
+        v = jnp.asarray(_r(1, heads, s, d_v)).astype(jnp.bfloat16)
+        assert att._kernel_path(q, k) == ("blockwise", att._Launch(False, (512, 512)))
 
-        def loss_flash(x):
-            return (att.flash_attention(x, x, x, causal=True) ** 2).sum().astype(jnp.float32)
+        def loss(attend):
+            return lambda q, k, v: (attend(q, k, v, causal=True) ** 2).sum().astype(jnp.float32)
 
-        g_flash = jax.grad(loss_flash)(q)
-
-        def loss_ref(x):
-            return (att.attention_reference(x, x, x, causal=True) ** 2).sum().astype(jnp.float32)
-
-        g_ref = jax.grad(loss_ref)(q)
-        np.testing.assert_allclose(
-            np.asarray(g_flash, dtype=np.float32), np.asarray(g_ref, dtype=np.float32),
-            rtol=5e-2, atol=5e-2)
+        grad = jax.jit(jax.grad(loss(att.flash_attention), argnums=(0, 1, 2)))
+        assert grad.lower(q, k, v).as_text().count("tpu_custom_call") == 2
+        g_ref = jax.grad(loss(att.attention_reference), argnums=(0, 1, 2))(q, k, v)
+        for got, want in zip(grad(q, k, v), g_ref):
+            np.testing.assert_allclose(
+                np.asarray(got, dtype=np.float32), np.asarray(want, dtype=np.float32),
+                rtol=5e-2, atol=5e-2)
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_one_tile_kernels_match_float32_reference_on_tpu(self, causal):
