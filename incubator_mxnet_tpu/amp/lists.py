@@ -18,7 +18,7 @@ TARGET_OPS = {
     "Convolution", "Deconvolution",
     "dot", "batch_dot", "linalg_gemm2",
     "fused_attention", "fused_qkv_attention", "fused_kv_attention",
-    "latent_attention", "swiglu_ffn",
+    "latent_attention", "swiglu_ffn", "relu2_ffn",
     "RNN",
     # Embedding output feeds the transformer residual stream; emitting it
     # in the target dtype keeps that stream bf16 end-to-end (the norms
@@ -41,6 +41,16 @@ FP32_OPS = {
     "smooth_l1", "MakeLoss",
     "power", "broadcast_power", "_power_scalar", "sqrt", "rsqrt", "square",
 }
+
+# The state-space ops (ops/ssm.py: causal_conv1d, ssm_scan, gated_rms_norm,
+# mamba2_mixer) are in NO tier, like moe_ffn_dropless and the mHC mixes: they
+# take their compute dtype from the activations they are handed (bf16 under
+# AMP: the matrix products ride the MXU in it) and keep in float32, whatever
+# arrives, the step (softplus), the decays, their cumulative sums and
+# exponentials, the carried state, the convolution's sum and the gated norm's
+# statistics; A_log, dt_bias and D stay float32 parameters under
+# ``cast("bfloat16")``.  A cast of every input, up or down, would be wrong
+# for one half of them.
 
 WIDEST_OPS = {
     "broadcast_add", "broadcast_sub", "broadcast_mul", "broadcast_div",

@@ -12,11 +12,12 @@ exactly as ``BERTForPretrain`` is.  What this file adds to the program:
   with an RMSNorm between, rotary (YaRN) on the shared rope part, the core
   through the attention dispatcher with 192-wide queries and keys and
   128-wide values.
-* :class:`SparseExperts` — routes over ALL ``n_routed_experts`` and computes
-  the part of the sum given by the experts this chip HOLDS
-  (``experts_held = (first, count)``), plus the shared expert, which every
-  chip computes alike.  Nothing stands in for the absent chips: on one chip
-  the layer runs without its exchange, and the partial sum goes on.
+* :class:`SparseExperts` (``decoder.py``: ``nemotron_h`` shares it) —
+  routes over ALL ``n_routed_experts`` and computes the part of the sum given
+  by the experts this chip HOLDS (``experts_held = (first, count)``), plus
+  the shared expert, which every chip computes alike.  Nothing stands in for
+  the absent chips: on one chip the layer runs without its exchange, and the
+  partial sum goes on.
 * :class:`Xing4Block` / :class:`Xing4Model` / :class:`Xing4ForCausalLM`.
 
 ``remat=True`` wraps every block in ``jax.checkpoint`` under a jit trace
@@ -34,9 +35,9 @@ import math
 import numpy as _np
 
 from ... import initializer as _init
-from ..block import HybridBlock, collect_aux_update
-from ..nn import Dense, Embedding
-from . import moe as _moe
+from ..block import HybridBlock
+from ..nn import Embedding
+from .decoder import CausalLM, RMSNorm, SparseExperts, SwiGLU, run_layer
 
 __all__ = ["HyperConnection", "LatentAttention", "SwiGLU", "SparseExperts",
            "Xing4Block", "Xing4Model", "Xing4ForCausalLM",
@@ -50,12 +51,6 @@ SCOPE_MIX = "xing.mix"
 SCOPE_HEAD = "xing.head"
 
 
-def _scope(name):
-    import jax
-
-    return jax.named_scope(name)
-
-
 def mhc_offset_init(n, off_diagonal=-8.0):
     """``b_pre | b_post | b_res`` for which, at ``α·m = 0``, ``H_pre`` is
     ``1/n`` on every stream (the sublayer reads the streams' mean),
@@ -66,17 +61,6 @@ def mhc_offset_init(n, off_diagonal=-8.0):
     b_post = _np.zeros((n,))
     b_res = _np.full((n, n), off_diagonal) * (1.0 - _np.eye(n))
     return _np.concatenate([b_pre, b_post, b_res.reshape(-1)]).astype("float32")
-
-
-class RMSNorm(HybridBlock):
-    def __init__(self, units, eps=1e-6, prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        self._eps = float(eps)
-        with self.name_scope():
-            self.gamma = self.params.get("gamma", shape=(units,), init="ones")
-
-    def hybrid_forward(self, F, x, gamma):
-        return F.RMSNorm(x, gamma, eps=self._eps)
 
 
 class HyperConnection(HybridBlock):
@@ -158,98 +142,6 @@ class LatentAttention(HybridBlock):
             kv_a_norm_gamma, kv_b_weight, o_weight, **self._kw)
 
 
-class SwiGLU(HybridBlock):
-    """``W_down (silu(W_gate x) ⊙ W_up x)``, gate and up as one weight."""
-
-    def __init__(self, units, hidden_size, prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        with self.name_scope():
-            self.gate_up_weight = self.params.get(
-                "gate_up_weight", shape=(2 * hidden_size, units))
-            self.down_weight = self.params.get(
-                "down_weight", shape=(units, hidden_size))
-
-    def hybrid_forward(self, F, x, gate_up_weight, down_weight):
-        return F.contrib.swiglu_ffn(x, gate_up_weight, down_weight)
-
-
-class SparseExperts(HybridBlock):
-    """Dropless routed experts + the shared expert, for the experts held.
-
-    ``experts_held = (first, count)``: this chip holds the routed experts
-    ``first .. first + count - 1`` of ``num_experts`` (default: all).  The
-    router and its selection bias cover all ``num_experts``; a pair routed
-    to an expert held elsewhere adds nothing here.
-
-    The selection bias is the ``noaux_tc`` balancing buffer: no gradient
-    reaches it; in training every step moves it by ``bias_update_speed``
-    towards balance, ``b_e += γ · sign(mean load − load_e)`` over ALL the
-    experts (:meth:`balanced_bias`; 0 freezes it).  It stays float32 under
-    ``cast``: a step of 0.001 is below bf16's resolution at 0.5.
-
-    ``forward`` returns ``(y, stats)``: ``stats`` is a float32 vector
-    ``(rows routed here, least load, greatest load)`` over the experts held
-    followed by the load of each of all the experts, which
-    :class:`Xing4Block` hands to the trainer's MoE frame and to the rule."""
-
-    def __init__(self, units, expert_width, num_experts, top_k,
-                 experts_held=None, n_shared_experts=1, routed_scaling=1.0,
-                 norm_topk=True, bias_update_speed=0.001,
-                 prefix=None, params=None):
-        super().__init__(prefix=prefix, params=params)
-        self._bias_speed = float(bias_update_speed)
-        first, count = experts_held or (0, num_experts)
-        if not (0 <= first and first + count <= num_experts and count > 0):
-            raise ValueError(f"experts_held {experts_held} outside "
-                             f"0..{num_experts}")
-        if top_k > num_experts:
-            raise ValueError(f"top_k {top_k} > num_experts {num_experts}")
-        self._kw = dict(num_experts=int(num_experts), top_k=int(top_k),
-                        first_expert=int(first),
-                        routed_scaling=float(routed_scaling),
-                        norm_topk=bool(norm_topk))
-        with self.name_scope():
-            get = self.params.get
-            self.router_weight = get("router_weight",
-                                     shape=(num_experts, units))
-            # the noaux_tc selection bias: a buffer that a balancing rule
-            # outside the gradient would move; no gradient reaches it
-            self.select_bias = get("select_bias", shape=(num_experts,),
-                                   init="zeros", grad_req="null")
-            self.experts_gate_up_weight = get(
-                "experts_gate_up_weight",
-                shape=(count, units, 2 * expert_width))
-            self.experts_down_weight = get(
-                "experts_down_weight", shape=(count, expert_width, units))
-            self.shared_expert = (
-                SwiGLU(units, expert_width * int(n_shared_experts),
-                       prefix="shared_")
-                if n_shared_experts else None)
-
-    def hybrid_forward(self, F, x, router_weight, select_bias,
-                       experts_gate_up_weight, experts_down_weight):
-        y, rows, load_min, load_max, load_all = F.contrib.moe_ffn_dropless(
-            x, router_weight, select_bias, experts_gate_up_weight,
-            experts_down_weight, scope=SCOPE_MOE, **self._kw)
-        if self.shared_expert is not None:
-            with _scope(SCOPE_MOE + ".shared"):
-                y = y + self.shared_expert(x)
-        return y, F.concat(F.stack(rows, load_min, load_max), load_all, dim=0)
-
-    def cast(self, dtype):
-        super().cast(dtype)
-        self.select_bias.cast("float32")
-        return self
-
-    def balanced_bias(self, bias, load_all):
-        """One step of the ``noaux_tc`` rule on raw arrays: the bias of an
-        expert with less than the mean load rises by ``bias_update_speed``,
-        that of one with more falls."""
-        import jax.numpy as jnp
-
-        return bias + self._bias_speed * jnp.sign(load_all.mean() - load_all)
-
-
 class Xing4Block(HybridBlock):
     """One decoder layer on the residual state ``[n, B, S, d]``: latent
     attention, then a SwiGLU (the leading dense layers) or
@@ -287,7 +179,7 @@ class Xing4Block(HybridBlock):
                     c["n_shared_experts"], c["routed_scaling_factor"],
                     c["norm_topk_prob"],
                     bias_update_speed=c.get("bias_update_speed", 0.001),
-                    prefix="moe_")
+                    scope=SCOPE_MOE, prefix="moe_")
         self._sparse = not dense
 
     def _body(self, state):
@@ -302,35 +194,8 @@ class Xing4Block(HybridBlock):
         return self.ffn_mix.merge(state, y, h_post, h_res), stats
 
     def forward(self, state):
-        import jax
-
-        from ... import autograd
-        from ...ndarray.ndarray import NDArray
-
-        traced = (isinstance(state._data, jax.core.Tracer)
-                  and not autograd.is_recording())
-        if self._remat and traced:
-            def body(data):
-                out, stats = self._body(NDArray(data))
-                return out._data, None if stats is None else stats._data
-
-            out, stats = jax.checkpoint(body)(state._data)
-            out = NDArray(out)
-        else:
-            out, stats = self._body(state)
-            stats = None if stats is None else stats._data
-        if stats is not None:
-            # outside the checkpoint: what the frame and the aux collector
-            # keep must belong to the step's own trace
-            _moe.register_metrics({
-                "rows_routed_here": stats[0], "expert_load_min": stats[1],
-                "expert_load_max": stats[2], "expert_load_all": stats[3:],
-                "tokens_dropped": 0.0 * stats[0]})   # dropless
-            if autograd.is_training() and self.ffn._bias_speed:
-                bias = self.ffn.select_bias
-                collect_aux_update(bias, NDArray(self.ffn.balanced_bias(
-                    bias.data()._data, stats[3:])))
-        return out
+        return run_layer(self._body, state, self._remat,
+                         self.ffn if self._sparse else None)
 
 
 class Xing4Model(HybridBlock):
@@ -375,22 +240,13 @@ class Xing4Model(HybridBlock):
         return self.norm(F.sum(state, axis=0))
 
 
-class Xing4ForCausalLM(HybridBlock):
+class Xing4ForCausalLM(CausalLM):
     """:class:`Xing4Model` and the untied output head: token ids ``[B, S]``
     → logits ``[B, S, vocab]`` (``vocab_size`` may be this chip's slice)."""
 
     def __init__(self, config, experts_held=None, remat=False, prefix=None,
                  params=None):
-        super().__init__(prefix=prefix, params=params)
-        with self.name_scope():
-            self.model = Xing4Model(config, experts_held, remat,
-                                    prefix="model_")
-            self.lm_head = Dense(config["vocab_size"], use_bias=False,
-                                 flatten=False,
-                                 in_units=config["hidden_size"],
-                                 prefix="lm_head_")
-
-    def forward(self, token_ids):
-        hidden = self.model(token_ids)
-        with _scope(SCOPE_HEAD):
-            return self.lm_head(hidden)
+        super().__init__(
+            lambda prefix: Xing4Model(config, experts_held, remat, prefix=prefix),
+            config["vocab_size"], config["hidden_size"], SCOPE_HEAD,
+            prefix=prefix, params=params)

@@ -254,13 +254,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
         lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
 
 
+def _kv_row(rows, kv_rows):
+    """Query row (batch·head) → the key/value row it reads.  With as many
+    key/value heads as query heads it is the row itself, and the index maps
+    are the ones they always were."""
+    if rows % kv_rows:
+        raise ValueError(f"{rows} query rows do not divide over {kv_rows} "
+                         f"key/value rows")
+    group = rows // kv_rows
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=128,
                       with_lse=False):
-    """q/k: [BH, S, D], v: [BH, S, Dv] (batch·heads flattened).
-    ``with_lse=True`` also returns the per-row log-sum-exp [BH, S] for the
-    blockwise backward."""
+    """q: [BH, S, D], k: [BHkv, Sk, D], v: [BHkv, Sk, Dv] (batch·heads
+    flattened; grouped-query heads have BH = group · BHkv and query row ``r``
+    reads key/value row ``r // group``: the block index does it, nothing is
+    repeated in memory).  ``with_lse=True`` also returns the per-row
+    log-sum-exp [BH, S] for the blockwise backward."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
+    kv_row = _kv_row(bh, k.shape[0])
     o_shape = (bh, sq, dv)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -285,8 +299,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
         grid=grid,
         in_specs=[
             _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _pl.BlockSpec((1, sk, d), lambda b, i: (b, 0, 0)),
-            _pl.BlockSpec((1, sk, dv), lambda b, i: (b, 0, 0)),
+            _pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
+            _pl.BlockSpec((1, sk, dv), lambda b, i: (kv_row(b), 0, 0)),
         ],
         out_specs=out_specs,
         interpret=interpret,
@@ -375,10 +389,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
                       block_q=128, block_k=128):
-    """q/k: [BH, S, D]; v/do/o: [BH, S, Dv]; lse: [BH, Sq, _LANE] fp32
-    → (dq, dk, dv)."""
+    """q: [BH, S, D]; k: [BHkv, Sk, D]; v: [BHkv, Sk, Dv]; do/o: [BH, S,
+    Dv]; lse: [BH, Sq, _LANE] fp32 → (dq, dk, dv).  With grouped-query heads
+    each query head's cell writes ITS dk and dv, in float32, and the group's
+    are summed after the kernel (one small XLA reduction a call)."""
     bh, sq, d = q.shape
-    sk, d_v = k.shape[1], v.shape[2]
+    bkv, sk, d_v = k.shape[0], k.shape[1], v.shape[2]
+    kv_row, group = _kv_row(bh, bkv), bh // bkv
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     nq = sq // block_q
@@ -391,16 +408,17 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
 
     head = lambda rows, w: _pl.BlockSpec((1, rows, w), lambda b, j: (b, 0, 0))
     blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (b, j, 0))
-    return _pl.pallas_call(
+    kv_blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (kv_row(b), j, 0))
+    part = (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)) if group == 1 else (
+        lambda a: jax.ShapeDtypeStruct((bh,) + a.shape[1:], jnp.float32))
+    dq, dk, dv = _pl.pallas_call(
         functools.partial(_bwd_kernel, block_q=block_q, causal=causal, scale=scale),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), part(k), part(v)),
         grid=(bh, sk // block_k),
         in_specs=[
             head(sq, d),                                              # q
-            blk(d),                                                   # k
-            blk(d_v),                                                 # v
+            kv_blk(d),                                                # k
+            kv_blk(d_v),                                              # v
             head(sq, d_v),                                            # do
             head(nq, block_q),                                        # lse
             head(nq, block_q),                                        # delta
@@ -410,6 +428,10 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
         interpret=interpret,
         compiler_params=_BWD_PARAMS,
     )(q, k, v, do, lse, delta)
+    if group > 1:
+        dk, dv = (a.reshape((bkv, group) + a.shape[1:]).sum(axis=1).astype(like.dtype)
+                  for a, like in ((dk, k), (dv, v)))
+    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------
@@ -720,27 +742,51 @@ def _kernel_path(q, k, seq_axis=2, qkv_heads=None):
     return "xla", None
 
 
-def _count_dispatch(kernels):
+def _count_dispatch(kernels, grouped=False):
     """One count a traced call site: dispatch is decided at trace time, so
     after a step has compiled the two counters say which path every
-    attention call of the program took."""
+    attention call of the program took (and the third how many of them had
+    fewer key/value heads than query heads)."""
     from .. import profiler
 
     if kernels:
         profiler.incr("attention_dispatch_pallas")
     else:
         profiler.incr("attention_dispatch_xla")
+    if grouped:
+        profiler.incr("attention_dispatch_grouped")
+
+
+def _head_group(q, k, head_axis):
+    """Query heads a key/value head (1: plain multi-head attention)."""
+    h, h_kv = q.shape[head_axis], k.shape[head_axis]
+    if h % h_kv:
+        raise ValueError(f"{h} query heads do not divide over {h_kv} "
+                         f"key/value heads")
+    return h // h_kv
+
+
+def _repeat_heads(k, v, group, head_axis):
+    """The XLA paths' grouped-query form: each key/value head repeated for
+    its group's query heads (short sequences only reach them); the repeat's
+    own transpose sums the group's dk and dv."""
+    if group == 1:
+        return k, v
+    return (jnp.repeat(k, group, axis=head_axis),
+            jnp.repeat(v, group, axis=head_axis))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_kernels(q, k, v, causal, scale, launch):
-    """[B, H, S, D] attention in the blockwise kernels, forward and backward."""
+    """[B, H, S, D] attention in the blockwise kernels, forward and backward;
+    ``k`` and ``v`` [B, Hkv, Sk, ·] may have fewer heads (grouped-query: query
+    head ``h`` reads key/value head ``h // (H / Hkv)``)."""
     b, h, s, d = q.shape
     out = _on_mesh(
         launch, lambda *qkv: _flash_fwd_pallas(
             *qkv, causal, scale, launch.interpret, *launch.blocks),
-        q.reshape(b * h, s, d), k.reshape(b * h, -1, d),
-        v.reshape(b * h, -1, v.shape[-1]))
+        q.reshape(b * h, s, d), k.reshape(b * k.shape[1], -1, d),
+        v.reshape(b * v.shape[1], -1, v.shape[-1]))
     return out.reshape(b, h, s, v.shape[-1])
 
 
@@ -748,11 +794,11 @@ def _flash_kernels_fwd(q, k, v, causal, scale, launch):
     """VJP forward: also save (o, lse) so the backward runs blockwise
     without ever materializing S×S."""
     b, h, s, d = q.shape
-    sk, d_v = k.shape[2], v.shape[-1]
+    h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[-1]
     out, lse = _on_mesh(
         launch, lambda *qkv: _flash_fwd_pallas(
             *qkv, causal, scale, launch.interpret, *launch.blocks, with_lse=True),
-        q.reshape(b * h, s, d), k.reshape(b * h, sk, d), v.reshape(b * h, sk, d_v))
+        q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d), v.reshape(b * h_kv, sk, d_v))
     out = out.reshape(b, h, s, d_v)
     return out, (q, k, v, out, lse)
 
@@ -760,12 +806,12 @@ def _flash_kernels_fwd(q, k, v, causal, scale, launch):
 def _flash_kernels_bwd(causal, scale, launch, res, do):
     q, k, v, o, lse = res
     b, h, s, d = q.shape
-    sk, d_v = k.shape[2], v.shape[-1]
+    h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[-1]
     dq, dk, dv = _on_mesh(
         launch, lambda *arrays: _flash_bwd_pallas(
             *arrays, causal, scale, launch.interpret, *launch.blocks),
-        q.reshape(b * h, s, d), k.reshape(b * h, sk, d),
-        v.reshape(b * h, sk, d_v), do.reshape(b * h, s, d_v),
+        q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d),
+        v.reshape(b * h_kv, sk, d_v), do.reshape(b * h, s, d_v),
         o.reshape(b * h, s, d_v), lse)
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -813,14 +859,17 @@ _flash_xla.defvjp(_flash_xla_fwd, _flash_bwd_xla)
 
 
 def flash_attention(q, k, v, causal=False, scale=None):
-    """Fused attention on [B, H, S, D] arrays; differentiable; bf16-safe."""
+    """Fused attention on [B, H, S, D] arrays; differentiable; bf16-safe.
+    ``k`` and ``v`` may have fewer heads, [B, Hkv, Sk, ·] with Hkv dividing H
+    (grouped-query attention)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    group = _head_group(q, k, 1)
     path, launch = _kernel_path(q, k)
-    _count_dispatch(path != "xla")
+    _count_dispatch(path != "xla", group > 1)
     if path == "blockwise":
         return _flash_kernels(q, k, v, causal, float(scale), launch)
-    return _flash_xla(q, k, v, causal, float(scale))
+    return _flash_xla(q, *_repeat_heads(k, v, group, 1), causal, float(scale))
 
 
 # ---------------------------------------------------------------------------
@@ -900,38 +949,45 @@ def _attend_bshd(q, k, v, causal, scale):
     round it in a device trace."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    group = _head_group(q, k, 2)
     path, launch = _kernel_path(q, k, seq_axis=1)
-    _count_dispatch(path != "xla")
+    _count_dispatch(path != "xla", group > 1)
     with jax.named_scope("attn.core"):
         if path == "blockwise":
             t = lambda x: x.transpose(0, 2, 1, 3)
             out = _flash_kernels(t(q), t(k), t(v), causal, float(scale), launch)
             return out.transpose(0, 2, 1, 3)
-        return _flash_bshd(q, k, v, causal, float(scale))
+        return _flash_bshd(q, *_repeat_heads(k, v, group, 2), causal, float(scale))
 
 
 from .registry import register  # noqa: E402
 
 
 @register("fused_attention")
-def fused_attention(q, k, v, num_heads=1, causal=False, scale=None):
+def fused_attention(q, k, v, num_heads=1, causal=False, scale=None,
+                    kv_heads=None):
     """[B, S, D] convenience form: split heads → flash attention → merge
     (``v`` [B, S, H·Dv] with its own head size).  Registered so it is reachable as ``nd.fused_attention`` /
     ``nd.contrib.fused_attention`` (the role cuDNN fused MHA plays for the
-    reference's GPU builds)."""
+    reference's GPU builds).  ``kv_heads`` (default ``num_heads``) is the
+    number of key/value heads of grouped-query attention: ``k`` [B, S,
+    kv_heads·Dh] and ``v`` [B, S, kv_heads·Dv], query head ``h`` reading
+    key/value head ``h // (num_heads / kv_heads)``."""
     b, s, d = q.shape
     h = num_heads
-    if d % h or v.shape[-1] % h or k.shape[-1] != d:
+    h_kv = h if kv_heads is None else int(kv_heads)
+    if (d % h or v.shape[-1] % h_kv or h % h_kv
+            or k.shape[-1] * h != d * h_kv):
         raise ValueError(f"feature dims {d}/{k.shape[-1]}/{v.shape[-1]} do "
-                         f"not split into num_heads {h}")
+                         f"not split into num_heads {h}, kv_heads {h_kv}")
 
-    def split(x):
-        return x.reshape(b, x.shape[1], h, x.shape[-1] // h)
+    def split(x, heads):
+        return x.reshape(b, x.shape[1], heads, x.shape[-1] // heads)
 
     # v may be narrower or wider a head than q and k (latent attention:
     # 192-wide queries and keys, 128-wide values); the output has v's width
-    out = _attend_bshd(split(q), split(k), split(v), causal, scale)
-    return out.reshape(b, s, v.shape[-1])
+    out = _attend_bshd(split(q, h), split(k, h_kv), split(v, h_kv), causal, scale)
+    return out.reshape(b, s, h * (v.shape[-1] // h_kv))
 
 
 @register("fused_qkv_attention")

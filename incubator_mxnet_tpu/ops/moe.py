@@ -132,11 +132,13 @@ def dropless_row_buckets(n_pairs, count, num_experts):
 
 
 def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
-                     w_gate_up, w_down, top_k):
-    """The held experts' SwiGLU on the first ``rows`` sorted pairs, summed
-    into their tokens: ``[T, d]`` float32.  Pairs past ``n_here`` belong to
-    experts held elsewhere: they add nothing (and ``ragged_dot`` leaves
-    their rows undefined, so they are masked, not trusted)."""
+                     w_in, w_down, top_k, form):
+    """The held experts' inner function (``form``: SwiGLU on a ``gate | up``
+    weight, or the non-gated ``relu(·)²`` on an ``up`` weight) on the first
+    ``rows`` sorted pairs, summed into their tokens: ``[T, d]`` float32.
+    Pairs past ``n_here`` belong to experts held elsewhere: they add nothing
+    (and ``ragged_dot`` leaves their rows undefined, so they are masked, not
+    trusted)."""
     cdt = xt.dtype
     pair = order[:rows]
     token = pair // top_k
@@ -157,16 +159,21 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
         out = jax.lax.ragged_dot(a, w.astype(cdt), group_sizes, precision=prec)
         return jnp.where(ours, out, 0)
 
-    gu = grouped(xt[token], w_gate_up)                         # [rows, 2h]
-    h = w_down.shape[1]
-    act = (jax.nn.silu(gu[:, :h]) * gu[:, h:]).astype(cdt)
+    up = grouped(xt[token], w_in)                    # [rows, 2h] or [rows, h]
+    if form == "swiglu":
+        h = w_down.shape[1]
+        act = (jax.nn.silu(up[:, :h]) * up[:, h:]).astype(cdt)
+    elif form == "relu2":
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(cdt)
+    else:
+        raise ValueError(f"expert_form {form!r}: 'swiglu' or 'relu2'")
     ys = grouped(act, w_down)                                  # [rows, d]
     ys = ys.astype(jnp.float32) * gate_flat[pair][:, None]
     return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _experts_in_bucket(buckets, top_k, xt, gate_flat, w_gate_up, w_down,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _experts_in_bucket(buckets, top_k, form, xt, gate_flat, w_in, w_down,
                        order, group_sizes, n_here):
     """:func:`_experts_on_rows` at the smallest of ``buckets`` that holds
     ``n_here`` rows.  The derivative is its own rule and not that of
@@ -176,38 +183,38 @@ def _experts_in_bucket(buckets, top_k, xt, gate_flat, w_gate_up, w_down,
     at 64 rows on the v5e.  So the forward keeps only what it was given, and
     the backward switches again and runs the chosen branch's forward and
     derivative together: one more forward of the held experts' products."""
-    branches = [functools.partial(_experts_on_rows, rows, top_k=top_k)
+    branches = [functools.partial(_experts_on_rows, rows, top_k=top_k, form=form)
                 for rows in buckets]
     return jax.lax.switch(_bucket_of(buckets, n_here), branches, xt, order,
-                          gate_flat, group_sizes, n_here, w_gate_up, w_down)
+                          gate_flat, group_sizes, n_here, w_in, w_down)
 
 
 def _bucket_of(buckets, n_here):
     return jnp.searchsorted(jnp.asarray(buckets, jnp.int32), n_here)
 
 
-def _experts_in_bucket_fwd(buckets, top_k, *args):
-    return _experts_in_bucket(buckets, top_k, *args), args
+def _experts_in_bucket_fwd(buckets, top_k, form, *args):
+    return _experts_in_bucket(buckets, top_k, form, *args), args
 
 
-def _experts_in_bucket_bwd(buckets, top_k, args, ct):
+def _experts_in_bucket_bwd(buckets, top_k, form, args, ct):
     from .nn import _zero_cotangent
 
-    xt, gate_flat, w_gate_up, w_down, order, group_sizes, n_here = args
+    xt, gate_flat, w_in, w_down, order, group_sizes, n_here = args
 
     def branch(rows):
-        def run(xt, gate_flat, w_gate_up, w_down, ct):
+        def run(xt, gate_flat, w_in, w_down, ct):
             _, pullback = jax.vjp(
-                lambda xt, gate_flat, w_gate_up, w_down: _experts_on_rows(
+                lambda xt, gate_flat, w_in, w_down: _experts_on_rows(
                     rows, xt, order, gate_flat, group_sizes, n_here,
-                    w_gate_up, w_down, top_k),
-                xt, gate_flat, w_gate_up, w_down)
+                    w_in, w_down, top_k, form),
+                xt, gate_flat, w_in, w_down)
             return pullback(ct)
         return run
 
     grads = jax.lax.switch(_bucket_of(buckets, n_here),
                            [branch(rows) for rows in buckets],
-                           xt, gate_flat, w_gate_up, w_down, ct)
+                           xt, gate_flat, w_in, w_down, ct)
     return tuple(grads) + tuple(
         _zero_cotangent(a) for a in (order, group_sizes, n_here))
 
@@ -216,17 +223,22 @@ _experts_in_bucket.defvjp(_experts_in_bucket_fwd, _experts_in_bucket_bwd)
 
 
 @register("moe_ffn_dropless")
-def moe_ffn_dropless(x, router_w, select_bias, w_gate_up, w_down,
+def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
                      num_experts=1, top_k=1, first_expert=0,
-                     routed_scaling=1.0, norm_topk=True, scope="moe"):
-    """Dropless top-k routed SwiGLU experts over the last axis of ``x``: the
-    part of the layer's sum that the experts HELD HERE give.
+                     routed_scaling=1.0, norm_topk=True, scope="moe",
+                     expert_form="swiglu"):
+    """Dropless top-k routed experts over the last axis of ``x``: the part of
+    the layer's sum that the experts HELD HERE give.
 
     Shapes: ``x`` [..., d]; ``router_w`` [E, d] over ALL ``num_experts``;
     ``select_bias`` [E] (the ``noaux_tc`` selection bias: it moves the
-    choice, never the gate, and carries no gradient); ``w_gate_up``
-    [count, d, 2·h] (gate | up) and ``w_down`` [count, h, d] for the experts
-    ``first_expert .. first_expert + count - 1``.
+    choice, never the gate, and carries no gradient); for the experts
+    ``first_expert .. first_expert + count - 1``, ``w_in`` [count, d, 2·h]
+    (gate | up) under ``expert_form`` ``"swiglu"``, ``W_down (silu(W_gate x)
+    ⊙ W_up x)``, or [count, d, h] under ``"relu2"``, the non-gated ``W_down
+    relu(W_up x)²``; ``w_down`` [count, h, d].  The form is a static choice
+    of the inner function only: router, selection bias, sort, row buckets,
+    grouped products, masking and combine are one code.
 
     ``s = sigmoid(W_r x)`` in float32; the ``top_k`` largest ``s + bias``;
     gates ``routed_scaling · s_i / Σ_selected s_j`` (``norm_topk``) — over
@@ -242,7 +254,7 @@ def moe_ffn_dropless(x, router_w, select_bias, w_gate_up, w_down,
     choice, sort) and ``<scope>.experts`` (gather, grouped products, combine).
     """
     E, k, first = int(num_experts), int(top_k), int(first_expert)
-    count, d = w_gate_up.shape[0], x.shape[-1]
+    count, d = w_in.shape[0], x.shape[-1]
     xt = x.reshape(-1, d)
     T = xt.shape[0]
     sg = jax.lax.stop_gradient
@@ -267,8 +279,8 @@ def moe_ffn_dropless(x, router_w, select_bias, w_gate_up, w_down,
 
     with jax.named_scope(scope + ".experts"):
         buckets = tuple(dropless_row_buckets(T * k, count, E))
-        y = _experts_in_bucket(buckets, k, xt, gates.reshape(-1), w_gate_up,
-                               w_down, order, group_sizes, n_here)
+        y = _experts_in_bucket(buckets, k, expert_form, xt, gates.reshape(-1),
+                               w_in, w_down, order, group_sizes, n_here)
         y = y.astype(x.dtype).reshape(x.shape)
     load = group_sizes.astype(jnp.float32)
     return (y, sg(n_here.astype(jnp.float32)), sg(load.min()), sg(load.max()),

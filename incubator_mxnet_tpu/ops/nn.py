@@ -351,6 +351,20 @@ def swiglu_ffn(data, w_gate_up, w_down):
                       precision=prec)
 
 
+@register("relu2_ffn")
+def relu2_ffn(data, w_up, w_down):
+    """TPU-era extension: the non-gated two-matrix feed-forward ``W_down
+    relu(W_up x)²`` over the last axis.  ``w_up`` [h, d] and ``w_down``
+    [d, h] are ``[out, in]`` like ``FullyConnected``'s; no bias."""
+    prec = (lax.Precision.HIGHEST if data.dtype == jnp.float32
+            else lax.Precision.DEFAULT)
+    up = jnp.einsum("...i,oi->...o", data, w_up.astype(data.dtype),
+                    precision=prec)
+    act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(data.dtype)
+    return jnp.einsum("...i,oi->...o", act, w_down.astype(data.dtype),
+                      precision=prec)
+
+
 # ---------------------------------------------------------------------------
 # Activations / softmax
 # ---------------------------------------------------------------------------
