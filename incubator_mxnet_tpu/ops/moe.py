@@ -20,8 +20,10 @@ the path for few experts and short batches.
 
 :func:`moe_ffn_dropless` (``gluon.model_zoo.xing4`` takes it) drops nothing:
 sigmoid scores with a selection bias, the (token, choice) pairs sorted by
-expert, grouped matrix products (``jax.lax.ragged_dot``) over the experts
-this chip HOLDS, a weighted combine.  Its cost follows the rows routed here.
+expert, grouped matrix products over the experts this chip HOLDS (the Pallas
+kernels of ``ops/grouped_matmul.py`` on a TPU, ``jax.lax.ragged_dot``
+elsewhere: :func:`_grouped_path`), a weighted combine.  Its cost follows the
+rows routed here.
 
 Static knobs (``num_experts``/``top_k``/``capacity_factor``...) arrive as
 kwargs → part of the dispatch-cache/compile signature; capacity derives
@@ -131,15 +133,54 @@ def dropless_row_buckets(n_pairs, count, num_experts):
     return sorted(sizes | {n_pairs})
 
 
+def _grouped_path(x):
+    """Which way the held experts' grouped products of one layer go, from
+    what the call can observe: ``"pallas"`` (the kernels of
+    ``ops/grouped_matmul.py``) on a TPU, ``"xla"`` (``jax.lax.ragged_dot``)
+    off it, for a dtype Mosaic has not, and on a mesh that splits anything
+    outside a ``shard_map`` — no compiler partitions a Mosaic kernel.  One
+    count a traced call site, as ``attention_dispatch_*`` are."""
+    from .. import profiler
+    from ..parallel.mesh import current_mesh
+    from ..util import resolve_platform
+    from .attention import _axis_bound
+
+    mesh = current_mesh()
+    split = [] if mesh is None else [a for a in mesh.axis_names
+                                     if mesh.shape[a] > 1]
+    if (resolve_platform(x) == "tpu" and x.dtype in (jnp.bfloat16, jnp.float32)
+            and all(_axis_bound(a) for a in split)):
+        profiler.incr("moe_grouped_dispatch_pallas")
+        return "pallas"
+    profiler.incr("moe_grouped_dispatch_xla")
+    return "xla"
+
+
+def _path_in(buckets, rows, path):
+    """``path`` in the bucket of ``rows`` rows — but ``"xla"`` in the bucket of
+    EVERY pair where that is beyond three times the expected share (all four
+    of :func:`dropless_row_buckets`' sizes exist): the worst case the layer
+    has to be compiled for and a routed deployment does not run.  A bucket's
+    six kernels are traced and lowered at every start of the program, compile
+    cache or not: ~1.3 s of set-up a bucket on the chip's host (PERF.md §6 PR
+    33.5), spent where it buys steps."""
+    return "xla" if len(buckets) == 4 and rows == buckets[-1] else path
+
+
 def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
-                     w_in, w_down, top_k, form):
+                     w_in, w_down, top_k, form, path="xla"):
     """The held experts' inner function (``form``: SwiGLU on a ``gate | up``
     weight, or the non-gated ``relu(·)²`` on an ``up`` weight) on the first
     ``rows`` sorted pairs, summed into their tokens: ``[T, d]`` float32.
     Pairs past ``n_here`` belong to experts held elsewhere: they add nothing
-    (and ``ragged_dot`` leaves their rows undefined, so they are masked, not
-    trusted)."""
+    (and neither grouped product defines their rows, so they are masked, not
+    trusted).  ``path``: :func:`_grouped_path`'s, or ``"interpret"`` (the
+    kernels in the Pallas interpreter: the CPU tests ask for it here); a row
+    count no kernel tile divides (the smallest bucket) stays on XLA."""
+    from .grouped_matmul import grouped_dot, row_tile
+
     cdt = xt.dtype
+    kernels = path != "xla" and row_tile(rows) is not None
     pair = order[:rows]
     token = pair // top_k
     ours = (jnp.arange(rows) < n_here)[:, None]
@@ -149,14 +190,18 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
             else jax.lax.Precision.DEFAULT)
 
     def grouped(a, w):
-        """``ragged_dot`` with the rows past the last group zero on both
-        sides: on the TPU it leaves them UNDEFINED, in its result and (through
-        its transpose) in the cotangent of ``a``; masking the operand masks
-        that cotangent before it is scattered back into real tokens, and
-        masking the result keeps whatever lay there (NaN bit patterns
-        included) out of everything downstream and its derivative."""
+        """The grouped product with the rows past the last group zero on both
+        sides: on the TPU ``ragged_dot`` and the kernels leave them UNDEFINED,
+        in the result and (through the transpose) in the cotangent of ``a``;
+        masking the operand masks that cotangent before it is scattered back
+        into real tokens, and masking the result keeps whatever lay there
+        (NaN bit patterns included) out of everything downstream and its
+        derivative."""
         a = jnp.where(ours, a, 0)
-        out = jax.lax.ragged_dot(a, w.astype(cdt), group_sizes, precision=prec)
+        if kernels:
+            out = grouped_dot(a, w.astype(cdt), group_sizes, path == "interpret")
+        else:
+            out = jax.lax.ragged_dot(a, w.astype(cdt), group_sizes, precision=prec)
         return jnp.where(ours, out, 0)
 
     up = grouped(xt[token], w_in)                    # [rows, 2h] or [rows, h]
@@ -172,8 +217,8 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
     return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _experts_in_bucket(buckets, top_k, form, xt, gate_flat, w_in, w_down,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _experts_in_bucket(buckets, top_k, form, path, xt, gate_flat, w_in, w_down,
                        order, group_sizes, n_here):
     """:func:`_experts_on_rows` at the smallest of ``buckets`` that holds
     ``n_here`` rows.  The derivative is its own rule and not that of
@@ -183,7 +228,8 @@ def _experts_in_bucket(buckets, top_k, form, xt, gate_flat, w_in, w_down,
     at 64 rows on the v5e.  So the forward keeps only what it was given, and
     the backward switches again and runs the chosen branch's forward and
     derivative together: one more forward of the held experts' products."""
-    branches = [functools.partial(_experts_on_rows, rows, top_k=top_k, form=form)
+    branches = [functools.partial(_experts_on_rows, rows, top_k=top_k, form=form,
+                                  path=_path_in(buckets, rows, path))
                 for rows in buckets]
     return jax.lax.switch(_bucket_of(buckets, n_here), branches, xt, order,
                           gate_flat, group_sizes, n_here, w_in, w_down)
@@ -193,11 +239,11 @@ def _bucket_of(buckets, n_here):
     return jnp.searchsorted(jnp.asarray(buckets, jnp.int32), n_here)
 
 
-def _experts_in_bucket_fwd(buckets, top_k, form, *args):
-    return _experts_in_bucket(buckets, top_k, form, *args), args
+def _experts_in_bucket_fwd(buckets, top_k, form, path, *args):
+    return _experts_in_bucket(buckets, top_k, form, path, *args), args
 
 
-def _experts_in_bucket_bwd(buckets, top_k, form, args, ct):
+def _experts_in_bucket_bwd(buckets, top_k, form, path, args, ct):
     from .nn import _zero_cotangent
 
     xt, gate_flat, w_in, w_down, order, group_sizes, n_here = args
@@ -207,7 +253,7 @@ def _experts_in_bucket_bwd(buckets, top_k, form, args, ct):
             _, pullback = jax.vjp(
                 lambda xt, gate_flat, w_in, w_down: _experts_on_rows(
                     rows, xt, order, gate_flat, group_sizes, n_here,
-                    w_in, w_down, top_k, form),
+                    w_in, w_down, top_k, form, _path_in(buckets, rows, path)),
                 xt, gate_flat, w_in, w_down)
             return pullback(ct)
         return run
@@ -279,8 +325,9 @@ def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
 
     with jax.named_scope(scope + ".experts"):
         buckets = tuple(dropless_row_buckets(T * k, count, E))
-        y = _experts_in_bucket(buckets, k, expert_form, xt, gates.reshape(-1),
-                               w_in, w_down, order, group_sizes, n_here)
+        y = _experts_in_bucket(buckets, k, expert_form, _grouped_path(xt), xt,
+                               gates.reshape(-1), w_in, w_down, order,
+                               group_sizes, n_here)
         y = y.astype(x.dtype).reshape(x.shape)
     load = group_sizes.astype(jnp.float32)
     return (y, sg(n_here.astype(jnp.float32)), sg(load.min()), sg(load.max()),

@@ -257,6 +257,8 @@ _counters = {
     "attention_dispatch_xla": 0,      # attention call sites traced onto the XLA path
     "attention_dispatch_grouped": 0,  # of either: call sites with fewer key/value heads than query heads
     "ssm_scan_traced": 0,             # chunked state-space scan call sites traced into a program
+    "moe_grouped_dispatch_pallas": 0,  # moe_ffn_dropless call sites traced onto the Pallas grouped-product kernels
+    "moe_grouped_dispatch_xla": 0,    # moe_ffn_dropless call sites traced onto jax.lax.ragged_dot
     "elastic_restart": 0,             # supervisor job re-formations
     "collective_timeout": 0,          # collective-watchdog expiries
     "snapshot_commit_ms": 0,          # two-phase run-snapshot commit wall ms
