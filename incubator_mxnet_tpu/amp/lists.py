@@ -19,6 +19,10 @@ TARGET_OPS = {
     "dot", "batch_dot", "linalg_gemm2",
     "fused_attention", "fused_qkv_attention", "fused_kv_attention",
     "latent_attention", "swiglu_ffn", "relu2_ffn",
+    # the whole indexer-selected attention layer: its projections ride the
+    # MXU in the target dtype; index scores, the selection's comparisons,
+    # soft-maxes and the indexer's loss are float32 inside it whatever arrives
+    "sparse_attention",
     "RNN",
     # Embedding output feeds the transformer residual stream; emitting it
     # in the target dtype keeps that stream bf16 end-to-end (the norms
@@ -40,6 +44,9 @@ FP32_OPS = {
     "erf", "erfinv", "gamma", "gammaln",
     "smooth_l1", "MakeLoss",
     "power", "broadcast_power", "_power_scalar", "sqrt", "rsqrt", "square",
+    # the lightning indexer's pieces, called alone (ops/sparse_attention.py):
+    # a near-tie at the topk-th score selects another key under any rounding
+    "lightning_index_scores", "indexer_kl_loss",
 }
 
 # The state-space ops (ops/ssm.py: causal_conv1d, ssm_scan, gated_rms_norm,

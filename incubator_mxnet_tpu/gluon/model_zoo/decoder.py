@@ -94,15 +94,23 @@ class SparseExperts(HybridBlock):
     ``forward`` returns ``(y, stats)``: ``stats`` is a float32 vector
     ``(rows routed here, least load, greatest load)`` over the experts held
     followed by the load of each of all the experts, which :func:`run_layer`
-    hands to the trainer's MoE frame and to the rule."""
+    hands to the trainer's MoE frame and to the rule.
+
+    ``scoring`` is the router's: ``"sigmoid"`` with the selection bias (the
+    above), or ``"softmax"`` over all the experts with NO selection bias (no
+    such parameter exists, nothing is balanced outside the gradient) and the
+    Switch load-balance term as a third result, ``(y, stats, balance)``,
+    raw, for the block to weight into the step's loss.  ``n_shared_experts``
+    0 builds no shared expert."""
 
     def __init__(self, units, expert_width, num_experts, top_k,
                  experts_held=None, n_shared_experts=1, routed_scaling=1.0,
                  norm_topk=True, bias_update_speed=0.001, scope="moe",
-                 expert_form="swiglu", shared_width=None,
+                 expert_form="swiglu", shared_width=None, scoring="sigmoid",
                  prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
-        self._bias_speed = float(bias_update_speed)
+        self._biased = scoring == "sigmoid"
+        self._bias_speed = float(bias_update_speed) if self._biased else 0.0
         self._jax_scope = str(scope)
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and first + count <= num_experts and count > 0):
@@ -119,6 +127,8 @@ class SparseExperts(HybridBlock):
                         routed_scaling=float(routed_scaling),
                         norm_topk=bool(norm_topk), scope=self._jax_scope,
                         expert_form=expert_form)
+        if not self._biased:      # the default stays out of the op's signature
+            self._kw["scoring"] = scoring
         if shared_width is None:
             shared_width = expert_width * int(n_shared_experts)
         with self.name_scope():
@@ -127,8 +137,9 @@ class SparseExperts(HybridBlock):
                                      shape=(num_experts, units))
             # the noaux_tc selection bias: a buffer that a balancing rule
             # outside the gradient would move; no gradient reaches it
-            self.select_bias = get("select_bias", shape=(num_experts,),
-                                   init="zeros", grad_req="null")
+            if self._biased:
+                self.select_bias = get("select_bias", shape=(num_experts,),
+                                       init="zeros", grad_req="null")
             self.experts_in_weight = get(
                 in_name, shape=(count, units, in_mult * expert_width))
             self.experts_down_weight = get(
@@ -137,20 +148,24 @@ class SparseExperts(HybridBlock):
                 shared_block(units, int(shared_width), prefix="shared_")
                 if n_shared_experts else None)
 
-    def hybrid_forward(self, F, x, router_weight, select_bias,
-                       experts_down_weight, **experts_in):
+    def hybrid_forward(self, F, x, router_weight, experts_down_weight,
+                       select_bias=None, **experts_in):
         (experts_in_weight,) = experts_in.values()   # named by the form
-        y, rows, load_min, load_max, load_all = F.contrib.moe_ffn_dropless(
+        if select_bias is None:      # softmax scoring reads none
+            select_bias = F.zeros((router_weight.shape[0],))
+        y, rows, load_min, load_max, load_all, *balance = F.contrib.moe_ffn_dropless(
             x, router_weight, select_bias, experts_in_weight,
             experts_down_weight, **self._kw)
         if self.shared_expert is not None:
             with _scope(self._jax_scope + ".shared"):
                 y = y + self.shared_expert(x)
-        return y, F.concat(F.stack(rows, load_min, load_max), load_all, dim=0)
+        stats = F.concat(F.stack(rows, load_min, load_max), load_all, dim=0)
+        return (y, stats, *balance)
 
     def cast(self, dtype):
         super().cast(dtype)
-        self.select_bias.cast("float32")
+        if self._biased:
+            self.select_bias.cast("float32")
         return self
 
     def balanced_bias(self, bias, load_all):
@@ -163,30 +178,37 @@ class SparseExperts(HybridBlock):
 
 
 def run_layer(body, x, remat, experts=None):
-    """One decoder layer: ``body(x) → (out, stats)``, ``stats`` the routing
-    statistics of ``experts`` (a :class:`SparseExperts`) or None.  Under a
+    """One decoder layer: ``body(x) → (out, stats)`` or ``(out, stats,
+    side)``, ``stats`` the routing statistics of ``experts`` (a
+    :class:`SparseExperts`) or None, ``side`` what the layer adds to the step
+    beside its output: ``{"loss": a weighted scalar, "counters": {a profiler
+    counter's name: a scalar}}`` (``model_zoo.moe.register_side``).  Under a
     jit trace with ``remat`` the body runs inside ``jax.checkpoint``: the
     layer keeps only its input and the backward pass runs its forward again.
-    The statistics are registered OUTSIDE the checkpoint (what the trainer's
-    MoE frame and the aux collector keep must belong to the step's own
-    trace), and in training the ``noaux_tc`` rule moves the selection bias."""
+    Statistics and side are registered OUTSIDE the checkpoint (what the
+    trainer's MoE frame and the aux collector keep must belong to the step's
+    own trace), and in training the ``noaux_tc`` rule moves the selection
+    bias."""
     import jax
 
     from ... import autograd
     from ...ndarray.ndarray import NDArray
 
+    def raw(tree):
+        return jax.tree_util.tree_map(
+            lambda v: v._data if isinstance(v, NDArray) else v, tree,
+            is_leaf=lambda v: isinstance(v, NDArray))
+
     traced = (isinstance(x._data, jax.core.Tracer)
               and not autograd.is_recording())
     if remat and traced:
-        def checkpointed(data):
-            out, stats = body(NDArray(data))
-            return out._data, None if stats is None else stats._data
-
-        out, stats = jax.checkpoint(checkpointed)(x._data)
+        out, stats, *side = jax.checkpoint(lambda data: raw(body(NDArray(data))))(x._data)
         out = NDArray(out)
     else:
-        out, stats = body(x)
-        stats = None if stats is None else stats._data
+        out, stats, *side = body(x)
+        stats, side = raw(stats), raw(side)
+    if side:
+        _moe.register_side(**side[0])
     if stats is not None:
         _moe.register_metrics({
             "rows_routed_here": stats[0], "expert_load_min": stats[1],

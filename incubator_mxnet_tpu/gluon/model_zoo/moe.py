@@ -36,7 +36,10 @@ __all__ = [
     "moe_loss_frame",
     "frame_loss",
     "frame_metrics",
+    "frame_counters",
     "register_metrics",
+    "register_side",
+    "tapped",
 ]
 
 _tls = _threading.local()
@@ -51,11 +54,18 @@ def _frames():
 
 class moe_loss_frame:
     """``with moe_loss_frame() as frame:`` — collect every MoE layer's
-    weighted aux losses and routing metrics traced inside the scope."""
+    weighted aux losses and routing metrics traced inside the scope.
+    ``taps`` names intermediate arrays the caller wants to look at (a
+    comparison with a reference: ``("selection",)`` is each sparse-attention
+    layer's selected keys): a layer that has one hands it over only when it
+    is asked for (:func:`tapped`), into ``frame.taps``, a dict a layer."""
 
-    def __init__(self):
+    def __init__(self, taps=()):
         self.losses = []     # weighted scalar losses (traced values)
         self.metrics = []    # dicts of traced metric scalars
+        self.counters = []   # dicts: profiler counter name → traced scalar
+        self.wanted = frozenset(taps)
+        self.taps = []       # dicts: tap name → traced array, a layer
 
     def __enter__(self):
         _frames().append(self)
@@ -92,6 +102,43 @@ def register_metrics(metrics):
         return False
     st[-1].metrics.append(metrics)
     return True
+
+
+def tapped(name):
+    """Whether the innermost frame asked for the intermediate ``name``."""
+    st = _frames()
+    return bool(st) and name in st[-1].wanted
+
+
+def register_side(loss=None, counters=None, taps=None):
+    """Hand the innermost frame what a layer adds to the step beside its
+    output: ``loss``, a weighted scalar that joins the differentiated loss (a
+    router's balance term, an indexer's own loss), and ``counters``, a map
+    from a DECLARED profiler counter's name to a traced scalar, which the
+    trainer sums over the layers, ships out of the program and adds to that
+    counter a step (``sparse_attn_tiles_live``); ``taps``, the intermediates
+    the frame asked for (:func:`tapped`).  The same rule as
+    :func:`register_metrics`: from the step's own trace."""
+    st = _frames()
+    if not st or in_backward_trace():
+        return False
+    if loss is not None:
+        st[-1].losses.append(loss)
+    if counters:
+        st[-1].counters.append(dict(counters))
+    if taps:
+        st[-1].taps.append(dict(taps))
+    return True
+
+
+def frame_counters(frame):
+    """The frame's counters summed by name over its layers: a name → traced
+    scalar map (empty when no layer registered any)."""
+    out = {}
+    for layer in frame.counters:
+        for name, value in layer.items():
+            out[name] = out[name] + value if name in out else value
+    return out
 
 
 def frame_loss(frame):

@@ -15,6 +15,7 @@ from . import attention  # noqa: F401  (registers fused/flash attention)
 from . import moe  # noqa: F401  (registers the MoE dispatch/combine kernels)
 from . import hyper_connections  # noqa: F401  (registers the mHC residual mix)
 from . import ssm  # noqa: F401  (registers the state-space scan, its convolution and gated norm)
+from . import sparse_attention  # noqa: F401  (registers indexer-selected attention and its pieces)
 from . import detection  # noqa: F401  (registers MultiBox*/box_nms/box_iou)
 from . import quantization  # noqa: F401  (registers quantize_v2/dequantize/int8 ops)
 from . import linalg  # noqa: F401  (registers the la_op family)

@@ -63,7 +63,7 @@ from jax.experimental.pallas import tpu as _pltpu
 from jax.sharding import PartitionSpec
 
 __all__ = ["flash_attention", "attention_reference", "latent_attention",
-           "yarn_rotary_tables", "apply_rotary"]
+           "yarn_rotary_tables", "multi_stream_rotary_tables", "apply_rotary"]
 
 # The forward kernel keeps one head's whole K and V in VMEM beside its
 # 512-wide tiles, the backward the head's Q, dO, dq and dq's float32 sum: 22 MB
@@ -190,11 +190,17 @@ def _live_softmax_update(o, m, l, s, v, matmul):
     return o * corr + matmul(p, v), m_new, l * corr + p.sum(axis=-1, keepdims=True)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale,
+                select_ref=None):
     """One (batch·head, q-block) grid cell: stream K/V blocks, online
     softmax in fp32.  Shapes: q_ref [1, Bq, D], k_ref [1, Sk, D],
     v_ref [1, Sk, Dv] (Dv may differ from D: latent attention has 192-wide
-    queries and keys and 128-wide values).
+    queries and keys and 128-wide values).  ``select_ref`` [1, Bq, Sk] int8,
+    if given, is the batch row's selection for these queries, shared by its
+    heads: a score is live only where it is non-zero (and causal-visible).
+    A row's first blocks may then hold no live key, so the update is the
+    guarded :func:`online_softmax_update`; every row must select a key
+    somewhere (the caller's contract), or its result is undefined.
 
     Operands stay in their input dtype (bf16 rides the MXU at full rate)
     with fp32 accumulation via preferred_element_type; matmul precision is
@@ -228,7 +234,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale):
             q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        acc_new, m_new, l_new = _live_softmax_update(
+        if select_ref is not None:
+            chosen = select_ref[0, :, _pl.ds(j * block_k, block_k)]
+            s = jnp.where(chosen.astype(jnp.int32) != 0, s, -jnp.inf)
+        update = _live_softmax_update if select_ref is None else online_softmax_update
+        acc_new, m_new, l_new = update(
             acc, m, l, s, v,
             lambda p, v: jax.lax.dot_general(
                 p.astype(v.dtype), v, _NN,
@@ -266,12 +276,15 @@ def _kv_row(rows, kv_rows):
 
 
 def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=128,
-                      with_lse=False):
+                      with_lse=False, select=None):
     """q: [BH, S, D], k: [BHkv, Sk, D], v: [BHkv, Sk, Dv] (batch·heads
     flattened; grouped-query heads have BH = group · BHkv and query row ``r``
     reads key/value row ``r // group``: the block index does it, nothing is
     repeated in memory).  ``with_lse=True`` also returns the per-row
-    log-sum-exp [BH, S] for the blockwise backward."""
+    log-sum-exp [BH, S, 128] for the blockwise backward.  ``select`` [B, S,
+    Sk] int8 is a selection shared by the heads of a batch row (query row
+    ``r`` reads row ``r // (BH / B)`` of it); without one the call is what it
+    always was."""
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     kv_row = _kv_row(bh, k.shape[0])
@@ -281,31 +294,44 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
     if sq % block_q or sk % block_k:
         raise ValueError(f"sequence lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
     grid = (bh, sq // block_q)
+    in_specs = [
+        _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+        _pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
+        _pl.BlockSpec((1, sk, dv), lambda b, i: (kv_row(b), 0, 0)),
+    ]
+    operands = (q, k, v)
+    static = dict(block_k=block_k, causal=causal, scale=scale)
+    if select is not None:
+        batch_row = _kv_row(bh, select.shape[0])    # query row → batch row
+        in_specs.append(
+            _pl.BlockSpec((1, block_q, sk), lambda b, i: (batch_row(b), i, 0)))
+        operands += (select,)
+
+        def kernel(q_ref, k_ref, v_ref, select_ref, o_ref, lse_ref=None):
+            _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                        select_ref=select_ref, **static)
+    elif with_lse:
+        kernel = functools.partial(_fwd_kernel, **static)
+    else:
+        def kernel(q_ref, k_ref, v_ref, o_ref, **_):
+            _fwd_kernel(q_ref, k_ref, v_ref, o_ref, None, **static)
     if with_lse:
-        kernel = functools.partial(_fwd_kernel, block_k=block_k, causal=causal, scale=scale)
         out_shape = (jax.ShapeDtypeStruct(o_shape, q.dtype),
                      jax.ShapeDtypeStruct((bh, sq, _LANE), jnp.float32))
         out_specs = (_pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
                      _pl.BlockSpec((1, block_q, _LANE), lambda b, i: (b, i, 0)))
     else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, **_):
-            _fwd_kernel(q_ref, k_ref, v_ref, o_ref, None,
-                        block_k=block_k, causal=causal, scale=scale)
         out_shape = jax.ShapeDtypeStruct(o_shape, q.dtype)
         out_specs = _pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0))
     return _pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid=grid,
-        in_specs=[
-            _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            _pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
-            _pl.BlockSpec((1, sk, dv), lambda b, i: (kv_row(b), 0, 0)),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         interpret=interpret,
         compiler_params=_MOSAIC_PARAMS,
-    )(q, k, v)
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -327,11 +353,15 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, causal, scale):
+                dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, causal, scale,
+                select_ref=None):
     """q_ref [1, Sq, D], do_ref [1, Sq, Dv], lse_ref / delta_ref
     [1, Sq/Bq, Bq] and dq_ref [1, Sq, D] are the head's, resident while its
     key blocks go by; k_ref / dk_ref [1, Bk, D] and v_ref / dv_ref
-    [1, Bk, Dv] the cell's; dq_acc [Sq, D] float32."""
+    [1, Bk, Dv] the cell's; dq_acc [Sq, D] float32.  ``select_ref`` [1, Bk,
+    Sq] int8, if given, is the batch row's selection TRANSPOSED (keys down,
+    queries across, as the score tile is held): a dead score's probability
+    is ``exp(-inf - lse)``, an exact zero, as under the causal mask."""
     j = _pl.program_id(1)
     block_k, d, d_v = k_ref.shape[1], k_ref.shape[2], v_ref.shape[2]
     nq = q_ref.shape[1] // block_q
@@ -365,6 +395,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         st = mm(k, q, _NT) * scale                 # [Bk, Bq]: sᵀ
         if causal:
             st = jnp.where(ahead >= j * block_k - i * block_q, st, -jnp.inf)
+        if select_ref is not None:
+            st = jnp.where(select_ref[0, :, rows].astype(jnp.int32) != 0, st, -jnp.inf)
         pt = jnp.exp(st - lse)                     # masked → exp(-inf) = 0
         dv = dv + mm(pt.astype(do.dtype), do, _NN)
         dpt = mm(v, do, _NT)
@@ -388,11 +420,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
-                      block_q=128, block_k=128):
+                      block_q=128, block_k=128, select=None):
     """q: [BH, S, D]; k: [BHkv, Sk, D]; v: [BHkv, Sk, Dv]; do/o: [BH, S,
     Dv]; lse: [BH, Sq, _LANE] fp32 → (dq, dk, dv).  With grouped-query heads
     each query head's cell writes ITS dk and dv, in float32, and the group's
-    are summed after the kernel (one small XLA reduction a call)."""
+    are summed after the kernel (one small XLA reduction a call).  ``select``
+    [B, Sq, Sk] int8 is the forward's selection; the kernel reads its
+    transpose (one XLA transpose of an int8 array a call)."""
     bh, sq, d = q.shape
     bkv, sk, d_v = k.shape[0], k.shape[1], v.shape[2]
     kv_row, group = _kv_row(bh, bkv), bh // bkv
@@ -411,23 +445,37 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
     kv_blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (kv_row(b), j, 0))
     part = (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)) if group == 1 else (
         lambda a: jax.ShapeDtypeStruct((bh,) + a.shape[1:], jnp.float32))
+    static = dict(block_q=block_q, causal=causal, scale=scale)
+    in_specs = [
+        head(sq, d),                                              # q
+        kv_blk(d),                                                # k
+        kv_blk(d_v),                                              # v
+        head(sq, d_v),                                            # do
+        head(nq, block_q),                                        # lse
+        head(nq, block_q),                                        # delta
+    ]
+    operands = (q, k, v, do, lse, delta)
+    if select is None:
+        kernel = functools.partial(_bwd_kernel, **static)
+    else:
+        batch_row = _kv_row(bh, select.shape[0])
+        in_specs.append(
+            _pl.BlockSpec((1, block_k, sq), lambda b, j: (batch_row(b), j, 0)))
+        operands += (select.transpose(0, 2, 1),)
+
+        def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, select_ref, *outs):
+            _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs,
+                        select_ref=select_ref, **static)
     dq, dk, dv = _pl.pallas_call(
-        functools.partial(_bwd_kernel, block_q=block_q, causal=causal, scale=scale),
+        kernel,
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), part(k), part(v)),
         grid=(bh, sk // block_k),
-        in_specs=[
-            head(sq, d),                                              # q
-            kv_blk(d),                                                # k
-            kv_blk(d_v),                                              # v
-            head(sq, d_v),                                            # do
-            head(nq, block_q),                                        # lse
-            head(nq, block_q),                                        # delta
-        ],
+        in_specs=in_specs,
         out_specs=(head(sq, d), blk(d), blk(d_v)),
         scratch_shapes=[_pltpu.VMEM((sq, d), jnp.float32)],
         interpret=interpret,
         compiler_params=_BWD_PARAMS,
-    )(q, k, v, do, lse, delta)
+    )(*operands)
     if group > 1:
         dk, dv = (a.reshape((bkv, group) + a.shape[1:]).sum(axis=1).astype(like.dtype)
                   for a, like in ((dk, k), (dv, v)))
@@ -633,8 +681,21 @@ _flash_qkv_tile.defvjp(_flash_qkv_tile_fwd, _flash_qkv_tile_bwd)
 # ---------------------------------------------------------------------------
 
 
-def attention_reference(q, k, v, causal=False, scale=None):
-    """Plain jnp attention: q/k/v [B, H, S, D] (or [BH, S, D]).
+def _live(s, causal, select=None, heads_axis=1):
+    """Scores ``[B, H, Sq, Sk]`` with the dead ones at ``-inf``: those the
+    causal mask hides and, under a selection ``[B, Sq, Sk]`` (non-zero =
+    chosen, shared by the heads), those not chosen."""
+    if causal:
+        sq, sk = s.shape[-2], s.shape[-1]
+        s = jnp.where(jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :], s, -jnp.inf)
+    if select is not None:
+        s = jnp.where(jnp.expand_dims(select, heads_axis) != 0, s, -jnp.inf)
+    return s
+
+
+def attention_reference(q, k, v, causal=False, scale=None, select=None):
+    """Plain jnp attention: q/k/v [B, H, S, D] (or [BH, S, D]); ``select``
+    [B, Sq, Sk] (with [B, H, S, D] operands) keeps only the chosen keys.
 
     Operands stay in their input dtype (bf16 rides the MXU at full rate)
     with fp32 accumulation via ``preferred_element_type``; only the softmax
@@ -647,11 +708,7 @@ def attention_reference(q, k, v, causal=False, scale=None):
             else jax.lax.Precision.DEFAULT)
     s = jnp.einsum("...qd,...kd->...qk", q, k,
                    preferred_element_type=jnp.float32, precision=prec) * scale
-    if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
-        s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+    p = jax.nn.softmax(_live(s, causal, select), axis=-1)
     return jnp.einsum("...qk,...kd->...qd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32,
                       precision=prec).astype(v.dtype)
@@ -742,11 +799,12 @@ def _kernel_path(q, k, seq_axis=2, qkv_heads=None):
     return "xla", None
 
 
-def _count_dispatch(kernels, grouped=False):
+def _count_dispatch(kernels, grouped=False, selected=False):
     """One count a traced call site: dispatch is decided at trace time, so
     after a step has compiled the two counters say which path every
-    attention call of the program took (and the third how many of them had
-    fewer key/value heads than query heads)."""
+    attention call of the program took (the third how many of them had
+    fewer key/value heads than query heads, the fourth how many of the
+    kernels' were given a selection)."""
     from .. import profiler
 
     if kernels:
@@ -755,6 +813,8 @@ def _count_dispatch(kernels, grouped=False):
         profiler.incr("attention_dispatch_xla")
     if grouped:
         profiler.incr("attention_dispatch_grouped")
+    if kernels and selected:
+        profiler.incr("attention_dispatch_masked")
 
 
 def _head_group(q, k, head_axis):
@@ -776,47 +836,81 @@ def _repeat_heads(k, v, group, head_axis):
             jnp.repeat(v, group, axis=head_axis))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_kernels(q, k, v, causal, scale, launch):
-    """[B, H, S, D] attention in the blockwise kernels, forward and backward;
-    ``k`` and ``v`` [B, Hkv, Sk, ·] may have fewer heads (grouped-query: query
-    head ``h`` reads key/value head ``h // (H / Hkv)``)."""
-    b, h, s, d = q.shape
-    out = _on_mesh(
-        launch, lambda *qkv: _flash_fwd_pallas(
-            *qkv, causal, scale, launch.interpret, *launch.blocks),
-        q.reshape(b * h, s, d), k.reshape(b * k.shape[1], -1, d),
-        v.reshape(b * v.shape[1], -1, v.shape[-1]))
-    return out.reshape(b, h, s, v.shape[-1])
-
-
-def _flash_kernels_fwd(q, k, v, causal, scale, launch):
-    """VJP forward: also save (o, lse) so the backward runs blockwise
-    without ever materializing S×S."""
+def _blockwise_forward(q, k, v, select, causal, scale, launch, with_lse):
+    """The forward kernel on [B, H, S, D] operands (``select`` [B, S, Sk] int8
+    or None): the output, and with ``with_lse`` the kernel's lane-replicated
+    log-sum-exp [B·H, S, 128] beside it."""
     b, h, s, d = q.shape
     h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[-1]
-    out, lse = _on_mesh(
-        launch, lambda *qkv: _flash_fwd_pallas(
-            *qkv, causal, scale, launch.interpret, *launch.blocks, with_lse=True),
-        q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d), v.reshape(b * h_kv, sk, d_v))
-    out = out.reshape(b, h, s, d_v)
-    return out, (q, k, v, out, lse)
+    arrays = (q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d),
+              v.reshape(b * h_kv, sk, d_v)) + (() if select is None else (select,))
+    got = _on_mesh(
+        launch, lambda q, k, v, select=None: _flash_fwd_pallas(
+            q, k, v, causal, scale, launch.interpret, *launch.blocks,
+            with_lse=with_lse, select=select), *arrays)
+    if with_lse:
+        return got[0].reshape(b, h, s, d_v), got[1]
+    return got.reshape(b, h, s, d_v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_kernels(q, k, v, causal, scale, launch, select=None):
+    """[B, H, S, D] attention in the blockwise kernels, forward and backward;
+    ``k`` and ``v`` [B, Hkv, Sk, ·] may have fewer heads (grouped-query: query
+    head ``h`` reads key/value head ``h // (H / Hkv)``).  ``select`` [B, S,
+    Sk] int8, if given, is a selection shared by a batch row's heads: a score
+    is live only where it is non-zero (and, under ``causal``, visible); every
+    query must select at least one key it can see.  It carries no gradient."""
+    return _blockwise_forward(q, k, v, select, causal, scale, launch, False)
+
+
+def _flash_kernels_fwd(q, k, v, causal, scale, launch, select=None):
+    """VJP forward: also save (o, lse) so the backward runs blockwise
+    without ever materializing S×S."""
+    out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
+    return out, (q, k, v, out, lse, select)
 
 
 def _flash_kernels_bwd(causal, scale, launch, res, do):
-    q, k, v, o, lse = res
+    q, k, v, o, lse, select = res
     b, h, s, d = q.shape
     h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[-1]
+    arrays = (q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d),
+              v.reshape(b * h_kv, sk, d_v), do.reshape(b * h, s, d_v),
+              o.reshape(b * h, s, d_v), lse) + (() if select is None else (select,))
     dq, dk, dv = _on_mesh(
-        launch, lambda *arrays: _flash_bwd_pallas(
-            *arrays, causal, scale, launch.interpret, *launch.blocks),
-        q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d),
-        v.reshape(b * h_kv, sk, d_v), do.reshape(b * h, s, d_v),
-        o.reshape(b * h, s, d_v), lse)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+        launch, lambda *a: _flash_bwd_pallas(
+            *a[:6], causal, scale, launch.interpret, *launch.blocks,
+            select=a[6] if len(a) > 6 else None), *arrays)
+    from .nn import _zero_cotangent
+
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            None if select is None else _zero_cotangent(select))
 
 
 _flash_kernels.defvjp(_flash_kernels_fwd, _flash_kernels_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_kernels_lse(q, k, v, causal, scale, launch, select=None):
+    """:func:`_flash_kernels` that also returns each query's log-sum-exp of
+    its live scores, float32 [B, H, S] (what the forward kernel writes for
+    the backward anyway): ``exp(score - lse)`` is the probability.  The
+    log-sum-exp is a by-product and carries no gradient."""
+    out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
+    return out, lse[:, :, 0].reshape(q.shape[:3])
+
+
+def _flash_kernels_lse_fwd(q, k, v, causal, scale, launch, select=None):
+    out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
+    return (out, lse[:, :, 0].reshape(q.shape[:3])), (q, k, v, out, lse, select)
+
+
+def _flash_kernels_lse_bwd(causal, scale, launch, res, cts):
+    return _flash_kernels_bwd(causal, scale, launch, res, cts[0])
+
+
+_flash_kernels_lse.defvjp(_flash_kernels_lse_fwd, _flash_kernels_lse_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -858,18 +952,39 @@ def _flash_bwd_xla(causal, scale, res, do):
 _flash_xla.defvjp(_flash_xla_fwd, _flash_bwd_xla)
 
 
-def flash_attention(q, k, v, causal=False, scale=None):
+def _as_selection(select):
+    """The kernels' form of a selection: int8, non-zero = chosen."""
+    return None if select is None else (select != 0).astype(jnp.int8)
+
+
+def flash_attention(q, k, v, causal=False, scale=None, select=None):
     """Fused attention on [B, H, S, D] arrays; differentiable; bf16-safe.
     ``k`` and ``v`` may have fewer heads, [B, Hkv, Sk, ·] with Hkv dividing H
-    (grouped-query attention)."""
+    (grouped-query attention).  ``select`` [B, Sq, Sk] (bool or integer,
+    non-zero = chosen) is a selection of keys a query, shared by the heads:
+    a score is live only where the key is chosen and, under ``causal``,
+    visible; every query must choose a key it can see.  No gradient reaches
+    it.  Without one the call is what it always was."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     group = _head_group(q, k, 1)
     path, launch = _kernel_path(q, k)
-    _count_dispatch(path != "xla", group > 1)
+    _count_dispatch(path != "xla", group > 1, select is not None)
     if path == "blockwise":
-        return _flash_kernels(q, k, v, causal, float(scale), launch)
-    return _flash_xla(q, *_repeat_heads(k, v, group, 1), causal, float(scale))
+        return _flash_kernels(q, k, v, causal, float(scale), launch,
+                              _as_selection(select))
+    k, v = _repeat_heads(k, v, group, 1)
+    if select is not None:
+        return _selected_xla(attention_reference, q, k, v, causal, float(scale), select)
+    return _flash_xla(q, k, v, causal, float(scale))
+
+
+def _selected_xla(reference, q, k, v, causal, scale, select):
+    """The XLA path under a selection: the plain expression, its backward
+    rematerialized (``jax.checkpoint``) as the paths' own rules do."""
+    select = lax.stop_gradient(_as_selection(select))
+    return jax.checkpoint(
+        lambda q, k, v, select: reference(q, k, v, causal, scale, select))(q, k, v, select)
 
 
 # ---------------------------------------------------------------------------
@@ -885,19 +1000,17 @@ def _causal_mask(s):
     return jnp.where(mask, s, -jnp.inf)
 
 
-def attention_reference_bshd(q, k, v, causal=False, scale=None):
+def attention_reference_bshd(q, k, v, causal=False, scale=None, select=None):
     """Plain jnp attention over [B, S, H, Dh] operands (head axis stays in
     place; same fp32-accumulate / fp32-softmax policy as
-    :func:`attention_reference`)."""
+    :func:`attention_reference`, and its ``select``)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32, precision=prec) * scale
-    if causal:
-        s = _causal_mask(s)
-    p = jax.nn.softmax(s, axis=-1)
+    p = jax.nn.softmax(_live(s, causal, select), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32,
                       precision=prec).astype(v.dtype)
@@ -941,23 +1054,37 @@ def _flash_bshd_bwd(causal, scale, res, do):
 _flash_bshd.defvjp(_flash_bshd_fwd, _flash_bshd_bwd)
 
 
-def _attend_bshd(q, k, v, causal, scale):
+def _attend_bshd(q, k, v, causal, scale, select=None, with_lse=False):
     """Dispatch [B, S, H, Dh] attention: the bshd XLA path, or transpose +
     the blockwise kernels where ``_kernel_path`` says they win (the two
     transposes are in the measurements it rests on).  Traced under the
     ``attn.core`` scope, which separates attention from the projections
-    round it in a device trace."""
+    round it in a device trace.  ``select``: :func:`flash_attention`'s.
+    ``with_lse`` returns ``(out, lse, launch)``: the kernels' log-sum-exp
+    [B, H, S] and how they were launched, both None on the XLA path."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     group = _head_group(q, k, 2)
     path, launch = _kernel_path(q, k, seq_axis=1)
-    _count_dispatch(path != "xla", group > 1)
+    _count_dispatch(path != "xla", group > 1, select is not None)
+    lse = None
     with jax.named_scope("attn.core"):
         if path == "blockwise":
             t = lambda x: x.transpose(0, 2, 1, 3)
-            out = _flash_kernels(t(q), t(k), t(v), causal, float(scale), launch)
-            return out.transpose(0, 2, 1, 3)
-        return _flash_bshd(q, *_repeat_heads(k, v, group, 2), causal, float(scale))
+            operands = (t(q), t(k), t(v), causal, float(scale), launch, _as_selection(select))
+            if with_lse:
+                out, lse = _flash_kernels_lse(*operands)
+            else:
+                out = _flash_kernels(*operands)
+            out = out.transpose(0, 2, 1, 3)
+        else:
+            k, v = _repeat_heads(k, v, group, 2)
+            if select is not None:
+                out = _selected_xla(attention_reference_bshd, q, k, v, causal,
+                                    float(scale), select)
+            else:
+                out = _flash_bshd(q, k, v, causal, float(scale))
+    return (out, lse, launch) if with_lse else out
 
 
 from .registry import register  # noqa: E402
@@ -1144,14 +1271,55 @@ def yarn_rotary_tables(seq, dim, theta=10000.0, factor=1.0, original=4096,
             (np.sin(angles) * scale).astype(np.float32))
 
 
-def apply_rotary(x, cos, sin):
-    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of the last axis of
-    ``x`` [B, S, H, dim] by the position's angles; the result is laid out as
+def rotary_stream_of_pair(half, sections=None):
+    """Which position stream each of the ``half`` frequency pairs reads:
+    pair ``i`` reads stream ``j`` where ``i`` falls in the ``j``-th of
+    ``sections`` (contiguous, summing to ``half``: ``[16, 24, 24]`` gives pairs
+    0..15 to stream 0, 16..39 to stream 1, 40..63 to stream 2).  None: one
+    stream."""
+    import numpy as np
+
+    if sections is None:
+        return np.zeros((half,), np.int32)
+    if sum(sections) != half:
+        raise ValueError(f"rotary sections {list(sections)} do not add up to "
+                         f"the {half} frequency pairs")
+    return np.repeat(np.arange(len(sections), dtype=np.int32), list(sections))
+
+
+def multi_stream_rotary_tables(positions, dim, theta=10000.0, sections=None):
+    """``(cos, sin)``, each float32 ``[B, S, dim/2]``, for rotary positions
+    given as streams ``positions`` [streams, B, S] (text: every stream is the
+    token's index; an image patch reads its time, row and column from three):
+    frequency pair ``i`` turns by ``positions[stream_of_pair[i]] · theta^(-2i/
+    dim)``.  Traced: the positions are data."""
+    half = dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / dim)
+    stream = rotary_stream_of_pair(half, sections)
+    pos = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., stream]   # [B, S, half]
+    angles = pos * inv_freq
+    return jnp.cos(angles), jnp.sin(angles)
+
+
+def apply_rotary(x, cos, sin, pairing="interleaved"):
+    """Rotate pairs of the last axis of ``x`` [B, S, H, dim] by the
+    position's angles.  ``cos`` / ``sin`` are ``[S, dim/2]`` (one position
+    stream, the same for every batch row: :func:`yarn_rotary_tables`) or ``[B,
+    S, dim/2]`` (:func:`multi_stream_rotary_tables`).  ``pairing``
+    ``"interleaved"`` rotates ``(x[2i], x[2i+1])`` and lays the result out as
     the DeepSeek-V3 modeling file leaves it (first halves, then second
-    halves), which a dot product of two rotated vectors does not see."""
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    halves), which a dot product of two rotated vectors does not see;
+    ``"half"`` rotates ``(x[i], x[i + dim/2])`` in place (rotate-half)."""
+    x32 = x.astype(jnp.float32)
+    if pairing == "interleaved":
+        pairs = x32.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+    elif pairing == "half":
+        a, b = jnp.split(x32, 2, axis=-1)
+    else:
+        raise ValueError(f"pairing {pairing!r}: 'interleaved' or 'half'")
+    lead = (None,) * (3 - cos.ndim)        # [S, half] → [1, S, half]
+    c, s = cos[lead + (Ellipsis, None, slice(None))], sin[lead + (Ellipsis, None, slice(None))]
     return jnp.concatenate([a * c - b * s, b * c + a * s], -1).astype(x.dtype)
 
 

@@ -272,7 +272,7 @@ _experts_in_bucket.defvjp(_experts_in_bucket_fwd, _experts_in_bucket_bwd)
 def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
                      num_experts=1, top_k=1, first_expert=0,
                      routed_scaling=1.0, norm_topk=True, scope="moe",
-                     expert_form="swiglu"):
+                     expert_form="swiglu", scoring="sigmoid"):
     """Dropless top-k routed experts over the last axis of ``x``: the part of
     the layer's sum that the experts HELD HERE give.
 
@@ -286,14 +286,21 @@ def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
     of the inner function only: router, selection bias, sort, row buckets,
     grouped products, masking and combine are one code.
 
-    ``s = sigmoid(W_r x)`` in float32; the ``top_k`` largest ``s + bias``;
-    gates ``routed_scaling · s_i / Σ_selected s_j`` (``norm_topk``) — over
+    ``scoring`` is a static choice of the router's scores: ``"sigmoid"``,
+    ``s = sigmoid(W_r x)`` in float32, the ``top_k`` largest ``s + bias``; or
+    ``"softmax"``, ``s = softmax(W_r x)`` over ALL the experts, the ``top_k``
+    largest ``s`` (no bias: ``select_bias`` is not read).  Either way the
+    gates are ``routed_scaling · s_i / Σ_selected s_j`` (``norm_topk``) — over
     all selected experts, held here or not, so the shares of a layer add up
     to the whole layer.  Pairs routed to experts held elsewhere add nothing.
-    No capacity, no auxiliary loss, no token dropped.
+    No capacity, no token dropped.
 
-    Returns ``(y, rows_routed_here, load_min, load_max, load_all)``; the
-    metrics are ``stop_gradient``-ed float32: three scalars (loads over the
+    Returns ``(y, rows_routed_here, load_min, load_max, load_all)`` and,
+    under ``"softmax"`` scoring, a sixth: the Switch load-balance term ``E ·
+    Σ_e f_e P̄_e`` over ALL the experts (``f_e`` the share of the pairs routed
+    to ``e``, no gradient; ``P̄_e`` the mean score; 1 under even routing),
+    raw: the caller weights it.  The metrics are
+    ``stop_gradient``-ed float32: three scalars (loads over the
     experts held) and the pairs routed to each of ALL the experts, ``[E]``,
     which is what the ``noaux_tc`` balancing rule moves the bias by.
     ``scope`` names the ``jax.named_scope``s ``<scope>.route`` (scores,
@@ -305,12 +312,19 @@ def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
     T = xt.shape[0]
     sg = jax.lax.stop_gradient
 
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax'")
     with jax.named_scope(scope + ".route"):
-        scores = jax.nn.sigmoid(jnp.einsum(
+        logits = jnp.einsum(
             "td,ed->te", xt.astype(jnp.float32), router_w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))              # [T, E]
-        _, idx = jax.lax.top_k(
-            sg(scores + select_bias.astype(jnp.float32)), k)
+            precision=jax.lax.Precision.HIGHEST)               # [T, E]
+        if scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(
+                sg(scores + select_bias.astype(jnp.float32)), k)
+        else:
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, idx = jax.lax.top_k(sg(scores), k)
         chosen = jnp.take_along_axis(scores, idx, axis=-1)     # [T, k]
         gates = chosen * float(routed_scaling)
         if norm_topk:
@@ -330,5 +344,10 @@ def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
                                group_sizes, n_here)
         y = y.astype(x.dtype).reshape(x.shape)
     load = group_sizes.astype(jnp.float32)
-    return (y, sg(n_here.astype(jnp.float32)), sg(load.min()), sg(load.max()),
-            sg(load_all.astype(jnp.float32)))
+    out = (y, sg(n_here.astype(jnp.float32)), sg(load.min()), sg(load.max()),
+           sg(load_all.astype(jnp.float32)))
+    if scoring == "softmax":
+        with jax.named_scope(scope + ".route"):
+            share = sg(load_all.astype(jnp.float32)) / float(T * k)
+            out += (float(E) * jnp.sum(share * scores.mean(axis=0)),)
+    return out

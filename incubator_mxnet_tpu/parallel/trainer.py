@@ -106,23 +106,17 @@ def _state_to_ndarrays(st):
     return st
 
 
-def _moe_extras(metrics):
-    """Frame metrics → the step's extras dict (raw jax scalars, fixed
-    keys — the extras pytree is part of the compile signature, so its
-    structure must be identical across every trace of one build)."""
-    if metrics is None:
-        return {}
-
+def _moe_extras(metrics, counters=None):
+    """Frame metrics and counters → the step's extras: a map from a name to
+    a raw jax scalar.  A routing metric ``m`` goes out as ``moe_<m>``, a
+    layer's counter under the profiler counter's own name (the extras pytree
+    is part of the compile signature: the layers of one build register the
+    same names in every trace)."""
     def raw(v):
         return v._data if isinstance(v, NDArray) else v
 
-    out = {
-        "moe_tokens_dropped": raw(metrics["tokens_dropped"]),
-        "moe_expert_load_min": raw(metrics["expert_load_min"]),
-        "moe_expert_load_max": raw(metrics["expert_load_max"]),
-    }
-    if "rows_routed_here" in metrics:   # layers that hold a share of experts
-        out["moe_rows_routed_here"] = raw(metrics["rows_routed_here"])
+    out = {f"moe_{name}": raw(v) for name, v in (metrics or {}).items()}
+    out.update((name, raw(v)) for name, v in (counters or {}).items())
     return out
 
 
@@ -733,9 +727,12 @@ class SPMDTrainer:
     def _drain_moe_extras(self):
         """Convert the PREVIOUS step's stashed MoE extras (by now the
         device has finished that step, so the read doesn't stall the
-        loop): bump the drop counter, refresh the gauges, emit the
-        ``moe.step`` marker.  Also called from the metrics provider so a
-        snapshot between steps sees current values."""
+        loop): bump the drop counter, refresh the gauges, add every
+        other extra to the declared counter of its name
+        (``moe_rows_routed_here``, and what a layer registered by
+        ``model_zoo.moe.register_side``), emit the ``moe.step`` marker.
+        Also called from the metrics provider so a snapshot between
+        steps sees current values."""
         with self._moe_lock:
             # swap-and-convert under the lock: the step thread and a
             # metrics-scrape thread both drain, and an unlocked swap
@@ -745,21 +742,27 @@ class SPMDTrainer:
             if not pending:
                 return
             vals = {key: _np.asarray(v) for key, v in pending.items()}
-        dropped = int(round(float(vals["moe_tokens_dropped"].sum())))
-        lmin = float(vals["moe_expert_load_min"].min())
-        lmax = float(vals["moe_expert_load_max"].max())
-        if dropped:
-            _profiler.incr("moe_tokens_dropped", dropped)
+        self._moe_last = {}
+        dropped = lmin = lmax = None
+        if "moe_tokens_dropped" in vals:     # a routed layer ran
+            dropped = int(round(float(vals.pop("moe_tokens_dropped").sum())))
+            lmin = float(vals.pop("moe_expert_load_min").min())
+            lmax = float(vals.pop("moe_expert_load_max").max())
+            if dropped:
+                _profiler.incr("moe_tokens_dropped", dropped)
+            self._moe_last = {
+                "moe_tokens_dropped": dropped,
+                "moe_expert_load_min": lmin,
+                "moe_expert_load_max": lmax,
+            }
         _profiler.incr("moe_step")
-        self._moe_last = {
-            "moe_tokens_dropped": dropped,
-            "moe_expert_load_min": lmin,
-            "moe_expert_load_max": lmax,
-        }
-        if "moe_rows_routed_here" in vals:
-            rows = int(round(float(vals["moe_rows_routed_here"].sum())))
-            _profiler.incr("moe_rows_routed_here", rows)
-            self._moe_last["moe_rows_routed_here"] = rows
+        # every other extra is a count a step under a declared counter's
+        # name: moe_rows_routed_here, and whatever a layer registered
+        # (model_zoo.moe.register_side)
+        for name, value in vals.items():
+            count = int(round(float(value.sum())))
+            _profiler.incr(name, count)
+            self._moe_last[name] = count
         if self._stages is not None:
             self._pipe_last.update(self._moe_last)
         if _profiler._active:
@@ -995,7 +998,8 @@ class SPMDTrainer:
                     if isinstance(moe_side, NDArray):
                         moe_side = moe_side._data
                     loss_scalar = loss_scalar + moe_side.astype(jnp.float32)
-                extras = _moe_extras(moe_mod.frame_metrics(moe_fr))
+                extras = _moe_extras(moe_mod.frame_metrics(moe_fr),
+                                     moe_mod.frame_counters(moe_fr))
             if not aux_idx_cell:
                 idx_map = {id(p): i for i, p in enumerate(params)}
                 aux_idx_cell.append([idx_map[id(p)] for p, _ in collector])

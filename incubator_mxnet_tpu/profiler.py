@@ -257,6 +257,10 @@ _counters = {
     "attention_dispatch_xla": 0,      # attention call sites traced onto the XLA path
     "attention_dispatch_grouped": 0,  # of either: call sites with fewer key/value heads than query heads
     "ssm_scan_traced": 0,             # chunked state-space scan call sites traced into a program
+    "attention_dispatch_masked": 0,   # of the Pallas ones: call sites given a selection of keys a query
+    "sparse_attention_traced": 0,     # indexer-selected attention call sites traced into a program
+    "sparse_attn_tiles_live": 0,      # 512 x 512 score tiles that hold a selected pair, over layers and steps
+    "sparse_attn_tiles_causal": 0,    # ... and those at or below the diagonal
     "moe_grouped_dispatch_pallas": 0,  # moe_ffn_dropless call sites traced onto the Pallas grouped-product kernels
     "moe_grouped_dispatch_xla": 0,    # moe_ffn_dropless call sites traced onto jax.lax.ragged_dot
     "elastic_restart": 0,             # supervisor job re-formations

@@ -14,7 +14,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from incubator_mxnet_tpu.ops import attention as attn_ops
 from incubator_mxnet_tpu.ops import moe as moe_ops
+from incubator_mxnet_tpu.ops import sparse_attention as sa_ops
 
 
 @pytest.fixture(scope="module")
@@ -42,11 +44,13 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-# cell: tokens, model width, expert width, experts a token, form, the row bucket the cell runs in
+# cell: tokens, model width, expert width, experts a token, form, the row bucket the cell runs in,
+# experts held
 EXPERT_LAYERS = {
-    "nemotron-3-nano-30b-a3b.clm-s8192": (8192, 2688, 1856, 6, "relu2", 4608),
-    "xing4.0-29b-a4b.clm-s4096": (4096, 3584, 1024, 4, "swiglu", 3072),
-    "nemotron-3-nano-30b-a3b.clm-s8192, every pair here": (8192, 2688, 1856, 6, "relu2", 49152),
+    "nemotron-3-nano-30b-a3b.clm-s8192": (8192, 2688, 1856, 6, "relu2", 4608, 8),
+    "xing4.0-29b-a4b.clm-s4096": (4096, 3584, 1024, 4, "swiglu", 3072, 8),
+    "keye-vl-2.0-30b-a3b.clm-s8192": (8192, 2048, 768, 8, "swiglu", 12288, 16),
+    "nemotron-3-nano-30b-a3b.clm-s8192, every pair here": (8192, 2688, 1856, 6, "relu2", 49152, 8),
 }
 
 
@@ -55,7 +59,7 @@ def test_the_held_experts_products_compile_at_the_cells_widths(cell, one_chip, n
     """Forward, input gradient and weight gradient of both grouped products
     of a layer (six Mosaic calls), bf16; 1856 is no whole number of 128-lane
     tiles, and the kernels take it as the whole extent of their blocks."""
-    tokens, d, h, top_k, form, rows = EXPERT_LAYERS[cell]
+    tokens, d, h, top_k, form, rows, held = EXPERT_LAYERS[cell]
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def loss(xt, gate, w_in, w_down, order, group_sizes, n_here):
@@ -64,7 +68,33 @@ def test_the_held_experts_products_compile_at_the_cells_widths(cell, one_chip, n
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
         spec((tokens, d), jnp.bfloat16), spec((tokens * top_k,), jnp.float32),
-        spec((8, d, h * (2 if form == "swiglu" else 1)), jnp.bfloat16),
-        spec((8, h, d), jnp.bfloat16), spec((tokens * top_k,), jnp.int32),
-        spec((8,), jnp.int32), spec((), jnp.int32)).compile()
+        spec((held, d, h * (2 if form == "swiglu" else 1)), jnp.bfloat16),
+        spec((held, h, d), jnp.bfloat16), spec((tokens * top_k,), jnp.int32),
+        spec((held,), jnp.int32), spec((), jnp.int32)).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 6
+
+
+def test_the_selected_attention_kernels_compile_at_the_keye_cells_shapes(one_chip, no_compile_cache):
+    """The blockwise forward (with its log-sum-exp) and one-pass backward
+    under an int8 selection shared by the heads, and the head-averaged
+    probabilities of the indexer's loss: 32 query heads on 4 key/value heads
+    of 128 at S 8192, bf16, 512 x 512 blocks (three Mosaic calls and a
+    fourth: the int8 blocks, their widening inside the kernels and the
+    guarded online-softmax update are what an interpreter does not check)."""
+    s, scale = 8192, 128 ** -0.5
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    q, kv = spec((1, 32, s, 128), jnp.bfloat16), spec((1, 4, s, 128), jnp.bfloat16)
+    select = spec((1, s, s), jnp.int8)
+    launch = attn_ops._Launch(False, (512, 512))
+
+    def loss(q, k, v, select):
+        out, lse = attn_ops._flash_kernels_lse(q, k, v, True, scale, launch, select)
+        return out.astype(jnp.float32).sum(), lse
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
+        q, kv, kv, select).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    probs = jax.jit(lambda q, k, lse, select: sa_ops._head_probs_pallas(
+        q, k, lse, select, scale=scale, blocks=(512, 512))).lower(
+        q, kv, spec((1, 32, s), jnp.float32), select).compile()
+    assert probs.as_text().count('custom_call_target="tpu_custom_call"') == 1
