@@ -11,7 +11,14 @@ calls:
 * :func:`index_scores` — ``I[t, s] = Σ_j H^-½ w[t, j] · relu(q_I[t, j] · k_I[s]
   · D^-½)`` in float32 over ``H`` index heads that share ONE index key, worked
   in ``q_chunk × kv_chunk`` tiles (tiles past the diagonal are skipped) so that
-  the ``[H, S, S]`` products never exist whole.
+  the ``[H, S, S]`` products never exist whole.  One algorithm, two
+  implementations, chosen a call by :func:`_index_path` from the platform,
+  the shapes and the mesh: on a TPU two Pallas kernels (forward; one backward
+  for ``dq_I``, ``dk_I`` and ``dw``) whose grid cell keeps a query block's
+  heads, a key block and the tile in VMEM and loops over the heads inside;
+  everywhere else XLA tiles (a scan of a scan).  Float32 means six bfloat16
+  partial products summed in float32 (``Precision.HIGHEST``) on both; the
+  kernels write them out and stack two to an MXU pass.
 * :func:`select_topk` — each query's ``topk`` highest-scored visible keys (all
   of them while it sees no more than ``topk``), as an int8 ``[B, S, S]``
   selection: exactly ``min(t + 1, topk)`` keys a row, ties to the lower index.
@@ -92,23 +99,13 @@ def _score_tile(q, k, w, q0, k0):
     return jnp.where(t >= s, tile, -jnp.inf)
 
 
-@register("lightning_index_scores")
-def index_scores(q_idx, k_idx, w, q_chunk=512, kv_chunk=512):
-    """The indexer's scores of every (query, key) pair of a sequence.
-
-    ``q_idx`` [B, S, H, D] (H index heads), ``k_idx`` [B, S, D] (ONE index key
-    a position, shared by the heads), ``w`` [B, S, H] (each query's weight of
-    each head) → ``[B, S, S]`` float32, ``I[b, t, s] = H^-½ Σ_j w[b, t, j] ·
-    relu(q_idx[b, t, j] · k_idx[b, s] · D^-½)`` for ``s ≤ t`` and ``-inf``
-    past the diagonal.  Computed in float32 whatever arrives, in tiles of
-    ``q_chunk × kv_chunk`` (a scan over query chunks of a scan over key
-    chunks; a tile wholly past the diagonal is not computed); a length no
-    chunk divides is padded inside.  Differentiable in all three operands
-    (each tile's products are computed again in the backward pass)."""
+def _index_scores_tiles(q_idx, k_idx, w, cq, ck):
+    """:func:`index_scores` as XLA tiles: a scan over query chunks of a scan
+    over key chunks, a tile wholly past the diagonal not computed, each
+    tile's products computed again in the backward pass; a length no chunk
+    divides is padded inside."""
     b, s = q_idx.shape[:2]
-    cq, ck = min(int(q_chunk), s), min(int(kv_chunk), s)
-    q, w, k = (_pad_rows(a.astype(_F32), 1, c)
-               for a, c in ((q_idx, cq), (w, cq), (k_idx, ck)))
+    q, w, k = (_pad_rows(a, 1, c) for a, c in ((q_idx, cq), (w, cq), (k_idx, ck)))
     k_chunks = _chunks(k, 1, ck)                               # [nk, B, Ck, D]
     tile = jax.checkpoint(_score_tile)
 
@@ -126,6 +123,301 @@ def index_scores(q_idx, k_idx, w, q_chunk=512, kv_chunk=512):
 
     _, out = lax.scan(rows, 0, (_chunks(q, 1, cq), _chunks(w, 1, cq)))
     return jnp.moveaxis(out, 0, 1).reshape(b, q.shape[1], -1)[:, :s, :s]
+
+
+# The same tiles as two Pallas kernels, forward and backward.  A cell of the
+# grid (batch row, query block, key block) holds the query block's H heads, one
+# key block and the [Bk, Bq] tile in VMEM: the [H, Bq, Bk] per-head products
+# never reach HBM.  The tile is held TRANSPOSED (keys down, queries across, as
+# the blockwise attention backward and ``_head_probs_kernel`` hold theirs), so
+# that a head's weights are a row that broadcasts down the tile and ``dw`` is a
+# sum down it; it is turned once a tile, on its way out (or, ``dI``, in).
+#
+# Precision: float32 products at ``Precision.HIGHEST`` are six bfloat16
+# products with float32 sums — x = hi + mid + lo, each a bfloat16, and of the
+# nine partial products the six that matter: hi·hi, hi·mid, mid·hi, mid·mid,
+# hi·lo, lo·hi.  The kernels form the SAME six, written out, and stack two of
+# them along a dimension the MXU would otherwise leave half empty (its tiles
+# are 128 x 128, an index head 64 wide): scores contract [q_hi | q_mid] with
+# [k_hi | k_mid] (hi·hi + mid·mid in one pass, summed in the MXU's float32
+# accumulator), with [k_mid | k_hi] (the two hi·mid), and [q_hi | q_lo] with
+# [k_lo | k_hi] (the two hi·lo): three passes at a contraction of 2 D where
+# six at D would run.  The gradients' products contract over a block's 512
+# rows and stack along their 2 D output columns: g_hi and g_mid against
+# [x_hi | x_mid], g_hi against the lo stack, g_lo against [x_hi | x_mid],
+# the two halves that are not among the six discarded: four passes where six
+# half-wide ones would run.  The parts of q and k are split off outside the
+# kernels (elementwise XLA, ``lax.reduce_precision``, which no simplification
+# removes), the parts of ``g`` inside.
+
+_BF16 = jnp.bfloat16
+_INDEX_VMEM_LIMIT = 100 << 20     # of a v5e core's 128 MiB
+
+
+def _bf16_parts(x):
+    """float32 → (hi, mid, lo): float32 arrays that each hold bfloat16
+    values exactly, hi + mid + lo == x to x's 24 bits."""
+    as_bf16 = lambda a: lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7)
+    hi = as_bf16(x)
+    mid = as_bf16(x - hi)
+    return hi, mid, as_bf16(x - hi - mid)
+
+
+def _stack(*parts):
+    return jnp.concatenate(parts, axis=-1).astype(_BF16)
+
+
+def _index_operands(q, k, w):
+    """q [B, S, H, D], k [B, S, D], w [B, S, H] float32 → the kernels'
+    operands: ``qa`` = [q_hi | q_mid], ``qb`` = [q_hi | q_lo] as [B, H, S, 2D]
+    bfloat16, ``ks`` = [k_mid | k_hi | k_hi | k_mid | k_lo | k_hi] as
+    [B, S, 6D] bfloat16, ``w`` as [B, H, S] float32."""
+    q_hi, q_mid, q_lo = _bf16_parts(q.transpose(0, 2, 1, 3))
+    k_hi, k_mid, k_lo = _bf16_parts(k)
+    return (_stack(q_hi, q_mid), _stack(q_hi, q_lo),
+            _stack(k_mid, k_hi, k_hi, k_mid, k_lo, k_hi), w.transpose(0, 2, 1))
+
+
+# a product of bfloat16 parts: ONE pass, whatever the package's default precision
+_mm = functools.partial(lax.dot_general, precision=lax.Precision.DEFAULT,
+                        preferred_element_type=_F32)
+
+
+def _tile_products(qa, qb, kb, ka, kc):
+    """A head's products with a key block, transposed: [Bk, Bq] float32,
+    the six partial products in three passes, the smallest summed first."""
+    return _mm(kc, qb, _att._NT) + _mm(kb, qa, _att._NT) + _mm(ka, qa, _att._NT)
+
+
+def _index_fwd_kernel(qa_ref, qb_ref, ks_ref, w_ref, out_ref, *, scale):
+    """qa_ref / qb_ref [1, H, Bq, 2D] bfloat16 (the query block's heads),
+    ks_ref [1, Bk, 6D] bfloat16, w_ref [1, H, Bq] float32, out_ref [1, Bq, Bk]
+    float32: ``scale · Σ_h w[h] · relu(q_h · k)``, ``-inf`` past the diagonal."""
+    i, j = _pl.program_id(1), _pl.program_id(2)
+    heads, block_q, d2 = qa_ref.shape[1:]
+    block_k = ks_ref.shape[1]
+    live = j * block_k <= i * block_q + block_q - 1
+
+    @_pl.when(jnp.logical_not(live))                          # wholly past the diagonal
+    def _():
+        out_ref[0] = jnp.full((block_q, block_k), -jnp.inf, _F32)
+
+    @_pl.when(live)
+    def _():
+        kb, ka, kc = (ks_ref[0, :, n * d2:(n + 1) * d2] for n in range(3))
+
+        def head(h, total):
+            st = _tile_products(qa_ref[0, h], qb_ref[0, h], kb, ka, kc)
+            return total + jnp.maximum(st, 0.0) * w_ref[0, _pl.ds(h, 1), :]
+
+        total = lax.fori_loop(0, heads, head, jnp.zeros((block_k, block_q), _F32))
+        t = i * block_q + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        s = j * block_k + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        out_ref[0] = jnp.where(t >= s, total.T * scale, -jnp.inf)
+
+
+def _g_parts(g):
+    """:func:`_bf16_parts` inside a kernel: the conversions round."""
+    hi = g.astype(_BF16)
+    rest = g - hi.astype(_F32)
+    mid = rest.astype(_BF16)
+    return hi, mid, (rest - mid.astype(_F32)).astype(_BF16)
+
+
+def _index_bwd_kernel(qa_ref, qb_ref, ks_ref, w_ref, di_ref, dq_ref, dk_ref, dw_ref):
+    """The forward's operands and di_ref [1, Bq, Bk] float32 → dq_ref [1, H,
+    Bq, 2D], dk_ref [1, S, 2D], dw_ref [1, H, Bq] float32, all without the
+    scale, dq and dk as the two halves whose sum is the gradient.  dq and dw
+    are the query block's, summed over its key blocks; dk is the batch row's,
+    resident while its whole grid goes by.  ``g_h = dI · w[h] · [q_h · k >
+    0]``; ``dq_h += g_h k``, ``dk += g_hᵀ q_h``, ``dw[h] = Σ_s dI · relu(q_h ·
+    k)``: each live tile's products once more, then two products a head."""
+    i, j = _pl.program_id(1), _pl.program_id(2)
+    heads, block_q, d2 = qa_ref.shape[1:]
+    block_k = ks_ref.shape[1]
+
+    @_pl.when((i == 0) & (j == 0))
+    def _():
+        dk_ref[...] = jnp.zeros(dk_ref.shape, _F32)
+
+    @_pl.when(j == 0)
+    def _():
+        dq_ref[...] = jnp.zeros(dq_ref.shape, _F32)
+        dw_ref[...] = jnp.zeros(dw_ref.shape, _F32)
+
+    @_pl.when(j * block_k <= i * block_q + block_q - 1)
+    def _():
+        kb, ka, kc = (ks_ref[0, :, n * d2:(n + 1) * d2] for n in range(3))
+        t = i * block_q + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+        s = j * block_k + lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
+        di = jnp.where(t >= s, di_ref[0].T, 0.0)                       # [Bk, Bq]
+        head_row = lax.broadcasted_iota(jnp.int32, (heads, block_q), 0)
+        first_half = lax.broadcasted_iota(jnp.int32, (1, d2), 1) < d2 // 2
+
+        def head(h, carry):
+            dk, dw = carry
+            qa, qb = qa_ref[0, h], qb_ref[0, h]
+            st = _tile_products(qa, qb, kb, ka, kc)
+            dw_h = jnp.sum(di * jnp.maximum(st, 0.0), axis=0, keepdims=True)
+            g_hi, g_mid, g_lo = _g_parts(
+                jnp.where(st > 0.0, di * w_ref[0, _pl.ds(h, 1), :], 0.0))
+            # against [x_hi | x_mid]: both halves of g_hi's and g_mid's, the
+            # first of g_lo's; against the lo stack: g_hi's lo half
+            dq_ref[0, h] += (
+                jnp.where(first_half, _mm(g_lo, ka, _att._TN) + _mm(g_hi, kc, _att._TN), 0.0)
+                + _mm(g_mid, ka, _att._TN) + _mm(g_hi, ka, _att._TN))
+            dk = dk + (jnp.where(first_half, _mm(g_lo, qa, _att._NN), _mm(g_hi, qb, _att._NN))
+                       + _mm(g_mid, qa, _att._NN) + _mm(g_hi, qa, _att._NN))
+            return dk, dw + jnp.where(head_row == h, dw_h, 0.0)
+
+        dk, dw = lax.fori_loop(
+            0, heads, head,
+            (jnp.zeros((block_k, d2), _F32), jnp.zeros((heads, block_q), _F32)))
+        rows = _pl.ds(_pl.multiple_of(j * block_k, block_k), block_k)
+        dk_ref[0, rows, :] += dk
+        dw_ref[0] += dw
+
+
+def _index_launch(q, k, w, blocks, semantics):
+    """What both kernels' launches share: the operands, their block specs,
+    the key block a cell reads — a tile wholly past the diagonal asks for the
+    query block's last live one again, so nothing is fetched for it — and the
+    compiler's parameters."""
+    h, d = q.shape[2:]
+    bq, bk = blocks
+    seen = lambda i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
+    heads = _pl.BlockSpec((1, h, bq, 2 * d), lambda b, i, j: (b, 0, i, 0))
+    specs = [heads, heads,
+             _pl.BlockSpec((1, bk, 6 * d), lambda b, i, j: (b, seen(i, j), 0)),
+             _pl.BlockSpec((1, h, bq), lambda b, i, j: (b, 0, i))]
+    params = _pltpu.CompilerParams(dimension_semantics=semantics,
+                                   vmem_limit_bytes=_INDEX_VMEM_LIMIT)
+    return _index_operands(q, k, w), specs, seen, params
+
+
+@functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
+def _index_fwd_pallas(q, k, w, *, blocks, interpret=False):
+    """q [B, S, H, D], k [B, S, D], w [B, S, H] float32 → [B, S, S] float32."""
+    b, s, h, d = q.shape
+    bq, bk = blocks
+    operands, specs, _, params = _index_launch(
+        q, k, w, blocks, ("parallel", "parallel", "arbitrary"))
+    return _pl.pallas_call(
+        functools.partial(_index_fwd_kernel, scale=(h * d) ** -0.5),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), _F32),
+        grid=(b, s // bq, s // bk),
+        in_specs=specs,
+        out_specs=_pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, j)),
+        interpret=interpret,
+        name="index_scores_fwd",
+        compiler_params=params,
+    )(*operands)
+
+
+@functools.partial(jax.jit, static_argnames=("blocks", "interpret"))
+def _index_bwd_pallas(q, k, w, di, *, blocks, interpret=False):
+    """The forward's operands and ``di`` [B, S, S] float32 (anything past
+    the diagonal is ignored) → (dq, dk, dw) float32, shaped as q, k, w."""
+    b, s, h, d = q.shape
+    bq, bk = blocks
+    operands, specs, seen, params = _index_launch(
+        q, k, w, blocks, ("parallel", "arbitrary", "arbitrary"))
+    dq, dk, dw = _pl.pallas_call(
+        _index_bwd_kernel,
+        out_shape=(jax.ShapeDtypeStruct((b, h, s, 2 * d), _F32),
+                   jax.ShapeDtypeStruct((b, s, 2 * d), _F32),
+                   jax.ShapeDtypeStruct((b, h, s), _F32)),
+        grid=(b, s // bq, s // bk),
+        in_specs=specs + [_pl.BlockSpec((1, bq, bk), lambda b, i, j: (b, i, seen(i, j)))],
+        out_specs=(specs[0],
+                   _pl.BlockSpec((1, s, 2 * d), lambda b, i, j: (b, 0, 0)),
+                   specs[3]),
+        interpret=interpret,
+        name="index_scores_bwd",
+        compiler_params=params,
+    )(*operands, di)
+    scale = (h * d) ** -0.5
+    halves = lambda a: (a[..., :d] + a[..., d:]) * scale
+    return halves(dq).transpose(0, 2, 1, 3), halves(dk), dw.transpose(0, 2, 1) * scale
+
+
+def _launched(entry, launch, *arrays):
+    """``entry`` (one of the two above) where and how ``launch`` says."""
+    return _att._on_mesh(launch, functools.partial(
+        entry, blocks=launch.blocks, interpret=launch.interpret), *arrays)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _index_scores_kernels(q, k, w, launch):
+    """:func:`index_scores` in the Pallas kernels, forward and backward:
+    float32 operands whose length ``launch.blocks`` divide."""
+    return _launched(_index_fwd_pallas, launch, q, k, w)
+
+
+def _index_scores_kernels_fwd(q, k, w, launch):
+    return _index_scores_kernels(q, k, w, launch), (q, k, w)
+
+
+def _index_scores_kernels_bwd(launch, res, di):
+    return _launched(_index_bwd_pallas, launch, *res, di)
+
+
+_index_scores_kernels.defvjp(_index_scores_kernels_fwd, _index_scores_kernels_bwd)
+
+
+def _index_path(q_idx, cq, ck):
+    """THE predicate of :func:`index_scores`: the kernels' :class:`_Launch`,
+    or None for the XLA tiles.  Decided once a call from what the operands
+    and the trace show, as ``attention._kernel_path`` decides for attention:
+    the platform (the interpreter only by name), chunks that divide the
+    length and that Mosaic can tile (the tile is turned inside the kernels:
+    128 rows and columns at a time), a head width whose pair fills whole
+    128-lane columns, VMEM for the blocks, and the mesh the trace is for (no
+    compiler partitions a Mosaic kernel: a batch split over ``dp`` / ``fsdp``
+    launches under a ``shard_map``, any other split takes the XLA tiles)."""
+    b, s, h, d = q_idx.shape
+    use, interpret = _att._use_pallas(q_idx)
+    if not use or s % cq or s % ck:
+        return None
+    if not interpret:
+        # two [H, Bq, 2D] bf16 stacks and dq's float32 one, dk's [S, 2D], the
+        # [Bq, Bk] float32 tiles of dI: all double-buffered; ~8 tiles of
+        # values; 16 MiB of the limit left to the compiler
+        vmem = 2 * (2 * h * cq * 2 * d * 2 + h * cq * 2 * d * 4 + s * 2 * d * 4
+                    + cq * ck * 4) + 8 * cq * ck * 4
+        if cq % 128 or ck % 128 or (2 * d) % 128 or vmem > _INDEX_VMEM_LIMIT - (16 << 20):
+            return None
+    where = _att._rows_split(b)
+    return None if where is None else _att._Launch(interpret, (cq, ck), *where)
+
+
+@register("lightning_index_scores")
+def index_scores(q_idx, k_idx, w, q_chunk=512, kv_chunk=512):
+    """The indexer's scores of every (query, key) pair of a sequence.
+
+    ``q_idx`` [B, S, H, D] (H index heads), ``k_idx`` [B, S, D] (ONE index key
+    a position, shared by the heads), ``w`` [B, S, H] (each query's weight of
+    each head) → ``[B, S, S]`` float32, ``I[b, t, s] = H^-½ Σ_j w[b, t, j] ·
+    relu(q_idx[b, t, j] · k_idx[b, s] · D^-½)`` for ``s ≤ t`` and ``-inf``
+    past the diagonal.  Computed in float32 whatever arrives (products of six
+    bfloat16 partial products, float32 sums), in tiles of ``q_chunk ×
+    kv_chunk``, a tile wholly past the diagonal not computed: on a TPU in two
+    Pallas kernels that keep a tile's per-head products in VMEM
+    (:func:`_index_path` says when), else as XLA tiles (a scan over query
+    chunks of a scan over key chunks; a length no chunk divides is padded
+    inside).  Differentiable in all three operands (each tile's products are
+    computed again in the backward pass)."""
+    from .. import profiler
+
+    s = q_idx.shape[1]
+    cq, ck = min(int(q_chunk), s), min(int(kv_chunk), s)
+    q, k, w = (a.astype(_F32) for a in (q_idx, k_idx, w))
+    launch = _index_path(q, cq, ck)
+    if launch is None:
+        profiler.incr("index_scores_dispatch_xla")
+        return _index_scores_tiles(q, k, w, cq, ck)
+    profiler.incr("index_scores_dispatch_pallas")
+    return _index_scores_kernels(q, k, w, launch)
 
 
 # ---------------------------------------------------------------------------

@@ -261,6 +261,8 @@ _counters = {
     "sparse_attention_traced": 0,     # indexer-selected attention call sites traced into a program
     "sparse_attn_tiles_live": 0,      # 512 x 512 score tiles that hold a selected pair, over layers and steps
     "sparse_attn_tiles_causal": 0,    # ... and those at or below the diagonal
+    "index_scores_dispatch_pallas": 0,  # index_scores call sites traced onto its Pallas kernels
+    "index_scores_dispatch_xla": 0,   # index_scores call sites traced onto the XLA tiles
     "moe_grouped_dispatch_pallas": 0,  # moe_ffn_dropless call sites traced onto the Pallas grouped-product kernels
     "moe_grouped_dispatch_xla": 0,    # moe_ffn_dropless call sites traced onto jax.lax.ragged_dot
     "elastic_restart": 0,             # supervisor job re-formations

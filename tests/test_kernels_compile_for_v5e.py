@@ -98,3 +98,32 @@ def test_the_selected_attention_kernels_compile_at_the_keye_cells_shapes(one_chi
         q, k, lse, select, scale=scale, blocks=(512, 512))).lower(
         q, kv, spec((1, 32, s), jnp.float32), select).compile()
     assert probs.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_the_index_scores_kernels_compile_at_the_keye_cells_shape(one_chip, no_compile_cache):
+    """The indexer's scores of 16 index heads of 64 on one index key at S
+    8192, float32, 512 x 512 tiles: the forward kernel and the one backward
+    kernel (bfloat16 stacks of two parts a 128-lane column, the float32 tile
+    turned inside the kernel, products with a transposed left operand, dk's
+    [S, 128] block resident for the whole grid, 100 MiB of VMEM asked for:
+    what an interpreter does not check).  The gradient of a scalar that
+    needs the scores holds exactly the two, and the dispatcher's predicate
+    takes these operands."""
+    s = 8192
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    q, k, w = spec(1, s, 16, 64), spec(1, s, 64), spec(1, s, 16)
+    launch = attn_ops._Launch(False, (512, 512))
+    assert sa_ops._index_path(jax.ShapeDtypeStruct(q.shape, q.dtype), 512, 512) is None   # here: a CPU
+
+    forward = jax.jit(lambda q, k, w: sa_ops._index_scores_kernels(q, k, w, launch)).lower(
+        q, k, w).compile()
+    assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+    def loss(q, k, w, select, target):
+        return sa_ops.indexer_kl_loss(sa_ops._index_scores_kernels(q, k, w, launch), select, target)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, w, jax.ShapeDtypeStruct((1, s, s), jnp.int8, sharding=one_chip), spec(1, s, s)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "index_scores_fwd" in text and "index_scores_bwd" in text

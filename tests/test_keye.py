@@ -103,6 +103,175 @@ def test_index_scores_gradients_match_the_dense_form(s, cq, ck):
         np.testing.assert_allclose(g, r, atol=1e-5)
 
 
+# the index scores' Pallas kernels, in the interpreter: (S, q_chunk, kv_chunk, H, D, batch) — small
+# heads, the cell's 16 heads of 64 in square and in oblong blocks, one block a side, a batch of one
+KERNEL_CASES = pytest.mark.parametrize("s,cq,ck,heads,dim,b", [
+    (64, 16, 16, 4, 8, 2), (256, 128, 128, 16, 64, 2), (256, 64, 128, 16, 64, 1),
+    (128, 128, 32, 16, 64, 2), (96, 96, 96, 3, 16, 2)],
+    ids=["s64-16x16-h4-d8", "s256-128x128-h16-d64", "s256-64x128-h16-d64-b1",
+         "s128-128x32-h16-d64", "s96-one-block-h3-d16"])
+
+
+def float64_index_scores(q, k, w, ct=None):
+    """The dense form in float64 (NumPy): the scores, and with a cotangent
+    ``ct`` [B, S, S] (zero where it does not count) the three gradients."""
+    q, k, w = (np.asarray(a, np.float64) for a in (q, k, w))
+    heads, dim = q.shape[2:]
+    c = (heads * dim) ** -0.5
+    prod = np.einsum("bqhd,bkd->bqhk", q, k)
+    scores = (np.maximum(prod, 0) * w[..., None]).sum(2) * c
+    if ct is None:
+        return scores
+    ct = np.asarray(ct, np.float64)[:, :, None, :]
+    g = ct * w[..., None] * (prod > 0)
+    return (np.einsum("bqhk,bkd->bqhd", g, k) * c, np.einsum("bqhk,bqhd->bkd", g, q) * c,
+            (ct * np.maximum(prod, 0)).sum(-1) * c)
+
+
+def selected_cotangent(b, s, seed=2, share=0.3):
+    """What the indexer's loss sends back: zero off a selection of visible keys."""
+    rng = np.random.RandomState(seed)
+    chosen = (rng.rand(b, s, s) < share) & causal(s)
+    return jnp.asarray(rng.randn(b, s, s) * chosen, jnp.float32)
+
+
+def dispatched(before):
+    after = profiler.counters()
+    return tuple(after[n] - before[n] for n in ("index_scores_dispatch_pallas",
+                                                "index_scores_dispatch_xla"))
+
+
+@KERNEL_CASES
+def test_index_scores_kernel_matches_the_dense_form(monkeypatch, s, cq, ck, heads, dim, b):
+    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
+    q, k, w = indexer_inputs(s, b, heads, dim)
+    before = profiler.counters()
+    got, want = sa.index_scores(q, k, w, cq, ck), dense_index_scores(q, k, w)
+    assert dispatched(before) == (1, 0)
+    assert got.dtype == jnp.float32 and got.shape == (b, s, s)
+    assert np.array_equal(np.isneginf(got), ~np.broadcast_to(causal(s), got.shape))
+    np.testing.assert_allclose(np.where(causal(s), got, 0), np.where(causal(s), want, 0),
+                               atol=4e-6)
+
+
+@KERNEL_CASES
+def test_index_scores_kernel_gradients_match_the_dense_form(monkeypatch, s, cq, ck, heads, dim, b):
+    """All three, under a cotangent that is zero off a selection; what stands
+    past the diagonal of the cotangent is ignored, as the tiles ignore it."""
+    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
+    q, k, w = indexer_inputs(s, b, heads, dim, seed=1)
+    ct = selected_cotangent(b, s)
+    junk = ct + jnp.asarray(~causal(s), jnp.float32)       # ones past the diagonal
+    run = lambda *a: sa.index_scores(*a, cq, ck)
+    before = profiler.counters()
+    got = jax.vjp(run, q, k, w)[1](junk)
+    assert dispatched(before) == (1, 0)
+    loss = lambda *a: jnp.sum(jnp.where(causal(s), dense_index_scores(*a), 0.0) * ct)
+    for g, r in zip(got, jax.grad(loss, (0, 1, 2))(q, k, w)):
+        assert g.dtype == jnp.float32 and g.shape == r.shape
+        np.testing.assert_allclose(g, r, atol=1e-5)
+
+
+def test_index_scores_kernel_is_as_exact_as_the_highest_einsum(monkeypatch):
+    """Value and gradients against a float64 dense form, at the cell's 16
+    heads of 64: the kernels' six bfloat16 partial products, two to an MXU
+    pass, err no more than the float32 einsum at ``Precision.HIGHEST`` — and
+    a product of bfloat16 operands alone, or one partial product left out,
+    would err a thousand times more."""
+    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
+    b, s = 2, 256
+    q, k, w = indexer_inputs(s, b, 16, 64, seed=7)
+    ct = selected_cotangent(b, s, seed=8)
+    visible = causal(s)[None]
+    masked = lambda f: lambda *a: jnp.where(visible, f(*a), 0.0)
+
+    def einsum_highest(q, k, w):
+        prod = jnp.einsum("bqhd,bkd->bqhk", q, k, precision=jax.lax.Precision.HIGHEST) * 64 ** -0.5
+        return (jax.nn.relu(prod) * w[..., None]).sum(2) * 16 ** -0.5
+
+    kernel = masked(lambda *a: sa.index_scores(*a, 128, 128))
+    want = (np.where(visible, float64_index_scores(q, k, w), 0.0),) + float64_index_scores(q, k, w, ct)
+    worst = {}
+    for name, f in (("kernel", kernel), ("einsum", masked(einsum_highest))):
+        value, vjp = jax.vjp(f, q, k, w)
+        worst[name] = [np.abs(np.asarray(a) - r).max() / np.abs(r).max()
+                       for a, r in zip((value,) + vjp(ct), want)]
+    for got, ref in zip(worst["kernel"], worst["einsum"]):
+        assert got <= max(ref, 2e-7) * 1.25, worst
+    assert max(worst["kernel"]) < 1e-6, worst
+
+
+@pytest.mark.parametrize("flash,s,chunks,path", [
+    ("interpret", 64, (16, 16), "pallas"), ("interpret", 40, (64, 64), "pallas"),
+    ("interpret", 50, (16, 8), "xla"), ("interpret", 64, (16, 24), "xla"),
+    ("auto", 64, (16, 16), "xla"), ("off", 64, (16, 16), "xla")],
+    ids=["chunks-divide", "shorter-than-a-chunk", "no-chunk-divides", "key-chunk-does-not-divide",
+         "on-the-cpu", "kernels-off"])
+def test_index_scores_dispatch_is_counted_at_trace_time(monkeypatch, flash, s, chunks, path):
+    """One count a traced call site, on the path the predicate chose: the
+    kernels where the platform runs them and the chunks divide the length,
+    the XLA tiles for everything else; a compiled program is not traced, and
+    not counted, again."""
+    monkeypatch.setenv("MXNET_TPU_FLASH", flash)
+    q, k, w = indexer_inputs(s, seed=5)
+    launch = sa._index_path(q, min(chunks[0], s), min(chunks[1], s))
+    assert (launch is not None) == (path == "pallas")
+    run = jax.jit(lambda *a: sa.index_scores(*a, *chunks))
+    before = profiler.counters()
+    got = run(q, k, w)
+    assert dispatched(before) == ((1, 0) if path == "pallas" else (0, 1))
+    run(q, k, w)
+    assert dispatched(before) == ((1, 0) if path == "pallas" else (0, 1))
+    traced = str(jax.make_jaxpr(lambda *a: sa.index_scores(*a, *chunks))(q, k, w))
+    assert traced.count("pallas_call") == (path == "pallas")
+    want = dense_index_scores(q, k, w)
+    np.testing.assert_allclose(np.where(causal(s), got, 0), np.where(causal(s), want, 0), atol=4e-6)
+
+
+def test_compiled_index_kernels_are_asked_only_for_blocks_mosaic_tiles(monkeypatch):
+    """Off the interpreter the predicate also wants 128-row and 128-column
+    tiles (the tile is turned inside the kernels), a head width whose pair
+    fills whole 128-lane columns, and blocks that fit VMEM."""
+    monkeypatch.setenv("MXNET_TPU_FLASH", "on")
+    like = lambda s, h, d: jax.ShapeDtypeStruct((1, s, h, d), jnp.float32)
+    assert sa._index_path(like(8192, 16, 64), 512, 512).blocks == (512, 512)
+    assert sa._index_path(like(1024, 16, 128), 256, 128).blocks == (256, 128)
+    assert sa._index_path(like(8192, 16, 64), 512, 64) is None       # a 64-column tile
+    assert sa._index_path(like(8192, 16, 48), 512, 512) is None      # 96 lanes a pair
+    assert sa._index_path(like(8192, 16, 64), 512, 500) is None      # divides nothing
+    assert sa._index_path(like(8192, 128, 64), 2048, 2048) is None   # VMEM
+    assert sa._index_path(like(1 << 18, 16, 64), 512, 512) is None   # dk's [S, 2D] resident
+
+
+@pytest.mark.parametrize("axes,kernels", [({"dp": 2}, True), ({"dp": 2, "tp": 2}, False)],
+                         ids=["dp2", "dp2-tp2"])
+def test_index_kernels_are_placed_on_the_mesh_of_the_trace(monkeypatch, axes, kernels):
+    """Under a mesh that splits the batch alone the kernels are launched in a
+    ``shard_map`` over its rows; a mesh that splits the model takes the XLA
+    tiles: no compiler partitions a Mosaic kernel."""
+    from incubator_mxnet_tpu.parallel import mesh_scope
+
+    n = int(np.prod(list(axes.values())))
+    if len(jax.devices()) < n:
+        pytest.skip(f"needs {n} devices")
+    monkeypatch.setenv("MXNET_TPU_FLASH", "interpret")
+    mesh = make_mesh(devices=jax.devices()[:n], **axes)
+    q, k, w = indexer_inputs(64, seed=6)
+    ct = selected_cotangent(2, 64)
+    loss = lambda *a: jnp.sum(jnp.where(causal(64), sa.index_scores(*a, 16, 16), 0.0) * ct)
+    want = jax.grad(loss, (0, 1, 2))(q, k, w)
+
+    def scoped(*a):
+        with mesh_scope(mesh):
+            return jax.grad(loss, (0, 1, 2))(*a)
+
+    traced = str(jax.make_jaxpr(scoped)(q, k, w))
+    assert traced.count("pallas_call") == (2 if kernels else 0)
+    assert traced.count("shard_map") == (2 if kernels else 0)
+    for g, r in zip(jax.jit(scoped)(q, k, w), want):
+        np.testing.assert_allclose(g, r, atol=1e-5)
+
+
 @TILINGS
 @pytest.mark.parametrize("ties", [False, True], ids=["distinct", "ties"])
 def test_selection_is_exactly_the_top_k_visible_keys(s, cq, ck, ties):
@@ -674,6 +843,11 @@ def test_spmd_trainer_step_lowers_the_loss_and_compiles_once(monkeypatch, flash)
     assert before["sparse_attention_traced"] - counted["sparse_attention_traced"] >= 2
     masked = before["attention_dispatch_masked"] - counted["attention_dispatch_masked"]
     assert (masked >= 2) if flash == "interpret" else (masked == 0)
+    scored = [before[n] - counted[n] for n in ("index_scores_dispatch_pallas",
+                                               "index_scores_dispatch_xla")]
+    taken, other = scored if flash == "interpret" else scored[::-1]
+    assert taken == before["sparse_attention_traced"] - counted["sparse_attention_traced"]
+    assert other == 0
     assert after["sparse_attention_traced"] == before["sparse_attention_traced"]   # no retrace
     for scope in ("keye.attn/", "keye.attn.proj", "keye.attn.index/", "keye.attn.select",
                   "keye.attn.core", "keye.attn.index_loss", "keye.attn.out",

@@ -151,12 +151,16 @@ class TestTransformerSeq2Seq:
         # warm both jit caches (compile time excluded from the ratio)
         beam_search(net, mx.nd.array(src, dtype="int32"), use_cache=True, **args)
         beam_search(net, mx.nd.array(src, dtype="int32"), use_cache=False, **args)
-        t0 = time.perf_counter()
-        beam_search(net, mx.nd.array(src, dtype="int32"), use_cache=True, **args)
-        t_cache = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        beam_search(net, mx.nd.array(src, dtype="int32"), use_cache=False, **args)
-        t_rerun = time.perf_counter() - t0
+
+        def timed(use_cache):
+            t0 = time.perf_counter()
+            beam_search(net, mx.nd.array(src, dtype="int32"), use_cache=use_cache, **args)
+            return time.perf_counter() - t0
+
+        # the sandbox shares its cores: one timing of the pair read 3.0x and the
+        # next 7x on the same tree; the least of five, taken in turn, is the decode's own
+        pairs = [(timed(True), timed(False)) for _ in range(5)]
+        t_cache, t_rerun = (min(side) for side in zip(*pairs))
         assert t_rerun / t_cache >= 5.0, (t_rerun, t_cache)
 
     def test_transformer_big_config(self):
