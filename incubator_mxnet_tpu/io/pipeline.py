@@ -34,11 +34,17 @@ Four pillars:
    reports memory pressure (``memory_stats`` watermark past
    ``MXNET_IO_HBM_FRAC`` of ``bytes_limit``).
 
-Observability (house style): ``io.prep`` / ``io.transfer`` / ``io.wait``
-spans, declared ``io_pipeline_*`` counters, and a
-``register_metrics_provider`` feed (buffer occupancy/bytes, depth,
-consumer-stall p50/p99) into JSONL / Prometheus.  See
-docs/input_pipeline.md.
+Observability (house style): every stage times its work with
+``profiler.span`` — ``io.read`` (reader: one ``next()`` on the source),
+``io.prep`` (a worker: ``prep_fn``), ``io.transfer`` (transfer thread:
+stack + ``device_put``) and ``io.wait`` (consumer: a stall on an empty
+buffer) — so each lands on the host plane of any running JAX trace, on the
+device operations' clock, and in the ring when the recorder is armed.  The
+spans of one batch share ``seq=``, its delivery position this epoch.
+Declared ``io_pipeline_*`` counters (``io_pipeline_wait_us``: what the
+consumer waited, without a trace) and a ``register_metrics_provider`` feed
+(buffer occupancy/bytes, depth, consumer-stall p50/p99) go into JSONL /
+Prometheus.  See docs/input_pipeline.md.
 
 Threading contract: ``__next__``/``reset``/``close`` are consumer-thread
 calls; all jax transfer work happens on the single transfer thread, so
@@ -47,6 +53,7 @@ numpy.
 """
 from __future__ import annotations
 
+import itertools
 import queue as _queue
 import threading
 import time
@@ -93,17 +100,26 @@ def _default_name():
     return "io_pipeline" if n == 1 else f"io_pipeline{n}"
 
 
+def _leaf(a):
+    """One leaf as an array with ``shape`` / ``dtype`` / ``nbytes``.  A
+    ``jax.Array`` stays what it is: its host copy is the transfer stage's
+    work and is made inside ``io.transfer`` (``_place_leaves``)."""
+    if isinstance(a, NDArray):
+        a = a._data
+    return a if isinstance(a, (jax.Array, _np.ndarray)) else _np.asarray(a)
+
+
 def _leaves(batch):
     """Flatten one source item into (leaves, rebuild) where ``leaves`` is a
-    list of host numpy arrays and ``rebuild(new_leaves)`` reassembles the
-    item with the leaves replaced by their device-resident counterparts.
+    list of arrays (numpy, or ``jax.Array`` where the source made one) and
+    ``rebuild(new_leaves)`` reassembles the item with the leaves replaced
+    by their device-resident counterparts.
     Type affinity is preserved: numpy in → ``jax.Array`` out, NDArray /
     DataBatch in → NDArray-wrapped device arrays out."""
     if isinstance(batch, DataBatch):
         n_data = len(batch.data or [])
         arrs = list(batch.data or []) + list(batch.label or [])
-        leaves = [_np.asarray(a._data if isinstance(a, NDArray) else a)
-                  for a in arrs]
+        leaves = [_leaf(a) for a in arrs]
 
         def rebuild(new):
             wrapped = [NDArray(a) for a in new]
@@ -116,9 +132,7 @@ def _leaves(batch):
         return leaves, rebuild
     if isinstance(batch, dict):
         keys = list(batch)
-        leaves = [_np.asarray(batch[k]._data
-                              if isinstance(batch[k], NDArray) else batch[k])
-                  for k in keys]
+        leaves = [_leaf(batch[k]) for k in keys]
         wrap = [isinstance(batch[k], NDArray) for k in keys]
 
         def rebuild(new):
@@ -127,8 +141,7 @@ def _leaves(batch):
 
         return leaves, rebuild
     if isinstance(batch, (list, tuple)):
-        leaves = [_np.asarray(a._data if isinstance(a, NDArray) else a)
-                  for a in batch]
+        leaves = [_leaf(a) for a in batch]
         wrap = [isinstance(a, NDArray) for a in batch]
         cls = type(batch)
 
@@ -137,8 +150,8 @@ def _leaves(batch):
 
         return leaves, rebuild
     if isinstance(batch, NDArray):
-        return [_np.asarray(batch._data)], lambda new: NDArray(new[0])
-    return [_np.asarray(batch)], lambda new: new[0]
+        return [_leaf(batch)], lambda new: NDArray(new[0])
+    return [_leaf(batch)], lambda new: new[0]
 
 
 def _rows_compatible(a, b):
@@ -279,6 +292,13 @@ class _Engine:
         self._ready_cond = threading.Condition(self._lock)
         self._buf = []               # device-resident items, delivery order
         self._ready = {}             # seq -> (prepped_batch, exc)
+        self._next_seq = 0           # the seq the transfer stage wants next:
+                                     # a worker publishes at most 2 x workers
+                                     # ahead of it (the prep queue's bound),
+                                     # so an endless source cannot run the
+                                     # host out of memory
+        self._wait_seq = 0           # the seq the consumer takes next (what
+                                     # an ``io.wait`` span waited for)
         self._prep_q = None          # (seq, raw_batch) feed to the workers
         self._threads = []
         self._stop = threading.Event()
@@ -349,6 +369,8 @@ class _Engine:
             self._stop.clear()
             self._buf = []
             self._ready = {}
+            self._next_seq = 0
+            self._wait_seq = 0
             self._gen += 1
             # a resumed epoch starts its delivered-count at the snapshot
             # cursor, so a LATER snapshot of the same epoch stays exact
@@ -505,12 +527,19 @@ class _Engine:
         skipped = 0
         try:
             it = self._open_epoch(extra_resets)
-            for i, batch in enumerate(it):
-                if self._dead(gen):
-                    return
-                if self._stride and i % self.num_parts != self.part_index:
+            for i in itertools.count():
+                strided = self._stride and i % self.num_parts != self.part_index
+                mine = not strided and skipped >= skip
+                # io.read: the source's whole cost for one batch (a
+                # DataLoader's record reads and batchify happen in here)
+                with _profiler.span("io.read", "io",
+                                    {"seq": seq if mine else -1}):
+                    batch = next(it, _EOS)
+                if batch is _EOS or self._dead(gen):
+                    break
+                if strided:
                     continue
-                if skipped < skip:
+                if not mine:
                     skipped += 1
                     continue
                 self._put_prep(q, gen, (seq, batch, None))
@@ -541,17 +570,21 @@ class _Engine:
                 self._publish(gen, seq, _EOS, None)
                 return
             if err is None and self._prep_fn is not None:
-                t0 = _perf() if _profiler._active else None
-                try:
-                    batch = self._prep_fn(batch)
-                except BaseException as e:  # noqa: BLE001
-                    batch, err = None, e
-                if t0 is not None:
-                    _profiler.record_span("io.prep", "io", t0)
+                with _profiler.span("io.prep", "io", {"seq": seq}):
+                    try:
+                        batch = self._prep_fn(batch)
+                    except BaseException as e:  # noqa: BLE001
+                        batch, err = None, e
             self._publish(gen, seq, batch, err)
 
     def _publish(self, gen, seq, batch, err):
         with self._ready_cond:
+            # bounded like the prep queue.  The worker that holds the seq
+            # the transfer stage wants never parks here (the queue is FIFO,
+            # so that seq was taken before any seq that does), so no cycle
+            while (seq - self._next_seq >= 2 * self._num_workers
+                   and not self._dead(gen)):
+                self._ready_cond.wait(timeout=0.05)
             if gen != self._gen:
                 return  # zombie from a pre-reset generation
             if seq not in self._ready:  # EOS may be re-published by siblings
@@ -568,7 +601,7 @@ class _Engine:
         before an in-stream error) still ships, as a short window."""
         next_seq = 0
         window = max(1, int(self._window))
-        pend = []    # prepped (leaves, rebuild) rows awaiting a window
+        pend = []    # prepped (leaves, rebuild, seq) rows awaiting a window
 
         def emit(batch, err, nbytes, count):
             # depth-bounded put that notices close(); False = stage died
@@ -587,25 +620,29 @@ class _Engine:
                 self._buf_cond.notify_all()
             return True
 
-        def place_and_emit(item, count):
-            # item: a raw batch (window == 1) or the pending rows list
+        def place_and_emit(item, count, seq):
+            # item: a raw batch (window == 1) or the pending rows list;
+            # seq: its delivery position (a window's: its first row's)
             nbytes, err = 0, None
-            t0 = _perf() if _profiler._active else None
             try:
-                if window == 1:
-                    batch, nbytes = self._place(item)
-                else:
-                    leaves = [_np.stack([r[0][i] for r in item])
-                              for i in range(len(item[0][0]))]
-                    batch, nbytes = self._place_leaves(leaves, item[0][1],
-                                                       window=True)
-            except BaseException as e:  # noqa: BLE001
-                batch, err, nbytes = None, e, 0
-            if t0 is not None:
-                args = {"bytes": nbytes}
+                # flattened before the span opens: an annotation's args are
+                # fixed then, so ``bytes`` is reckoned from the leaves
+                rows = [_leaves(item)] if window == 1 else item
+                rebuild = rows[0][1]
+                args = {"seq": seq,
+                        "bytes": sum(a.nbytes for r in rows for a in r[0])}
                 if window > 1:
                     args["window"] = count
-                _profiler.record_span("io.transfer", "io", t0, args=args)
+                with _profiler.span("io.transfer", "io", args):
+                    if window == 1:
+                        leaves = rows[0][0]
+                    else:
+                        leaves = [_np.stack([r[0][i] for r in rows])
+                                  for i in range(len(rows[0][0]))]
+                    batch, nbytes = self._place_leaves(leaves, rebuild,
+                                                       window=window > 1)
+            except BaseException as e:  # noqa: BLE001
+                batch, err, nbytes = None, e, 0
             if err is None:
                 _profiler.incr("io_pipeline_bytes", nbytes)
                 with self._lock:
@@ -617,7 +654,7 @@ class _Engine:
             if not pend:
                 return True
             rows, pend[:] = pend[:], []
-            return place_and_emit(rows, len(rows))
+            return place_and_emit(rows, len(rows), rows[0][2])
 
         while True:
             with self._ready_cond:
@@ -626,10 +663,12 @@ class _Engine:
                 if self._dead(gen):
                     return
                 batch, err = self._ready.pop(next_seq)
-            next_seq += 1
+                self._next_seq = next_seq + 1
+                self._ready_cond.notify_all()  # a parked worker may publish
+            seq, next_seq = next_seq, next_seq + 1
             if err is None and batch is not _EOS:
                 if window == 1:
-                    if not place_and_emit(batch, 1):
+                    if not place_and_emit(batch, 1, seq):
                         return
                 else:
                     try:
@@ -643,7 +682,7 @@ class _Engine:
                     if pend and not _rows_compatible(pend[0][0], leaves):
                         if not flush_pend():
                             return
-                    pend.append((leaves, rebuild))
+                    pend.append((leaves, rebuild, seq))
                     if len(pend) >= window and not flush_pend():
                         return
                 _profiler.maybe_sample_memory()  # pipeline tick: keep the
@@ -658,19 +697,16 @@ class _Engine:
             if batch is _EOS:
                 return
 
-    def _place(self, batch):
+    def _place_leaves(self, leaves, rebuild, window=False):
         """Move one prepped batch's leaves host→device with the mesh data
         sharding (or plain device placement when there is no mesh)."""
-        leaves, rebuild = _leaves(batch)
-        return self._place_leaves(leaves, rebuild)
-
-    def _place_leaves(self, leaves, rebuild, window=False):
         from ..parallel.sharding import batch_pspec, _fit_spec
 
         nbytes = 0
         placed = []
         multi = jax.process_count() > 1
         for a in leaves:
+            a = _np.asarray(a)
             nbytes += a.nbytes
             if self._mesh is None:
                 placed.append(jax.device_put(a, self._device))
@@ -755,25 +791,26 @@ class _Engine:
                 if self._epoch_batches >= self._depth:
                     self._warm_stalls += 1
                 t0 = _perf()
-                while not self._buf and not self._stop.is_set():
-                    self._buf_cond.wait(timeout=0.05)
-                dt = _perf() - t0
-                self._stall_ms.append(dt * 1e3)
+                with _profiler.span("io.wait", "io",
+                                    {"seq": self._wait_seq}):
+                    while not self._buf and not self._stop.is_set():
+                        self._buf_cond.wait(timeout=0.05)
+                waited = _perf() - t0
+                self._stall_ms.append(waited * 1e3)
                 if len(self._stall_ms) > self._stall_cap:
                     del self._stall_ms[:len(self._stall_ms) - self._stall_cap]
                 if self._stop.is_set() and not self._buf:
                     raise RuntimeError("pipeline closed while waiting")
-                stalled_t0 = t0
             else:
-                stalled_t0 = None
+                waited = None
             batch, err, nbytes, count = self._buf.pop(0)
+            self._wait_seq += count
             self._buf_cond.notify_all()
         if nbytes:
             self._mem.free(nbytes)   # the consumer owns the batch now
-        if stalled_t0 is not None:
+        if waited is not None:
             _profiler.incr("io_pipeline_stalls")
-            if _profiler._active:
-                _profiler.record_span("io.wait", "io", stalled_t0)
+            _profiler.incr("io_pipeline_wait_us", int(waited * 1e6))
         if err is not None:
             raise err
         if batch is _EOS:

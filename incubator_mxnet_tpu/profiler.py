@@ -219,6 +219,7 @@ _counters = {
     "io_prefetch_batches": 0,         # batches produced by prefetch workers
     "io_pipeline_batches": 0,         # device-resident batches DataPipeline delivered
     "io_pipeline_stalls": 0,          # consumer arrivals that found the buffer empty
+    "io_pipeline_wait_us": 0,         # whole microseconds those arrivals then waited
     "io_pipeline_depth_change": 0,    # autotuner depth raises + lowers
     "io_pipeline_bytes": 0,           # host->device bytes the transfer thread moved
     "ps_retry": 0,                    # async-PS client request retries

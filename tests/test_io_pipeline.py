@@ -384,6 +384,194 @@ class TestObservability:
             pipe.close()
         assert "io_test_pipe" not in profiler.metrics_snapshot()["providers"]
 
+    def test_spans_reach_a_jax_trace_with_the_recorder_off(self, clean_profiler):
+        """Every stage is a ``profiler.span``: under a bare
+        ``jax.profiler.start_trace`` the host plane of the ``.xplane.pb``
+        holds ``io.read``, ``io.prep``, ``io.transfer`` and (slow source)
+        ``io.wait``, the spans of one batch sharing a ``seq``, while the
+        ring recorder is OFF (``record_span`` wrote nothing then)."""
+        from common import host_spans
+
+        assert not profiler.recording_enabled()
+        ring_rows = profiler.recorder_stats()["spans"]
+        n = 5
+
+        def slow_source():
+            for i in range(n):
+                time.sleep(0.03)  # the consumer arrives first: it waits
+                yield np.full((8, 4), i, np.float32)
+
+        trace_dir = clean_profiler / "xplane"
+        jax.profiler.start_trace(str(trace_dir))
+        try:
+            with DataPipeline(slow_source, mesh=make_mesh(), autotune=False,
+                              prep_fn=lambda b: b + 1.0) as pipe:
+                got = [np.asarray(a) for a in pipe]
+        finally:
+            jax.profiler.stop_trace()
+        assert len(got) == n
+        assert profiler.recorder_stats()["spans"] == ring_rows  # the ring stayed off
+        spans = host_spans(trace_dir, "io.")
+        by_name = {}
+        for line, s, e, name, stats in spans:
+            by_name.setdefault(name, []).append((line, s, e, stats))
+        assert {"io.read", "io.prep", "io.transfer", "io.wait"} <= set(by_name)
+        seq_of = lambda name: sorted(int(st["seq"]) for _, _, _, st in by_name[name])
+        # one read a batch and the one that found the source exhausted,
+        # which carries the end marker's seq
+        assert seq_of("io.read") == list(range(n + 1))
+        assert seq_of("io.prep") == list(range(n))
+        assert seq_of("io.transfer") == list(range(n))
+        assert set(seq_of("io.wait")) <= set(range(n + 1)) and seq_of("io.wait")
+        for _, _, _, st in by_name["io.transfer"]:
+            assert int(st["bytes"]) == 8 * 4 * 4
+        # a batch is read before it is staged, each stage on a thread of its own
+        reads = {int(st["seq"]): (line, e) for line, _, e, st in by_name["io.read"]}
+        for line, s, _, st in by_name["io.transfer"]:
+            read_line, read_end = reads[int(st["seq"])]
+            assert read_line != line and read_end <= s
+        assert len({line for line, *_ in by_name["io.read"]}) == 1
+        assert len({line for line, *_ in by_name["io.transfer"]}) == 1
+
+    def test_strided_and_skipped_reads_carry_no_seq(self, clean_profiler):
+        """A read the stride drops or the resume cursor skips delivers
+        nothing: its ``io.read`` carries ``seq=-1``, and the survivors are
+        numbered by delivery position."""
+        from common import host_spans
+
+        src = [np.full((2, 2), i, np.float32) for i in range(8)]
+        trace_dir = clean_profiler / "xplane"
+        jax.profiler.start_trace(str(trace_dir))
+        try:
+            pipe = DataPipeline(src, mesh=make_mesh(), num_parts=2,
+                                part_index=1, autostart=False)
+            pipe.load_state_dict({"kind": "DataPipeline", "epoch": 0,
+                                  "delivered": 1})
+            with pipe:
+                got = [float(np.asarray(a)[0, 0]) for a in pipe]
+        finally:
+            jax.profiler.stop_trace()
+        assert got == [3.0, 5.0, 7.0]  # part 1 of 2, its first batch skipped
+        reads = sorted((s, int(st["seq"])) for _, s, _, name, st
+                       in host_spans(trace_dir, "io.read"))
+        assert [seq for _, seq in reads] == [-1, -1, -1, 0, -1, 1, -1, 2, -1]
+
+    @pytest.mark.parametrize("slow", [True, False], ids=["slow-source", "fast-source"])
+    def test_wait_counter_is_the_time_the_consumer_waited(self, clean_profiler, slow):
+        """``io_pipeline_wait_us`` grows by at least the slept time behind a
+        slow source and by nothing behind one that runs ahead."""
+        n, nap = 4, 0.05
+
+        def source():
+            for i in range(n):
+                if slow:
+                    time.sleep(nap)
+                yield np.full((2, 2), i, np.float32)
+
+        before = profiler.counters()
+        with DataPipeline(source, mesh=make_mesh(), autotune=False,
+                          depth=n + 1) as pipe:
+            it = iter(pipe)
+            if not slow:  # let the whole epoch reach the buffer first
+                _wait_until(lambda: pipe.stats()["buffer_occupancy"] == n + 1,
+                            msg="the buffer to fill")
+            got = [next(it) for _ in range(n)]
+        assert len(got) == n
+        after = profiler.counters()
+        waited = after["io_pipeline_wait_us"] - before["io_pipeline_wait_us"]
+        stalls = after["io_pipeline_stalls"] - before["io_pipeline_stalls"]
+        if slow:
+            assert stalls >= 1 and waited >= (n * nap - 0.01) * 1e6 * 0.9
+            assert waited == pytest.approx(
+                sum(pipe._eng._stall_ms) * 1e3, abs=len(pipe._eng._stall_ms))
+        else:
+            assert stalls == 0 and waited == 0
+
+    def test_wait_still_bills_the_step_and_the_goodput_ledger(self, clean_profiler):
+        """The ring's billing of ``io.wait`` reads as it did under
+        ``record_span``: a ring row, the step's host bucket and the run's
+        ``data_wait``, from the step-owning thread."""
+        profiler.start()
+        profiler.reset_goodput()
+        profiler.step_boundary()  # pin this thread as the step's
+
+        def source():
+            for i in range(3):
+                time.sleep(0.04)
+                yield np.full((2, 2), i, np.float32)
+
+        with DataPipeline(source, mesh=make_mesh(), autotune=False) as pipe:
+            list(pipe)
+        waited = profiler.counters()["io_pipeline_wait_us"] / 1e6
+        assert waited >= 0.1
+        assert profiler.goodput_snapshot()["buckets_s"]["data_wait"] == \
+            pytest.approx(waited, abs=0.01)
+        rows = [e for e in profiler._trace_events()
+                if e.get("ph") == "B" and e.get("name") == "io.wait"]
+        assert rows and all("seq" in e.get("args", {}) for e in rows)
+        names = {e.get("name") for e in profiler._trace_events() if e.get("ph") == "B"}
+        assert {"io.read", "io.transfer"} <= names
+        profiler.stop()
+
+    def test_read_ahead_is_bounded_on_an_endless_source(self):
+        """An endless source (a generator that repeats its loader) is held
+        back by the consumer: no stage buffers without bound."""
+        made = []
+
+        def endless():
+            i = 0
+            while True:
+                made.append(i)
+                yield np.full((2, 2), i, np.float32)
+                i += 1
+
+        with DataPipeline(endless, mesh=make_mesh(), autotune=False) as pipe:
+            it = iter(pipe)
+            first = [float(np.asarray(next(it))[0, 0]) for _ in range(3)]
+            time.sleep(0.3)  # unbounded, the reader makes thousands in this time
+            eng = pipe._eng
+            # prep queue + seq table (2 x workers each) + a batch in each
+            # stage's hands + the device buffer
+            bound = 4 * eng._num_workers + eng._num_workers + 2 + eng.depth
+            assert len(made) - 3 <= bound, len(made)
+            assert len(eng._ready) <= 2 * eng._num_workers
+            nxt = [float(np.asarray(next(it))[0, 0]) for _ in range(20)]
+        assert first + nxt == [float(i) for i in range(23)]
+
+    def test_bounded_table_keeps_order_under_many_workers(self):
+        """Stress for the bound the workers share with the transfer stage:
+        more workers than cores, a short switch interval, preps that finish
+        out of order — delivery stays in source order, the seq table never
+        passes its bound, and nothing deadlocks."""
+        import os
+        import random
+        import sys
+
+        workers = min(32, 2 * (os.cpu_count() or 4))
+        n = 300
+        rng = random.Random(7)
+        naps = [rng.random() * 1e-3 for _ in range(n)]
+
+        def prep(b):
+            time.sleep(naps[int(b[0, 0])])
+            return b
+
+        src = [np.full((2, 2), i, np.float32) for i in range(n)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            deadline = time.perf_counter() + 60.0
+            with DataPipeline(src, mesh=make_mesh(), prep_fn=prep,
+                              num_workers=workers, autotune=False) as pipe:
+                got, most = [], 0
+                for a in pipe:
+                    got.append(float(np.asarray(a)[0, 0]))
+                    most = max(most, len(pipe._eng._ready))
+                    assert time.perf_counter() < deadline, "pipeline stuck"
+        finally:
+            sys.setswitchinterval(interval)
+        assert got == [float(i) for i in range(n)]
+        assert most <= 2 * workers, most
 
 class TestLifecycle:
     def test_close_drains_and_joins_all_threads(self):
