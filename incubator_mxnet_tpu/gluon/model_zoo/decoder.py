@@ -2,18 +2,31 @@
 :class:`RMSNorm`, the two feed-forward forms (:class:`SwiGLU`,
 :class:`Relu2FFN`), :class:`SparseExperts` (the dropless routed experts of
 which a chip holds its share, with the ``noaux_tc`` selection bias),
-:func:`run_layer` (one layer under ``jax.checkpoint``, its routing statistics
-handed to the trainer's MoE frame and to the bias rule) and
+:func:`run_layer` (one layer under ``jax.checkpoint``, which keeps the values
+named in :data:`KEPT`; its routing statistics handed to the trainer's MoE
+frame and to the bias rule) and
 :class:`CausalLM` (a model and its untied output head).
 """
 from __future__ import annotations
 
+from ... import profiler
+from ...ops import attention as _att
+from ...ops import sparse_attention as _sparse
 from ..block import HybridBlock, collect_aux_update
 from ..nn import Dense
 from . import moe as _moe
 
 __all__ = ["RMSNorm", "SwiGLU", "Relu2FFN", "SparseExperts", "run_layer",
-           "CausalLM"]
+           "KEPT", "CausalLM"]
+
+# The names (``jax.ad_checkpoint.checkpoint_name``) of what a layer's
+# checkpoint keeps beside the layer's input: arrays that a kernel wrote, that
+# are small next to the time it takes to write them again, and that the
+# backward pass reads as they are: the attention core's output and its
+# log-sum-exp (the blockwise kernels' forward rules set them), and the int8
+# selection of an indexer (``select_topk``).  A name no layer sets costs
+# nothing.
+KEPT = (_att.KEEP_OUT, _att.KEEP_LSE, _sparse.KEEP_SELECT)
 
 
 def _scope(name):
@@ -177,6 +190,24 @@ class SparseExperts(HybridBlock):
         return bias + self._bias_speed * jnp.sign(load_all.mean() - load_all)
 
 
+def _keeping_policy():
+    """The layer checkpoint's policy: save the values named in :data:`KEPT`
+    and nothing else.  Asked once an operation when a checkpoint is
+    differentiated, so the bytes it says yes to are what the step's layers
+    keep: the counter ``remat_kept_bytes``."""
+    import jax
+
+    named = jax.checkpoint_policies.save_only_these_names(*KEPT)
+
+    def keeps(prim, *avals, **params):
+        kept = named(prim, *avals, **params)
+        if kept:
+            profiler.incr("remat_kept_bytes", sum(a.size * a.dtype.itemsize for a in avals))
+        return kept
+
+    return keeps
+
+
 def run_layer(body, x, remat, experts=None):
     """One decoder layer: ``body(x) → (out, stats)`` or ``(out, stats,
     side)``, ``stats`` the routing statistics of ``experts`` (a
@@ -184,7 +215,11 @@ def run_layer(body, x, remat, experts=None):
     beside its output: ``{"loss": a weighted scalar, "counters": {a profiler
     counter's name: a scalar}}`` (``model_zoo.moe.register_side``).  Under a
     jit trace with ``remat`` the body runs inside ``jax.checkpoint``: the
-    layer keeps only its input and the backward pass runs its forward again.
+    layer keeps its input and the values named in :data:`KEPT`, and the
+    backward pass runs the rest of its forward again (a kernel all of whose
+    results were kept does not run: nothing reads it).  A layer of your own
+    keeps a value the same way, ``checkpoint_name(value, one of KEPT)``:
+    docs/observability.md says what it costs.
     Statistics and side are registered OUTSIDE the checkpoint (what the
     trainer's MoE frame and the aux collector keep must belong to the step's
     own trace), and in training the ``noaux_tc`` rule moves the selection
@@ -202,7 +237,8 @@ def run_layer(body, x, remat, experts=None):
     traced = (isinstance(x._data, jax.core.Tracer)
               and not autograd.is_recording())
     if remat and traced:
-        out, stats, *side = jax.checkpoint(lambda data: raw(body(NDArray(data))))(x._data)
+        out, stats, *side = jax.checkpoint(
+            lambda data: raw(body(NDArray(data))), policy=_keeping_policy())(x._data)
         out = NDArray(out)
     else:
         out, stats, *side = body(x)

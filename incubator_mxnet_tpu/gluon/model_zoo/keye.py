@@ -24,8 +24,10 @@ and two counters (``sparse_attn_tiles_live`` / ``_causal``), through
 ``run_layer`` and the trainer's MoE frame.
 
 ``remat=True`` wraps every layer in ``jax.checkpoint`` under a jit trace
-(``SPMDTrainer``): a layer keeps only its input ``[B, S, d]`` and the
-backward pass runs its forward again.
+(``SPMDTrainer``): a layer keeps its input ``[B, S, d]``, the attention
+core's output and log-sum-exp and the int8 selection (``decoder.KEPT``), and
+the backward pass runs the rest of its forward again: index scores,
+projections, experts, not the core's kernel and not the bisection.
 
 Not built: the vision tower (its widths are not in the language model's
 config).  The model takes text; ``positions`` [3, B, S] are there for the

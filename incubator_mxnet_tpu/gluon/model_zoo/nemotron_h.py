@@ -22,8 +22,9 @@ Built from the published ``config.json`` keys (``model_type``
   count)``), plus the shared expert.
 
 ``remat=True`` wraps every layer in ``jax.checkpoint`` under a jit trace
-(``SPMDTrainer``): a layer keeps only its input ``[B, S, d]`` and the
-backward pass runs its forward again.
+(``SPMDTrainer``): a layer keeps its input ``[B, S, d]`` and, an attention
+layer, the core's output and log-sum-exp (``decoder.KEPT``), and the backward
+pass runs the rest of its forward again.
 """
 from __future__ import annotations
 

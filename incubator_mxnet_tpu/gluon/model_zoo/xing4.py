@@ -21,8 +21,9 @@ exactly as ``BERTForPretrain`` is.  What this file adds to the program:
 * :class:`Xing4Block` / :class:`Xing4Model` / :class:`Xing4ForCausalLM`.
 
 ``remat=True`` wraps every block in ``jax.checkpoint`` under a jit trace
-(``SPMDTrainer``): a block keeps only its input state, ``[n, B, S, d]``, and
-the backward pass runs its forward again.
+(``SPMDTrainer``): a block keeps its input state, ``[n, B, S, d]``, and the
+attention core's output and log-sum-exp (``decoder.KEPT``), and the backward
+pass runs the rest of its forward again.
 
 Not built: the multi-token-prediction module (``num_nextn_predict_layers``
 must be 0).  How its input is formed from several residual streams is not

@@ -38,7 +38,14 @@ belong to it:
 
 Gradients: ``jax.custom_vjp`` on every path — the backward recomputes
 attention probabilities from the saved (q, k, v) (the kernels: and the
-log-sum-exp), so no S×S residual is stored *between* fwd and bwd.
+output and the log-sum-exp, one float32 a query row), so no S×S residual is
+stored *between* fwd and bwd.  The blockwise path's forward rules name the two
+arrays their kernel wrote (``jax.ad_checkpoint.checkpoint_name``:
+:data:`KEEP_OUT`, :data:`KEEP_LSE`).  Outside a ``jax.checkpoint`` a name is
+the identity; under one whose policy saves these names
+(``gluon.model_zoo.decoder.run_layer``) the two arrays are kept from the
+forward pass, every output of the kernel is then known to the recomputed
+forward, and the kernel does not run a second time.
 
 On a mesh: no compiler partitions a Mosaic kernel, so where the trace is
 for several devices (``parallel.mesh_scope``, which ``SPMDTrainer`` opens
@@ -77,6 +84,13 @@ _BWD_PARAMS = _pltpu.CompilerParams(
 # TPU lane width: row statistics (lse) are replicated across a 128-lane
 # trailing dim so their blocks satisfy Mosaic's (8, 128) tiling rule.
 _LANE = 128
+
+# What the blockwise forward rules call the arrays their kernel wrote
+# (``checkpoint_name``): the output [B, H, S, Dv] and one lane of the
+# log-sum-exp, float32 [B·H, S].  A checkpoint whose policy saves these names
+# keeps them, and its recomputed forward has no kernel left to run.
+KEEP_OUT = "attn.core.out"
+KEEP_LSE = "attn.core.lse"
 
 # dot_general dimension numbers of the kernels' 2-D products
 _NT = (((1,), (1,)), ((), ()))   # a · bᵀ
@@ -422,21 +436,22 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
                       block_q=128, block_k=128, select=None):
     """q: [BH, S, D]; k: [BHkv, Sk, D]; v: [BHkv, Sk, Dv]; do/o: [BH, S,
-    Dv]; lse: [BH, Sq, _LANE] fp32 → (dq, dk, dv).  With grouped-query heads
-    each query head's cell writes ITS dk and dv, in float32, and the group's
-    are summed after the kernel (one small XLA reduction a call).  ``select``
-    [B, Sq, Sk] int8 is the forward's selection; the kernel reads its
-    transpose (one XLA transpose of an int8 array a call)."""
+    Dv]; lse: [BH, Sq] fp32, a value a query row → (dq, dk, dv).  With
+    grouped-query heads each query head's cell writes ITS dk and dv, in
+    float32, and the group's are summed after the kernel (one small XLA
+    reduction a call).  ``select`` [B, Sq, Sk] int8 is the forward's
+    selection; the kernel reads its transpose (one XLA transpose of an int8
+    array a call)."""
     bh, sq, d = q.shape
     bkv, sk, d_v = k.shape[0], k.shape[1], v.shape[2]
     kv_row, group = _kv_row(bh, bkv), bh // bkv
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     nq = sq // block_q
-    # the query rows' statistics as rows: the forward's lane-replicated
-    # log-sum-exp, and rowsum(dO ⊙ O) once a row where the kernels used to
-    # recompute it (and read o whole) for every key block
-    lse = lse[:, :, 0].reshape(bh, nq, block_q)
+    # the query rows' statistics as rows: the forward's log-sum-exp, and
+    # rowsum(dO ⊙ O) once a row where the kernels used to recompute it (and
+    # read o whole) for every key block
+    lse = lse.reshape(bh, nq, block_q)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(bh, nq, block_q)
 
@@ -838,8 +853,9 @@ def _repeat_heads(k, v, group, head_axis):
 
 def _blockwise_forward(q, k, v, select, causal, scale, launch, with_lse):
     """The forward kernel on [B, H, S, D] operands (``select`` [B, S, Sk] int8
-    or None): the output, and with ``with_lse`` the kernel's lane-replicated
-    log-sum-exp [B·H, S, 128] beside it."""
+    or None): the output, and with ``with_lse`` each query row's log-sum-exp
+    beside it, float32 [B·H, S] (one lane of what the kernel writes
+    lane-replicated)."""
     b, h, s, d = q.shape
     h_kv, sk, d_v = k.shape[1], k.shape[2], v.shape[-1]
     arrays = (q.reshape(b * h, s, d), k.reshape(b * h_kv, sk, d),
@@ -849,8 +865,21 @@ def _blockwise_forward(q, k, v, select, causal, scale, launch, with_lse):
             q, k, v, causal, scale, launch.interpret, *launch.blocks,
             with_lse=with_lse, select=select), *arrays)
     if with_lse:
-        return got[0].reshape(b, h, s, d_v), got[1]
+        return got[0].reshape(b, h, s, d_v), got[1][:, :, 0]
     return got.reshape(b, h, s, d_v)
+
+
+def _blockwise_forward_named(q, k, v, select, causal, scale, launch):
+    """What both forward rules share: the kernel's output and log-sum-exp
+    under their names, and the backward's residuals.  The rules hand the
+    NAMED arrays on as primal outputs too: a primal output taken from the
+    kernel's own result would keep the kernel alive in a recomputed forward
+    whose checkpoint saved the names."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
+    out, lse = checkpoint_name(out, KEEP_OUT), checkpoint_name(lse, KEEP_LSE)
+    return out, lse, (q, k, v, out, lse, select)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -867,8 +896,8 @@ def _flash_kernels(q, k, v, causal, scale, launch, select=None):
 def _flash_kernels_fwd(q, k, v, causal, scale, launch, select=None):
     """VJP forward: also save (o, lse) so the backward runs blockwise
     without ever materializing S×S."""
-    out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
-    return out, (q, k, v, out, lse, select)
+    out, _, res = _blockwise_forward_named(q, k, v, select, causal, scale, launch)
+    return out, res
 
 
 def _flash_kernels_bwd(causal, scale, launch, res, do):
@@ -898,12 +927,12 @@ def _flash_kernels_lse(q, k, v, causal, scale, launch, select=None):
     the backward anyway): ``exp(score - lse)`` is the probability.  The
     log-sum-exp is a by-product and carries no gradient."""
     out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
-    return out, lse[:, :, 0].reshape(q.shape[:3])
+    return out, lse.reshape(q.shape[:3])
 
 
 def _flash_kernels_lse_fwd(q, k, v, causal, scale, launch, select=None):
-    out, lse = _blockwise_forward(q, k, v, select, causal, scale, launch, True)
-    return (out, lse[:, :, 0].reshape(q.shape[:3])), (q, k, v, out, lse, select)
+    out, lse, res = _blockwise_forward_named(q, k, v, select, causal, scale, launch)
+    return (out, lse.reshape(q.shape[:3])), res
 
 
 def _flash_kernels_lse_bwd(causal, scale, launch, res, cts):

@@ -65,6 +65,12 @@ __all__ = ["index_scores", "select_topk", "head_mean_probs", "indexer_kl_loss",
 _F32 = jnp.float32
 _HIGHEST = lax.Precision.HIGHEST
 
+# What :func:`select_topk` calls the selection it found by bisection
+# (``checkpoint_name``), for a checkpoint whose policy saves the name
+KEEP_SELECT = "attn.select"
+# ... while a query's row of it is no longer than this many times ``topk``
+_KEEP_SELECT_MAX_ROW = 4
+
 
 def _pad_rows(x, axis, multiple):
     pad = -x.shape[axis] % multiple
@@ -481,7 +487,21 @@ def select_topk(scores, topk, q_chunk=512):
     the set ``jax.lax.top_k`` gives (equal scores: the lower index).  No
     sort: the ``topk``-th largest score of a row is found bit by bit, 32
     passes that each count the keys at or above a threshold, over ``q_chunk``
-    rows at a time.  No gradient passes through a selection."""
+    rows at a time.  No gradient passes through a selection.
+
+    While ``S ≤ 4 · topk`` the result carries the name :data:`KEEP_SELECT`
+    (``jax.ad_checkpoint.checkpoint_name``; the identity outside a
+    ``jax.checkpoint``): a layer checkpoint whose policy saves the name
+    (``gluon.model_zoo.decoder.run_layer``) keeps the selection and its
+    backward pass does not bisect again.  The rule weighs bytes: a query's
+    row of the selection is ``S`` bytes, and at ``4 · topk`` (8,192 for the
+    published 2,048) that is what its row of the attention's output takes,
+    which the same checkpoint keeps.  Past it the bytes grow with ``S²``, the
+    mask is mostly zeros and the thing to keep would be a compact form (a
+    row's threshold and its tie count), which is not built: the selection is
+    found again."""
+    from jax.ad_checkpoint import checkpoint_name
+
     b, s = scores.shape[:2]
     topk = int(topk)
     if topk >= s:          # every query selects all it sees
@@ -493,7 +513,10 @@ def select_topk(scores, topk, q_chunk=512):
         return i + 1, _select_rows(chunk, i * cq, topk).astype(jnp.int8)
 
     _, out = lax.scan(rows, 0, _chunks(padded, 1, cq))
-    return jnp.moveaxis(out, 0, 1).reshape(b, -1, s)[:, :s]
+    select = jnp.moveaxis(out, 0, 1).reshape(b, -1, s)[:, :s]
+    if s <= _KEEP_SELECT_MAX_ROW * topk:
+        select = checkpoint_name(select, KEEP_SELECT)
+    return select
 
 
 def live_tiles(select, q_chunk=512, kv_chunk=512):

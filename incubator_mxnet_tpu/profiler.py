@@ -264,6 +264,7 @@ _counters = {
     "sparse_attn_tiles_causal": 0,    # ... and those at or below the diagonal
     "index_scores_dispatch_pallas": 0,  # index_scores call sites traced onto its Pallas kernels
     "index_scores_dispatch_xla": 0,   # index_scores call sites traced onto the XLA tiles
+    "remat_kept_bytes": 0,            # bytes of named values the layer checkpoints of a traced step keep
     "moe_grouped_dispatch_pallas": 0,  # moe_ffn_dropless call sites traced onto the Pallas grouped-product kernels
     "moe_grouped_dispatch_xla": 0,    # moe_ffn_dropless call sites traced onto jax.lax.ragged_dot
     "elastic_restart": 0,             # supervisor job re-formations

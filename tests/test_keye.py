@@ -849,6 +849,13 @@ def test_spmd_trainer_step_lowers_the_loss_and_compiles_once(monkeypatch, flash)
     assert taken == before["sparse_attention_traced"] - counted["sparse_attention_traced"]
     assert other == 0
     assert after["sparse_attention_traced"] == before["sparse_attention_traced"]   # no retrace
+    # what the two layers' checkpoints keep by name (B 2, S 32): the int8
+    # selection, and on the kernels' path the core's bf16 output (8 heads of
+    # 16) and its float32 log-sum-exp
+    selection, core = 2 * 32 * 32, 2 * 8 * 32 * (16 * 2 + 4)
+    assert (before["remat_kept_bytes"] - counted["remat_kept_bytes"]
+            == 2 * (selection + (core if flash == "interpret" else 0)))
+    assert after["remat_kept_bytes"] == before["remat_kept_bytes"]
     for scope in ("keye.attn/", "keye.attn.proj", "keye.attn.index/", "keye.attn.select",
                   "keye.attn.core", "keye.attn.index_loss", "keye.attn.out",
                   "keye.moe.route", "keye.moe.experts", "keye.head"):
