@@ -258,6 +258,8 @@ _counters = {
     "attention_dispatch_xla": 0,      # attention call sites traced onto the XLA path
     "attention_dispatch_grouped": 0,  # of either: call sites with fewer key/value heads than query heads
     "ssm_scan_traced": 0,             # chunked state-space scan call sites traced into a program
+    "ssm_scan_dispatch_pallas": 0,    # ... of them traced onto its Pallas kernels
+    "ssm_scan_dispatch_xla": 0,       # ... of them traced onto the XLA chunks
     "kda_traced": 0,                  # chunked gated delta rule (KDA) call sites traced into a program
     "attention_dispatch_masked": 0,   # of the Pallas ones: call sites given a selection of keys a query
     "sparse_attention_traced": 0,     # indexer-selected attention call sites traced into a program

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 from incubator_mxnet_tpu.ops import attention as attn_ops
 from incubator_mxnet_tpu.ops import moe as moe_ops
 from incubator_mxnet_tpu.ops import sparse_attention as sa_ops
+from incubator_mxnet_tpu.ops import ssm as ssm_ops
 
 
 @pytest.fixture(scope="module")
@@ -145,3 +146,33 @@ def test_the_grouped_query_kernels_compile_at_the_solar_cells_shapes(one_chip, n
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
         spec((1, 8, s, 128)), spec((1, 1, s, 128)), spec((1, 1, s, 128))).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_the_scan_kernels_compile_at_the_nemotron_cells_shape(one_chip, no_compile_cache):
+    """The chunked scan's forward and backward kernels at the nemotron cell's
+    shape: x̃ [1, 8192, 64, 64] bf16 as [1, 8192, 4096], 8 groups of state
+    128, chunk 128 (the grid cell's [128, 512] x̃ columns sliced a 64-lane head
+    at a time, D along the lanes, the running sums turned into columns, the
+    entering states written as the forward rule's residual: what an
+    interpreter does not check).  The forward alone is one Mosaic call, the
+    gradient of a scalar that needs y two, and on this CPU the predicate
+    keeps the XLA chunks."""
+    b, s, h, p, g, n, q = 1, 8192, 64, 64, 8, 128, 128
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x, bc = spec((b, s, h * p), jnp.bfloat16), spec((b, s, g * n), jnp.bfloat16)
+    dl, d = spec((b, s // q, h, q), jnp.float32), spec((b, h, 128), jnp.float32)
+    launch = attn_ops._Launch(False)
+    assert ssm_ops._scan_path(jax.ShapeDtypeStruct((b, s, h, p), jnp.bfloat16),
+                              jax.ShapeDtypeStruct((b, s, g, n), jnp.bfloat16), q) is None
+
+    forward = jax.jit(lambda *a: ssm_ops._scan_kernels(*a, g, launch)).lower(
+        x, dl, dl, bc, bc, d).compile()
+    assert forward.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+    def loss(*a):
+        return ssm_ops._scan_kernels(*a, g, launch).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        x, dl, dl, bc, bc, d).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
