@@ -23,6 +23,10 @@ TARGET_OPS = {
     # MXU in the target dtype; index scores, the selection's comparisons,
     # soft-maxes and the indexer's loss are float32 inside it whatever arrives
     "sparse_attention",
+    # LFM2's gated short convolution (ops/ssm.py): it takes the in-projection's
+    # output and feeds the out-projection, both in the target dtype; its gates
+    # and its three-tap sum are float32 inside whatever arrives
+    "gated_short_conv",
     "RNN",
     # Embedding output feeds the transformer residual stream; emitting it
     # in the target dtype keeps that stream bf16 end-to-end (the norms

@@ -1,15 +1,16 @@
 """What the decoder models of the zoo share (``xing4``, ``nemotron_h``,
-``keye``, ``solar_open2``):
+``keye``, ``solar_open2``, ``lfm2_moe``):
 :class:`RMSNorm`, the two feed-forward forms (:class:`SwiGLU`,
 :class:`Relu2FFN`), :class:`GroupedQueryAttention` (with an optional output
-gate, for the query heads a chip holds), :class:`SparseExperts` (the dropless
+gate, per-head q/k norms and rotary positions, for the query heads a chip
+holds), :class:`SparseExperts` (the dropless
 routed experts of which a chip holds its share, with the ``noaux_tc``
 selection bias), the state-space initialisers (``_LogUniform``,
 ``_InverseSoftplusStep``),
 :func:`run_layer` (one layer under ``jax.checkpoint``, which keeps the values
 named in :data:`KEPT`; its routing statistics handed to the trainer's MoE
 frame and to the bias rule) and
-:class:`CausalLM` (a model and its untied output head).
+:class:`CausalLM` (a model and its output head, untied or tied).
 """
 from __future__ import annotations
 
@@ -124,10 +125,10 @@ class _InverseSoftplusStep(_OwnInit):
 
 class GroupedQueryAttention(HybridBlock):
     """Causal self-attention with ``num_heads`` query heads on ``kv_heads``
-    key/value heads of ``head_dim``, scale ``head_dim^-½``, no bias, no
-    rotary embedding: ``[B, S, d] → [B, S, d]``.  Query, key and value
-    projections are one weight (q rows, then k, then v).  ``scope`` names
-    the ``jax.named_scope`` of the layer and ``<scope>.core`` of its core.
+    key/value heads of ``head_dim``, scale ``head_dim^-½``, no bias:
+    ``[B, S, d] → [B, S, d]``.  Query, key and value projections are one
+    weight (q rows, then k, then v).  ``scope`` names the
+    ``jax.named_scope`` of the layer and ``<scope>.core`` of its core.
 
     ``heads_held = (first, count)``: this chip holds query heads ``first ..
     first + count − 1`` of ``num_heads`` (default: all) and the key/value
@@ -135,10 +136,18 @@ class GroupedQueryAttention(HybridBlock):
     with the rows of ``W_o`` that read them; the output is those heads' part
     of the sum, as tensor parallelism by heads leaves it before its
     all-reduce.  ``gate`` adds an elementwise output gate, ``attn ⊙ σ(x
-    W_z)`` before ``W_o`` (``gate_weight`` [count · head_dim, d])."""
+    W_z)`` before ``W_o`` (``gate_weight`` [count · head_dim, d]).
+
+    ``qk_norm`` (an RMSNorm ε, or None) normalises each head of q and of k
+    over its ``head_dim`` channels with one gain an element
+    (``q_norm_gamma``, ``k_norm_gamma`` [head_dim]); ``rope_theta`` (or
+    None) then turns q and k by rotary positions in the rotate-half form
+    over every dim.  Both run outside ``<scope>.core``; with both off the
+    layer has neither parameter nor operation of them."""
 
     def __init__(self, units, num_heads, kv_heads, head_dim, scope="attn",
-                 gate=False, heads_held=None, prefix=None, params=None):
+                 gate=False, heads_held=None, qk_norm=None, rope_theta=None,
+                 prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         heads, kv_heads = int(num_heads), int(kv_heads)
         if heads % kv_heads:
@@ -155,14 +164,33 @@ class GroupedQueryAttention(HybridBlock):
         self._q, self._kv = self._heads * int(head_dim), self._kv_heads * int(head_dim)
         self._scale = float(head_dim) ** -0.5
         self._jax_scope = str(scope)
+        self._qk_norm = None if qk_norm is None else float(qk_norm)
+        self._rope_theta = None if rope_theta is None else float(rope_theta)
         with self.name_scope():
             self.qkv_weight = self.params.get(
                 "qkv_weight", shape=(self._q + 2 * self._kv, units))
             self.gate_weight = (self.params.get("gate_weight", shape=(self._q, units))
                                 if gate else None)
             self.o_weight = self.params.get("o_weight", shape=(units, self._q))
+            if self._qk_norm is not None:
+                self.q_norm_gamma = self.params.get(
+                    "q_norm_gamma", shape=(int(head_dim),), init="ones")
+                self.k_norm_gamma = self.params.get(
+                    "k_norm_gamma", shape=(int(head_dim),), init="ones")
 
-    def hybrid_forward(self, F, x, qkv_weight, o_weight, gate_weight=None):
+    def _heads_turned(self, F, a, heads, gamma):
+        """``a`` [B, S, heads · head_dim] normed per head (with ``gamma``)
+        and turned by rotary positions, as the options ask."""
+        b, s, width = a.shape
+        a = a.reshape((b, s, heads, width // heads))
+        if gamma is not None:
+            a = F.RMSNorm(a, gamma, eps=self._qk_norm)
+        if self._rope_theta is not None:
+            a = F.rotary_embedding(a, theta=self._rope_theta)
+        return a.reshape((b, s, width))
+
+    def hybrid_forward(self, F, x, qkv_weight, o_weight, gate_weight=None,
+                       q_norm_gamma=None, k_norm_gamma=None):
         with _scope(self._jax_scope):
             qkv = F.FullyConnected(x, qkv_weight, no_bias=True, flatten=False,
                                    num_hidden=self._q + 2 * self._kv)
@@ -170,6 +198,9 @@ class GroupedQueryAttention(HybridBlock):
             k = F.slice_axis(qkv, axis=-1, begin=self._q, end=self._q + self._kv)
             v = F.slice_axis(qkv, axis=-1, begin=self._q + self._kv,
                              end=self._q + 2 * self._kv)
+            if self._qk_norm is not None or self._rope_theta is not None:
+                q = self._heads_turned(F, q, self._heads, q_norm_gamma)
+                k = self._heads_turned(F, k, self._kv_heads, k_norm_gamma)
             with _scope(self._jax_scope + ".core"):
                 out = F.contrib.fused_attention(
                     q, k, v, num_heads=self._heads, kv_heads=self._kv_heads,
@@ -365,19 +396,28 @@ def run_layer(body, x, remat, experts=None):
 class CausalLM(HybridBlock):
     """A decoder model (token ids ``[B, S]`` → hidden states ``[B, S, d]``;
     ``build_model(prefix)`` makes it, inside this block's name scope) and the
-    untied output head: logits ``[B, S, vocab]``, traced under ``head_scope``
-    (``vocab_size`` may be this chip's slice)."""
+    output head: logits ``[B, S, vocab]``, traced under ``head_scope``
+    (``vocab_size`` may be this chip's slice).  The head is a weight of its
+    own, or with ``tie_embedding`` the model's embedding (``model.embed``):
+    the rows that embed the ids predict them."""
 
     def __init__(self, build_model, vocab_size, hidden_size, head_scope,
-                 prefix=None, params=None):
+                 tie_embedding=False, prefix=None, params=None):
         super().__init__(prefix=prefix, params=params)
         self._head_scope = head_scope
+        self._vocab = int(vocab_size)
         with self.name_scope():
             self.model = build_model("model_")
-            self.lm_head = Dense(vocab_size, use_bias=False, flatten=False,
-                                 in_units=hidden_size, prefix="lm_head_")
+            self.lm_head = None if tie_embedding else Dense(
+                vocab_size, use_bias=False, flatten=False, in_units=hidden_size,
+                prefix="lm_head_")
 
     def forward(self, token_ids):
+        from ... import ndarray as F
+
         hidden = self.model(token_ids)
         with _scope(self._head_scope):
-            return self.lm_head(hidden)
+            if self.lm_head is not None:
+                return self.lm_head(hidden)
+            return F.FullyConnected(hidden, self.model.embed.weight.data(), no_bias=True,
+                                    flatten=False, num_hidden=self._vocab)

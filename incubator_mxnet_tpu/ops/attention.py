@@ -70,7 +70,8 @@ from jax.experimental.pallas import tpu as _pltpu
 from jax.sharding import PartitionSpec
 
 __all__ = ["flash_attention", "attention_reference", "latent_attention",
-           "yarn_rotary_tables", "multi_stream_rotary_tables", "apply_rotary"]
+           "yarn_rotary_tables", "multi_stream_rotary_tables", "apply_rotary",
+           "rotary_embedding"]
 
 # The forward kernel keeps one head's whole K and V in VMEM beside its
 # 512-wide tiles, the backward the head's Q, dO, dq and dq's float32 sum: 22 MB
@@ -1350,6 +1351,15 @@ def apply_rotary(x, cos, sin, pairing="interleaved"):
     lead = (None,) * (3 - cos.ndim)        # [S, half] → [1, S, half]
     c, s = cos[lead + (Ellipsis, None, slice(None))], sin[lead + (Ellipsis, None, slice(None))]
     return jnp.concatenate([a * c - b * s, b * c + a * s], -1).astype(x.dtype)
+
+
+@register("rotary_embedding")
+def rotary_embedding(data, theta=10000.0):
+    """Rotary positions ``0 .. S−1`` on every dim of ``data`` [B, S, H, dim]
+    in the rotate-half form (:func:`apply_rotary`'s ``"half"``), frequencies
+    ``theta^(−2i/dim)``: float32 inside, stored in the input's dtype."""
+    cos, sin = yarn_rotary_tables(data.shape[1], data.shape[-1], theta)
+    return apply_rotary(data, cos, sin, "half")
 
 
 @register("latent_attention")

@@ -1,7 +1,9 @@
 """State-space layers (Mamba-2): the causal depthwise convolution over time,
 the chunked state-space scan and the grouped gated RMSNorm, and the mixer
 that strings them together (``gluon.model_zoo.nemotron_h`` takes it;
-docs/nemotron_h.md has the equations).
+docs/nemotron_h.md has the equations).  LFM2's gated short convolution
+(:func:`gated_short_conv`, ``gluon.model_zoo.lfm2_moe``; docs/lfm2.md) is
+the same convolution between two elementwise gates.
 
 The recurrence of one head (``x̃_t`` [P], ``B_t``, ``C_t`` [N], a scalar
 step ``Δ_t`` and a scalar decay ``a_t = exp(Δ_t · A)``, ``A < 0``):
@@ -46,7 +48,7 @@ from jax.experimental.pallas import tpu as _pltpu
 from . import attention as _att
 from .registry import register
 
-__all__ = ["causal_conv1d", "ssm_scan", "gated_rms_norm", "mamba2_mixer"]
+__all__ = ["causal_conv1d", "gated_short_conv", "ssm_scan", "gated_rms_norm", "mamba2_mixer"]
 
 _F32 = jnp.float32
 
@@ -75,6 +77,20 @@ def causal_conv1d(data, weight, bias=None, activation=None):
 
         out = _ACTS[activation](out)
     return out.astype(data.dtype)
+
+
+@register("gated_short_conv")
+def gated_short_conv(bcx, weight):
+    """LFM2's gated short convolution on the in-projection's output ``bcx``
+    [B, S, 3·C], chunked ``[B | C | x̃]``: ``C ⊙ conv(B ⊙ x̃)``, the
+    convolution :func:`causal_conv1d`'s with ``weight`` [C, K], no bias and
+    no activation.  Float32 inside, stored in ``bcx``'s dtype.  One count of
+    ``short_conv_traced`` a traced call site."""
+    from .. import profiler
+
+    profiler.incr("short_conv_traced")
+    b, c, x = jnp.split(bcx.astype(_F32), 3, axis=-1)
+    return (c * causal_conv1d(b * x, weight)).astype(bcx.dtype)
 
 
 @register("gated_rms_norm")

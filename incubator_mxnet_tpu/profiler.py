@@ -261,6 +261,7 @@ _counters = {
     "ssm_scan_dispatch_pallas": 0,    # ... of them traced onto its Pallas kernels
     "ssm_scan_dispatch_xla": 0,       # ... of them traced onto the XLA chunks
     "kda_traced": 0,                  # chunked gated delta rule (KDA) call sites traced into a program
+    "short_conv_traced": 0,           # gated short convolution call sites traced into a program
     "attention_dispatch_masked": 0,   # of the Pallas ones: call sites given a selection of keys a query
     "sparse_attention_traced": 0,     # indexer-selected attention call sites traced into a program
     "sparse_attn_tiles_live": 0,      # 512 x 512 score tiles that hold a selected pair, over layers and steps

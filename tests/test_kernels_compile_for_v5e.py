@@ -148,6 +148,27 @@ def test_the_grouped_query_kernels_compile_at_the_solar_cells_shapes(one_chip, n
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
 
 
+def test_the_grouped_query_kernels_compile_at_head_width_64_for_the_lfm2_cell(
+        one_chip, no_compile_cache):
+    """The blockwise forward and one-pass backward at the lfm2 cell's shapes:
+    2 rows of 32 query heads on 8 key/value heads (groups of 4) of head width
+    64 at S 8192, bf16, 512 x 512 blocks: blocks half a 128-lane column wide,
+    which the one-tile kernels of BERT's width 64 never hand the blockwise
+    ones."""
+    s, scale = 8192, 64 ** -0.5
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    launch = attn_ops._Launch(False, (512, 512))
+    assert attn_ops._pallas_blocks(s, s) == (512, 512)
+
+    def loss(q, k, v):
+        out, lse = attn_ops._flash_kernels_lse(q, k, v, True, scale, launch)
+        return out.astype(jnp.float32).sum(), lse
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
+        spec((2, 32, s, 64)), spec((2, 8, s, 64)), spec((2, 8, s, 64))).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 2
+
+
 def test_the_scan_kernels_compile_at_the_nemotron_cells_shape(one_chip, no_compile_cache):
     """The chunked scan's forward and backward kernels at the nemotron cell's
     shape: x̃ [1, 8192, 64, 64] bf16 as [1, 8192, 4096], 8 groups of state

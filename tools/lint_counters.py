@@ -14,6 +14,13 @@ table in ``docs/observability.md`` ("Counter reference" section): a
 counter added without a doc row — or documented after removal — fails the
 profiler CI tier, so code and doc cannot drift.
 
+And it checks the models' ``jax.named_scope`` names (the ``SCOPE_* = "..."``
+literals of ``gluon/model_zoo``): a per-layer metric selects device
+operations whose scope CONTAINS its string, so no scope may lie inside
+another unless nested in it (``lfm2.conv`` in ``lfm2.conv.core`` is nested;
+a ``conv`` beside it would not be), and each is named in
+``docs/observability.md``.
+
 Exit 0 = every literal declared AND the doc table in sync; 1 = violations
 (listed on stderr).
 """
@@ -44,6 +51,7 @@ def declared_counters():
     raise SystemExit("lint_counters: no _counters dict literal in profiler.py")
 
 
+SCOPE_RE = re.compile(r'^SCOPE_[A-Z0-9_]+ = "([^"]+)"', re.M)
 DOC_PATH = os.path.join(ROOT, "docs", "observability.md")
 DOC_ROW_RE = re.compile(r"^\|\s*`([A-Za-z0-9_]+)`")
 
@@ -106,11 +114,27 @@ def main():
         print(f"docs/observability.md: counter {name!r} documented but not "
               "declared in profiler._counters (stale row?)", file=sys.stderr)
         drift += 1
+    # pass 4: the models' named scopes
+    zoo = os.path.join("incubator_mxnet_tpu", "gluon", "model_zoo")
+    scopes = sorted({name for path, text in files.items()
+                     if os.path.relpath(path, ROOT).startswith(zoo)
+                     for name in SCOPE_RE.findall(text)})
+    doc = open(DOC_PATH).read()
+    for a in scopes:
+        for b in scopes:
+            if a != b and a in b and not b.startswith(a + "."):
+                print(f"scope {a!r} lies inside {b!r} without being nested in it: "
+                      f"a metric of {a!r} would read {b!r}'s operations", file=sys.stderr)
+                drift += 1
+        if f"`{a}" not in doc:
+            print(f"docs/observability.md: scope {a!r} of the model zoo is not named",
+                  file=sys.stderr)
+            drift += 1
     if drift:
         return 1
     print(f"lint_counters OK: {len(declared)} declared counters, all "
           f"incr() literals match, doc table in sync "
-          f"({len(documented)} rows)")
+          f"({len(documented)} rows); {len(scopes)} model scopes apart and named")
     return 0
 
 
