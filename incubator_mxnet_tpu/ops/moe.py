@@ -164,6 +164,17 @@ def _grouped_path(x):
     return "xla"
 
 
+def _count_combines(n_pairs, buckets, top_k):
+    """One count a row bucket of a traced call site: ``moe_combine_gather``
+    where the bucket moves its rows by gathers (:func:`_gathers`),
+    ``moe_combine_scatter`` where by scatter-adds."""
+    from .. import profiler
+
+    for rows in buckets:
+        profiler.incr("moe_combine_gather" if _gathers(n_pairs, rows, top_k)
+                      else "moe_combine_scatter")
+
+
 def _path_in(buckets, rows, path):
     """``path`` in the bucket of ``rows`` rows — but ``"xla"`` in the bucket of
     EVERY pair where that is beyond three times the expected share (all four
@@ -175,6 +186,94 @@ def _path_in(buckets, rows, path):
     return "xla" if len(buckets) == 4 and rows == buckets[-1] else path
 
 
+# A bucket moves its rows between token order and sorted-row order by gathers
+# through the inverse of the expert sort where the T·k pairs are at most
+# GATHER_RATIO times its rows and a token has at most GATHER_TOP_K choices,
+# and by scatter-adds of its rows otherwise: a gather reads every pair, one
+# [T, d] pass a choice, a scatter-add serialises the bucket's rows.  On the
+# v5e the gathers win alone at 5.3 pairs a row in every decoder cell and lose
+# at 10.7 and above (the combine and the input gradient timed alone); in the
+# training step they won at 4 choices a token and lost at 8 (PERF.md §5, §6).
+GATHER_RATIO = 8
+GATHER_TOP_K = 4
+
+
+def _gathers(n_pairs, rows, top_k):
+    """Whether a bucket of ``rows`` rows over ``n_pairs`` pairs, ``top_k`` a
+    token, gathers."""
+    return top_k <= GATHER_TOP_K and n_pairs <= GATHER_RATIO * rows
+
+
+def _sum_of_pairs(values, inv, n_here, top_k, gate=None):
+    """``y[t] = Σ_j [inv[p] < n_here] · f32(values[inv[p]]) · gate[p]``, ``p =
+    t·k + j``: each token's held rows summed by gathers through the inverse of
+    the expert sort, ``[T, d]`` float32.  A pair held elsewhere reads row 0 and
+    is selected away, so whatever the rows past ``n_here`` hold stays out.  One
+    ``[T, d]`` gather a choice ``j``: a ``[T·k, d]`` gather summed over ``k``
+    lays ``k`` on the tile's sublanes, 3 × slower on the v5e at ``k`` 4.  The
+    rows are selected before the cast: cast first, the v5e's compiler wrote
+    each choice's float32 ``[T, d]`` out alone at the lfm2 cell's shape."""
+    held = (inv < n_here).reshape(-1, top_k)
+    at = jnp.where(held, inv.reshape(-1, top_k), 0)
+
+    def choice(j):
+        got = jnp.where(held[:, j, None], values[at[:, j]], 0).astype(jnp.float32)
+        return got if gate is None else got * gate.reshape(-1, top_k)[:, j, None]
+    return sum(map(choice, range(1, top_k)), choice(0))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _token_rows(xt, token, inv, n_here, top_k):
+    """``xt[token]``, whose transpose is :func:`_sum_of_pairs` (float32, cast
+    to ``xt``'s dtype) and not autodiff's scatter-add."""
+    return xt[token]
+
+
+def _token_rows_fwd(xt, token, inv, n_here, top_k):
+    return xt[token], (token, inv, n_here)
+
+
+def _token_rows_bwd(top_k, res, da):
+    from .nn import _zero_cotangent
+
+    _, inv, n_here = res
+    return ((_sum_of_pairs(da, inv, n_here, top_k).astype(da.dtype),)
+            + tuple(_zero_cotangent(a) for a in res))
+
+
+_token_rows.defvjp(_token_rows_fwd, _token_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(ys, gate_flat, pair, inv, n_here, top_k):
+    """The rows summed into their tokens with their gates,
+    :func:`_sum_of_pairs`, the transpose of :func:`_token_rows`.  Its
+    derivative gathers too: ``d ys[r] = ct[token[r]] · gate[pair[r]]``, ``d
+    gate[p] = ⟨ct[t], f32(ys[inv[p]])⟩`` for the held pairs, the dot formed on
+    the rows and gathered through ``inv``."""
+    return _sum_of_pairs(ys, inv, n_here, top_k, gate_flat)
+
+
+def _combine_fwd(ys, gate_flat, pair, inv, n_here, top_k):
+    return _combine(ys, gate_flat, pair, inv, n_here, top_k), (ys, gate_flat, pair, inv,
+                                                                n_here)
+
+
+def _combine_bwd(top_k, res, ct):
+    from .nn import _zero_cotangent
+
+    ys, gate_flat, pair, inv, n_here = res
+    ct_rows = ct[pair // top_k]                                   # [rows, d] float32
+    d_ys = (ct_rows * gate_flat[pair][:, None]).astype(ys.dtype)
+    dots = jnp.sum(ct_rows * ys.astype(jnp.float32), axis=-1)     # [rows]
+    held = inv < n_here
+    d_gate = jnp.where(held, dots[jnp.where(held, inv, 0)], 0)
+    return (d_ys, d_gate) + tuple(_zero_cotangent(a) for a in (pair, inv, n_here))
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
                      w_in, w_down, top_k, form, path="xla"):
     """The held experts' inner function (``form``: SwiGLU on a ``gate | up``
@@ -182,13 +281,19 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
     ``rows`` sorted pairs, summed into their tokens: ``[T, d]`` float32.
     Pairs past ``n_here`` belong to experts held elsewhere: they add nothing
     (and neither grouped product defines their rows, so they are masked, not
-    trusted).  ``path``: :func:`_grouped_path`'s, or ``"interpret"`` (the
-    kernels in the Pallas interpreter: the CPU tests ask for it here); a row
-    count no kernel tile divides (the smallest bucket) stays on XLA."""
+    trusted).  Where :func:`_gathers` says so, the rows reach their tokens,
+    and the tokens' gradient leaves the rows, by gathers through the inverse
+    of the sort, ``inv[order[r]] = r`` (:func:`_combine`,
+    :func:`_token_rows`), formed here so that a bucket that keeps the
+    scatter-adds of its rows into ``[T, d]`` does not pay for it.  ``path``:
+    :func:`_grouped_path`'s, or ``"interpret"`` (the kernels in the Pallas
+    interpreter: the CPU tests ask for it here); a row count no kernel tile
+    divides (the smallest bucket) stays on XLA."""
     from .grouped_matmul import grouped_dot, row_tile
 
     cdt = xt.dtype
     kernels = path != "xla" and row_tile(rows) is not None
+    gathers = _gathers(order.shape[0], rows, top_k)
     pair = order[:rows]
     token = pair // top_k
     ours = (jnp.arange(rows) < n_here)[:, None]
@@ -201,10 +306,9 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
         """The grouped product with the rows past the last group zero on both
         sides: on the TPU ``ragged_dot`` and the kernels leave them UNDEFINED,
         in the result and (through the transpose) in the cotangent of ``a``;
-        masking the operand masks that cotangent before it is scattered back
-        into real tokens, and masking the result keeps whatever lay there
-        (NaN bit patterns included) out of everything downstream and its
-        derivative."""
+        masking the operand masks that cotangent before it goes back to real
+        tokens, and masking the result keeps whatever lay there (NaN bit
+        patterns included) out of everything downstream and its derivative."""
         a = jnp.where(ours, a, 0)
         if kernels:
             out = grouped_dot(a, w.astype(cdt), group_sizes, path == "interpret")
@@ -212,7 +316,12 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
             out = jax.lax.ragged_dot(a, w.astype(cdt), group_sizes, precision=prec)
         return jnp.where(ours, out, 0)
 
-    up = grouped(xt[token], w_in)                    # [rows, 2h] or [rows, h]
+    if gathers:
+        inv = jnp.argsort(order).astype(order.dtype)
+        a = _token_rows(xt, token, inv, n_here, top_k)
+    else:
+        a = xt[token]
+    up = grouped(a, w_in)                            # [rows, 2h] or [rows, h]
     if form == "swiglu":
         h = w_down.shape[1]
         act = (jax.nn.silu(up[:, :h]) * up[:, h:]).astype(cdt)
@@ -221,6 +330,8 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
     else:
         raise ValueError(f"expert_form {form!r}: 'swiglu' or 'relu2'")
     ys = grouped(act, w_down)                                  # [rows, d]
+    if gathers:
+        return _combine(ys, gate_flat, pair, inv, n_here, top_k)
     ys = ys.astype(jnp.float32) * gate_flat[pair][:, None]
     return jnp.zeros(xt.shape, jnp.float32).at[token].add(ys)
 
@@ -229,8 +340,10 @@ def _experts_on_rows(rows, xt, order, gate_flat, group_sizes, n_here,
 def _experts_in_bucket(buckets, top_k, form, path, xt, gate_flat, w_in, w_down,
                        order, group_sizes, n_here):
     """:func:`_experts_on_rows` at the smallest of ``buckets`` that holds
-    ``n_here`` rows.  The derivative is its own rule and not that of
-    ``lax.switch``: branches of different row counts keep intermediates of
+    ``n_here`` rows, each bucket moving its rows by gathers or by
+    scatter-adds as its static shape says (:func:`_gathers`).  The
+    derivative is its own rule and not that of ``lax.switch``: branches of
+    different row counts keep intermediates of
     different shapes, and differentiating the switch makes EVERY branch write
     (zeros for) every other branch's, the weights included — 1.9 ms a pass
     at 64 rows on the v5e.  So the forward keeps only what it was given, and
@@ -347,6 +460,7 @@ def moe_ffn_dropless(x, router_w, select_bias, w_in, w_down,
 
     with jax.named_scope(scope + ".experts"):
         buckets = tuple(dropless_row_buckets(T * k, count, E))
+        _count_combines(T * k, buckets, k)
         y = _experts_in_bucket(buckets, k, expert_form, _grouped_path(xt), xt,
                                gates.reshape(-1), w_in, w_down, order,
                                group_sizes, n_here)

@@ -271,6 +271,8 @@ _counters = {
     "remat_kept_bytes": 0,            # bytes of named values the layer checkpoints of a traced step keep
     "moe_grouped_dispatch_pallas": 0,  # moe_ffn_dropless call sites traced onto the Pallas grouped-product kernels
     "moe_grouped_dispatch_xla": 0,    # moe_ffn_dropless call sites traced onto jax.lax.ragged_dot
+    "moe_combine_gather": 0,          # moe_ffn_dropless row buckets traced moving rows by gathers through the inverse sort
+    "moe_combine_scatter": 0,         # moe_ffn_dropless row buckets traced moving rows by scatter-adds
     "elastic_restart": 0,             # supervisor job re-formations
     "collective_timeout": 0,          # collective-watchdog expiries
     "snapshot_commit_ms": 0,          # two-phase run-snapshot commit wall ms

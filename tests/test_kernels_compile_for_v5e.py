@@ -8,6 +8,7 @@ ONE file, and the topology is described inside a fixture: only the worker
 that runs this file loads the TPU's library, and only once a test of it has
 started."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +54,7 @@ EXPERT_LAYERS = {
     "keye-vl-2.0-30b-a3b.clm-s8192": (8192, 2048, 768, 8, "swiglu", 12288, 16),
     "nemotron-3-nano-30b-a3b.clm-s8192, every pair here": (8192, 2688, 1856, 6, "relu2", 49152, 8),
     "solar-open2-250b.clm-s4096": (4096, 4096, 1280, 8, "swiglu", 1280, 8),
+    "lfm2-24b-a2b.clm-b2-s8192": (16384, 2048, 1536, 4, "swiglu", 12288, 8),
 }
 
 
@@ -75,6 +77,31 @@ def test_the_held_experts_products_compile_at_the_cells_widths(cell, one_chip, n
         spec((held,), jnp.int32), spec((), jnp.int32)).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 6
 
+
+
+def test_the_gathered_rows_cast_inside_their_sum_at_the_lfm2_cells_shape(one_chip, no_compile_cache):
+    """The combine of the lfm2 cell's bucket (12,288 rows over 2 x 8,192
+    tokens, 4 a token) by gathers through the inverse sort, added to its
+    input: no float32 ``[T, d]`` is written out alone.  Each choice's rows
+    are selected before they are cast; cast first, the compiler wrote every
+    choice's float32 copy to memory, 4 x 134 MB a pass."""
+    b, s, d, h, top_k, held, rows = 2, 8192, 2048, 1536, 4, 8, 12288
+    tokens = b * s
+    assert moe_ops._gathers(tokens * top_k, rows, top_k)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, gate, w_in, w_down, order, group_sizes, n_here):
+        y = moe_ops._experts_on_rows(rows, x.reshape(tokens, d), order, gate, group_sizes,
+                                     n_here, w_in, w_down, top_k, "swiglu", "pallas")
+        return x + y.astype(x.dtype).reshape(x.shape)
+
+    text = jax.jit(layer).lower(
+        spec((b, s, d), jnp.bfloat16), spec((tokens * top_k,), jnp.float32),
+        spec((held, d, 2 * h), jnp.bfloat16), spec((held, h, d), jnp.bfloat16),
+        spec((tokens * top_k,), jnp.int32), spec((held,), jnp.int32),
+        spec((), jnp.int32)).compile().as_text()
+    unfused = [c for c in text.split("\n\n") if "fused_computation" not in c.split("\n", 1)[0]]
+    assert re.findall(r"= f32\[[0-9,]+\]\{[^}]*\} convert\(", "\n".join(unfused)) == []
 
 def test_the_selected_attention_kernels_compile_at_the_keye_cells_shapes(one_chip, no_compile_cache):
     """The blockwise forward (with its log-sum-exp) and one-pass backward

@@ -97,3 +97,46 @@ def test_moe_ffn_dropless_takes_the_kernels_on_the_tpu(form, monkeypatch):
         got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
         assert np.isfinite(got).all()
         assert np.sqrt(np.mean((got - want) ** 2)) < 2e-2 * np.sqrt(np.mean(want ** 2))
+
+
+# cell: tokens, model width, expert width, experts a token, the row bucket the cell runs in
+LAYERS = {
+    "xing4.0-29b-a4b.clm-s4096": (4096, 3584, 1024, 4, 3072),
+    "lfm2-24b-a2b.clm-b2-s8192": (16384, 2048, 1536, 4, 12288),
+}
+
+
+@pytest.mark.parametrize("cell", LAYERS)
+def test_gathers_and_scatter_adds_give_one_layer_on_the_chip(cell, monkeypatch):
+    """One expert layer's bucket at the cell's shapes on the kernels, rows
+    moved by gathers through the inverse sort and by scatter-adds: the same
+    value and the same gradients (the input gradient summed in float32 by the
+    gathers and in bf16 by the scatter-add: a bf16 rounding apart)."""
+    tokens, d, h, top_k, rows = LAYERS[cell]
+    rng = np.random.default_rng(5)
+    key = np.minimum(rng.integers(0, 64, tokens * top_k), GROUPS)      # 8 of 64 held
+    order = np.argsort(key, kind="stable").astype(np.int32)
+    sizes = np.bincount(key, minlength=GROUPS + 1)[:GROUPS].astype(np.int32)
+    assert sizes.sum() <= rows
+    w = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.02, jnp.bfloat16)
+    xt, w_in, w_down = w(tokens, d) * 50, w(GROUPS, d, 2 * h), w(GROUPS, h, d)
+    gate = jnp.asarray(rng.uniform(size=tokens * top_k), jnp.float32)
+    consts = jnp.asarray(order), jnp.asarray(sizes), jnp.int32(sizes.sum())
+
+    def run(ratio):
+        monkeypatch.setattr(moe_ops, "GATHER_RATIO", ratio)
+        assert moe_ops._gathers(tokens * top_k, rows, top_k) == (ratio > 0)
+
+        def loss(xt, gate, w_in, w_down, order, sizes, n_here):
+            y = moe_ops._experts_on_rows(rows, xt, order, gate, sizes, n_here, w_in, w_down,
+                                         top_k, "swiglu", "pallas")
+            return (y * y).sum(), y
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3), has_aux=True))(
+            xt, gate, w_in, w_down, *consts)
+
+    (_, y), grads = run(1e9)
+    (_, y_scatter), grads_scatter = run(0)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_scatter), rtol=1e-4, atol=1e-4)
+    for got, want in zip(grads, grads_scatter):
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        close(got, want)
