@@ -24,7 +24,12 @@ belong to it:
    the S×S score matrix is never materialized in HBM and memory stays
    linear in S.  Accumulation in fp32 on the MXU
    (``preferred_element_type``), inputs may be bf16.  Want ``[B·H, S, Dh]``
-   physically, so ``[B, S, H, Dh]`` callers pay two transposes.
+   physically, so ``[B, S, H, Dh]`` callers pay two transposes.  A causal
+   call whose blocks tile a square score matrix with an even number of them
+   takes the FOLDED kernels (``_folds``) where the head's rows they keep
+   resident fit in VMEM: a grid cell computes query blocks i and n-1-i (the
+   backward: key blocks j and n-1-j), n + 1 block steps in a loop whose trip
+   count the compiler knows, two steps a loop body.
 3. **XLA path** (short sequences, float16, lengths no block divides,
    non-TPU backends, ``MXNET_TPU_FLASH=off``): same math as one fused jnp
    expression with a rematerialized backward; in the ``[B, S, H, Dh]``
@@ -76,10 +81,16 @@ __all__ = ["flash_attention", "attention_reference", "latent_attention",
 # The forward kernel keeps one head's whole K and V in VMEM beside its
 # 512-wide tiles, the backward the head's Q, dO, dq and dq's float32 sum: 22 MB
 # at S 4096 with 192-wide keys, 36 MB at S 16384 and 70 MB at S 32768 with
-# 64-wide ones.  The compiler's default scoped limit is 16 MiB; a v5e core has
-# 128 MiB.
+# 64-wide ones.  The folded kernels keep the head's whole rows of the blocks
+# they visit too (the forward Q, O and the log-sum-exp; the backward K, V, dK
+# and dV), and a selection's rows of both blocks of a cell: 40 MB for the
+# forward at S 8192 under an int8 selection, and are taken only where those
+# rows fit (``_folds``).  The compiler's default scoped
+# limit is 16 MiB; a v5e core has 128 MiB.
 _MOSAIC_PARAMS = _pltpu.CompilerParams(vmem_limit_bytes=48 << 20)
-_BWD_PARAMS = _pltpu.CompilerParams(
+# The kernels whose grid cells share blocks resident across the inner axis (the
+# one-pass backward's dq; the folded kernels' head rows) run its cells in order.
+_RESIDENT_PARAMS = _pltpu.CompilerParams(
     dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=100 << 20)
 
 # TPU lane width: row statistics (lse) are replicated across a 128-lane
@@ -205,6 +216,48 @@ def _live_softmax_update(o, m, l, s, v, matmul):
     return o * corr + matmul(p, v), m_new, l * corr + p.sum(axis=-1, keepdims=True)
 
 
+def _precision(dtype):
+    """Matmul precision pinned per dtype: the package-global 'highest'
+    default would request an fp32 contraction on bf16 operands, which Mosaic
+    rejects."""
+    return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)  # bf16 AND fp16 operands
+
+
+def _fwd_step(q, k, v, carry, q_start, k_start, chosen, *, causal, scale):
+    """One key block of a query block's online softmax: ``carry`` (m, l,
+    acc) after the keys ``k`` [Bk, D] / ``v`` [Bk, Dv] from position
+    ``k_start`` on, for the queries ``q`` [Bq, D] from ``q_start`` on.
+    ``chosen`` [Bq, Bk] int8 or None is the selection's tile."""
+    m, l, acc = carry
+    block_q, block_k = q.shape[0], k.shape[0]
+    prec = _precision(q.dtype)
+    s = jax.lax.dot_general(
+        q, k, _NT, preferred_element_type=jnp.float32, precision=prec,
+    ) * scale  # [Bq, Bk], fp32 accumulate then scale
+    if causal:
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+    if chosen is not None:
+        s = jnp.where(chosen.astype(jnp.int32) != 0, s, -jnp.inf)
+    update = _live_softmax_update if chosen is None else online_softmax_update
+    acc_new, m_new, l_new = update(
+        acc, m, l, s, v,
+        lambda p, v: jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN,
+            preferred_element_type=jnp.float32, precision=prec,
+        ),
+    )
+    return m_new, l_new, acc_new
+
+
+def _fwd_init(block_q, d_v):
+    return (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32),
+            jnp.zeros((block_q, d_v), jnp.float32))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale,
                 select_ref=None):
     """One (batch·head, q-block) grid cell: stream K/V blocks, online
@@ -218,9 +271,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale,
     somewhere (the caller's contract), or its result is undefined.
 
     Operands stay in their input dtype (bf16 rides the MXU at full rate)
-    with fp32 accumulation via preferred_element_type; matmul precision is
-    pinned per-dtype because the package-global 'highest' default would
-    request an fp32 contraction on bf16 operands, which Mosaic rejects.
+    with fp32 accumulation via preferred_element_type (:func:`_precision`).
 
     Under the causal mask every block a cell computes is masked, those wholly
     below the diagonal too: the compare and select hide under the MXU's
@@ -228,39 +279,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale,
     v5e (PERF.md §6, PR 31)."""
     i = _pl.program_id(1)
     block_q = q_ref.shape[1]
-    seq_k = k_ref.shape[1]
-    nk = seq_k // block_k
-    prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)  # bf16 AND fp16 operands
-
+    nk = k_ref.shape[1] // block_k
     q = q_ref[0]  # [Bq, D], native dtype
-    m0 = jnp.full((block_q, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
 
     def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, _pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, _pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, _NT, preferred_element_type=jnp.float32, precision=prec,
-        ) * scale  # [Bq, Bk], fp32 accumulate then scale
-        if causal:
-            q_pos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        if select_ref is not None:
-            chosen = select_ref[0, :, _pl.ds(j * block_k, block_k)]
-            s = jnp.where(chosen.astype(jnp.int32) != 0, s, -jnp.inf)
-        update = _live_softmax_update if select_ref is None else online_softmax_update
-        acc_new, m_new, l_new = update(
-            acc, m, l, s, v,
-            lambda p, v: jax.lax.dot_general(
-                p.astype(v.dtype), v, _NN,
-                preferred_element_type=jnp.float32, precision=prec,
-            ),
-        )
-        return m_new, l_new, acc_new
+        keys = _pl.ds(j * block_k, block_k)
+        chosen = None if select_ref is None else select_ref[0, :, keys]
+        return _fwd_step(q, k_ref[0, keys, :], v_ref[0, keys, :], carry,
+                         i * block_q, j * block_k, chosen, causal=causal, scale=scale)
 
     if causal:
         # Skip K/V blocks entirely in the masked future: q-block i only
@@ -269,7 +295,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale,
         nk_bound = jnp.minimum(nk, ((i + 1) * block_q + block_k - 1) // block_k)
     else:
         nk_bound = nk
-    m, l, acc = lax.fori_loop(0, nk_bound, body, (m0, l0, acc0))
+    m, l, acc = lax.fori_loop(0, nk_bound, body, _fwd_init(block_q, v_ref.shape[2]))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     if lse_ref is not None:
         # log-sum-exp per query row, saved for the blockwise backward:
@@ -277,6 +303,138 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal, scale,
         # online rescaling.  Replicated across a 128-lane trailing dim to
         # satisfy TPU tiling (same layout as jax's reference TPU kernel).
         lse_ref[0] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape[1:])
+
+
+# ---------------------------------------------------------------------------
+# The causal triangle folded.  Where a causal call's blocks tile a square score
+# matrix (Sq == Sk, one block size, an even number n of blocks), grid cell i
+# takes query block i and query block n-1-i (in the backward: key block j and
+# key block n-1-j), which together hold n+1 blocks at or below the diagonal,
+# whatever i: ONE loop whose trip count the compiler knows, where a cell of the
+# unfolded kernels loops a number of times it reads from its grid index.  The
+# step index says which block pair a step computes and where the second block
+# starts, so every step is the same code.  The forward visits a query row's
+# key blocks in the unfolded order, so its bits are the unfolded kernel's; the
+# backward sums dq in another order.
+# ---------------------------------------------------------------------------
+
+
+def _lanes(rows, width, dtype):
+    """VMEM bytes of a [rows, width] block: its last dimension in whole
+    128-lane tiles."""
+    return rows * -(-width // _LANE) * _LANE * jnp.dtype(dtype).itemsize
+
+
+def _folded_vmem_bytes(s, block, d, d_v, dtype, group, selected):
+    """The VMEM the folded kernels' blocks take, the larger of the forward's
+    and the backward's: every block double-buffered, the backward's float32
+    dq sum once.  The forward holds the head's Q, K, V, O and log-sum-exp,
+    the backward its Q, K, V, dO, dQ, dK and dV (float32 where grouped heads
+    sum theirs after the kernel) and the query rows' statistics; both the
+    int8 rows of a cell's two blocks under a selection."""
+    f32 = jnp.float32
+    sel = 2 * _lanes(block, s, jnp.int8) if selected else 0
+    fwd = 2 * _lanes(s, d, dtype) + 2 * _lanes(s, d_v, dtype) + _lanes(s, _LANE, f32) + sel
+    grad = f32 if group > 1 else dtype
+    bwd = (3 * _lanes(s, d, dtype) + 2 * _lanes(s, d_v, dtype) + _lanes(s, d, grad)
+           + _lanes(s, d_v, grad) + 2 * _lanes(s // block, block, f32) + sel)
+    return max(2 * fwd, 2 * bwd + _lanes(s, d, f32))
+
+
+# The folded kernels' blocks must leave the steps' tiles (scores,
+# probabilities and their gradients, a few MB) room under _RESIDENT_PARAMS's
+# limit.  Compiled for a v5e: S 16384 with 64-wide heads, 64 MB of blocks by
+# _folded_vmem_bytes, fits; S 32768 (128 MB) and S 16384 with 192-wide keys
+# (104 MB) do not.
+_FOLDED_VMEM_BYTES = 84 << 20
+
+
+def _folds(causal, sq, sk, blocks, d, d_v, dtype, group=1, selected=False):
+    """Does this blockwise call take the folded kernels?  From its shapes:
+    causal, a square score matrix, one block size (``blocks``: query, key),
+    an even number of blocks, and the head's rows the cells keep resident
+    within :data:`_FOLDED_VMEM_BYTES` (``group`` query heads a key/value
+    head, ``selected`` under an int8 selection); else the unfolded kernels,
+    whose cells keep the head's keys (forward) or queries (backward) only."""
+    block_q, block_k = blocks
+    n = sq // block_q
+    return (causal and sq == sk and block_q == block_k and n >= 2 and n % 2 == 0
+            and _folded_vmem_bytes(sq, block_q, d, d_v, dtype, group, selected)
+            <= _FOLDED_VMEM_BYTES)
+
+
+# Steps a loop body of the folded kernels.  Measured on a v5e at the keye
+# cell's shape (32 query heads on 4 key/value heads of 128, S 8192, an int8
+# selection), forward / backward ms a call (PERF.md §6): unfolded 6.23 /
+# 10.52; folded, one step a body 6.00 / 10.14, two 5.56 / 9.70, three 5.34 /
+# 9.61, the loop unrolled 5.06 / 9.40; the unfolded kernels at two steps a body
+# (their count read from the grid index, the odd step under a cond) 5.72 /
+# 10.12, 60 % of the folded pair's gain (21 % at xing's shape, 58-65 % at
+# nemotron's and lfm2's).  The compiled kernel holds a copy of the
+# step for each step a body and each step left over ((n + 1) mod this), so an
+# unrolled loop's code grows with n (11.5 x the forward's at S 8192) and every
+# call site of a program carries it; two steps a body take 3 copies whatever n.
+_FOLD_STEPS_A_BODY = 2
+
+
+def _run_steps(steps, step, carry):
+    """``carry = step(t, carry)`` for t in 0..steps-1: the steps left over
+    first, then a loop of ``_FOLD_STEPS_A_BODY`` steps a body, so that the
+    compiler schedules each step's products beside its neighbour's vector
+    work."""
+    per = _FOLD_STEPS_A_BODY
+    head = steps % per
+    for t in range(head):
+        carry = step(t, carry)
+
+    def body(r, carry):
+        for u in range(per):
+            carry = step(head + r * per + u, carry)
+        return carry
+
+    return lax.fori_loop(0, steps // per, body, carry)
+
+
+def _fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block, scale,
+                       select_refs=None):
+    """Grid cell (batch·head, i) of a folded causal forward: query block i
+    over key blocks 0..i, then query block n-1-i over key blocks 0..n-1-i, n
+    + 1 steps.  q_ref, o_ref and lse_ref hold the head's whole sequence
+    (resident while its cells go by), k_ref / v_ref as in
+    :func:`_fwd_kernel`; ``select_refs`` (the selection's rows of query
+    block i, of query block n-1-i), each [1, Bq, Sk] int8, or None."""
+    i = _pl.program_id(1)
+    n = k_ref.shape[1] // block
+    init = _fwd_init(block, v_ref.shape[2])
+
+    def body(t, carry):
+        first = t <= i                              # still on query block i
+        qb = jnp.where(first, i, n - 1 - i)
+        kb = jnp.where(first, t, t - i - 1)
+        # the second query block starts from the online softmax's start
+        carry = tuple(jnp.where(t == i + 1, a, c) for a, c in zip(init, carry))
+        rows = _pl.ds(_pl.multiple_of(qb * block, block), block)
+        keys = _pl.ds(_pl.multiple_of(kb * block, block), block)
+        chosen = None
+        if select_refs is not None:
+            lo, hi = select_refs
+            # both tiles read and one selected: a cond that reads one splits
+            # the loop body, and measured slower (keye's forward 5.53 -> 6.05 ms)
+            chosen = jnp.where(first, lo[0, :, keys], hi[0, :, keys])
+        m, l, acc = carry = _fwd_step(
+            q_ref[0, rows, :], k_ref[0, keys, :], v_ref[0, keys, :], carry,
+            qb * block, kb * block, chosen, causal=True, scale=scale)
+
+        @_pl.when((t == i) | (t == n))              # a query block's last key block
+        def _():
+            o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+            if lse_ref is not None:
+                lse_ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                                       (block, lse_ref.shape[2]))
+
+        return carry
+
+    _run_steps(n + 1, body, init)
 
 
 def _kv_row(rows, kv_rows):
@@ -303,41 +461,48 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
     bh, sq, d = q.shape
     sk, dv = k.shape[1], v.shape[2]
     kv_row = _kv_row(bh, k.shape[0])
-    o_shape = (bh, sq, dv)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     if sq % block_q or sk % block_k:
         raise ValueError(f"sequence lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
-    grid = (bh, sq // block_q)
-    in_specs = [
-        _pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        _pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
-        _pl.BlockSpec((1, sk, dv), lambda b, i: (kv_row(b), 0, 0)),
-    ]
+    n = sq // block_q
+    folded = _folds(causal, sq, sk, (block_q, block_k), d, dv, q.dtype, bh // k.shape[0],
+                    select is not None)
+    if folded:   # grid cell i: query blocks i and n-1-i, the head's rows resident
+        grid, params = (bh, n // 2), _RESIDENT_PARAMS
+        rows = lambda w: _pl.BlockSpec((1, sq, w), lambda b, i: (b, 0, 0))
+        selected = (lambda b, i: i, lambda b, i: n - 1 - i)
+    else:
+        grid, params = (bh, n), _MOSAIC_PARAMS
+        rows = lambda w: _pl.BlockSpec((1, block_q, w), lambda b, i: (b, i, 0))
+        selected = (lambda b, i: i,)
+    in_specs = [rows(d),
+                _pl.BlockSpec((1, sk, d), lambda b, i: (kv_row(b), 0, 0)),
+                _pl.BlockSpec((1, sk, dv), lambda b, i: (kv_row(b), 0, 0))]
     operands = (q, k, v)
-    static = dict(block_k=block_k, causal=causal, scale=scale)
     if select is not None:
         batch_row = _kv_row(bh, select.shape[0])    # query row → batch row
-        in_specs.append(
-            _pl.BlockSpec((1, block_q, sk), lambda b, i: (batch_row(b), i, 0)))
-        operands += (select,)
+        in_specs += [_pl.BlockSpec((1, block_q, sk),
+                                   lambda b, i, at=at: (batch_row(b), at(b, i), 0))
+                     for at in selected]
+        operands += (select,) * len(selected)
+    n_select = len(operands) - 3
 
-        def kernel(q_ref, k_ref, v_ref, select_ref, o_ref, lse_ref=None):
-            _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                        select_ref=select_ref, **static)
-    elif with_lse:
-        kernel = functools.partial(_fwd_kernel, **static)
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, **_):
-            _fwd_kernel(q_ref, k_ref, v_ref, o_ref, None, **static)
-    if with_lse:
-        out_shape = (jax.ShapeDtypeStruct(o_shape, q.dtype),
-                     jax.ShapeDtypeStruct((bh, sq, _LANE), jnp.float32))
-        out_specs = (_pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0)),
-                     _pl.BlockSpec((1, block_q, _LANE), lambda b, i: (b, i, 0)))
-    else:
-        out_shape = jax.ShapeDtypeStruct(o_shape, q.dtype)
-        out_specs = _pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0))
+    def kernel(q_ref, k_ref, v_ref, *refs):
+        selects, (o_ref, *lse_ref) = refs[:n_select] or None, refs[n_select:]
+        lse_ref = lse_ref[0] if lse_ref else None
+        if folded:
+            _fwd_folded_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, block=block_q,
+                               scale=scale, select_refs=selects)
+        else:
+            _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, block_k=block_k, causal=causal,
+                        scale=scale, select_ref=selects and selects[0])
+
+    out_shape = (jax.ShapeDtypeStruct((bh, sq, dv), q.dtype),
+                 jax.ShapeDtypeStruct((bh, sq, _LANE), jnp.float32))
+    out_specs = (rows(dv), rows(_LANE))
+    if not with_lse:
+        out_shape, out_specs = out_shape[0], out_specs[0]
     return _pl.pallas_call(
         kernel,
         out_shape=out_shape,
@@ -345,7 +510,8 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
         in_specs=in_specs,
         out_specs=out_specs,
         interpret=interpret,
-        compiler_params=_MOSAIC_PARAMS,
+        compiler_params=params,
+        name="flash_fwd_folded" if folded else "flash_fwd",
     )(*operands)
 
 
@@ -367,6 +533,45 @@ def _flash_fwd_pallas(q, k, v, causal, scale, interpret, block_q=128, block_k=12
 # ---------------------------------------------------------------------------
 
 
+def _bwd_step(k, v, q_ref, do_ref, lse_ref, delta_ref, dq_acc, carry, qb, ahead, offset,
+              chosen, *, scale):
+    """Query block ``qb``'s share of one key block's gradients: ``carry``
+    (dk, dv) float32 after it, its dq summed into ``dq_acc``.  ``ahead``
+    [Bk, Bq] is query column less key row inside a block, ``offset`` the key
+    block's first position less the query block's (None: no causal mask);
+    ``chosen`` [Bk, Bq] int8 or None is the transposed selection's tile."""
+    dk, dv = carry
+    block_q = lse_ref.shape[2]
+    mm = functools.partial(jax.lax.dot_general, precision=_precision(k.dtype),
+                           preferred_element_type=jnp.float32)
+    rows = _pl.ds(_pl.multiple_of(qb * block_q, block_q), block_q)
+    q = q_ref[0, rows, :]
+    do = do_ref[0, rows, :]
+    lse = lse_ref[0, _pl.ds(qb, 1), :]          # [1, Bq] fp32
+    delta = delta_ref[0, _pl.ds(qb, 1), :]
+    st = mm(k, q, _NT) * scale                 # [Bk, Bq]: sᵀ
+    if offset is not None:
+        st = jnp.where(ahead >= offset, st, -jnp.inf)
+    if chosen is not None:
+        st = jnp.where(chosen.astype(jnp.int32) != 0, st, -jnp.inf)
+    pt = jnp.exp(st - lse)                     # masked → exp(-inf) = 0
+    dv = dv + mm(pt.astype(do.dtype), do, _NN)
+    dpt = mm(v, do, _NT)
+    dst = (pt * (dpt - delta)).astype(q.dtype)
+    dk = dk + mm(dst, q, _NN)
+    dq_acc[rows, :] += mm(dst, k, _TN)         # [Bq, D]
+    return dk, dv
+
+
+def _ahead(block_k, block_q):
+    """Query column less key row inside a [Bk, Bq] block: q_pos - k_pos less
+    the blocks' offsets.  Every causal block is masked, those wholly past the
+    diagonal too: the compare and select hide under the MXU's work, and an
+    unmasked loop of its own for them measured slower."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0))
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, causal, scale,
                 select_ref=None):
@@ -380,45 +585,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     j = _pl.program_id(1)
     block_k, d, d_v = k_ref.shape[1], k_ref.shape[2], v_ref.shape[2]
     nq = q_ref.shape[1] // block_q
-    prec = (jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32
-            else jax.lax.Precision.DEFAULT)
-    mm = functools.partial(jax.lax.dot_general, precision=prec,
-                           preferred_element_type=jnp.float32)
-
     k = k_ref[0]                       # [Bk, D] native dtype
     v = v_ref[0]                       # [Bk, Dv]
+    ahead = _ahead(block_k, block_q) if causal else None
 
     @_pl.when(j == 0)
     def _():
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    if causal:
-        # query column - key row inside a block: q_pos - k_pos less the
-        # blocks' offsets.  Every block is masked, those wholly past the
-        # diagonal too: the compare and select hide under the MXU's work,
-        # and an unmasked loop of its own for them measured slower
-        ahead = (jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-                 - jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0))
-
     def body(i, carry):
-        dk, dv = carry
-        rows = _pl.ds(_pl.multiple_of(i * block_q, block_q), block_q)
-        q = q_ref[0, rows, :]
-        do = do_ref[0, rows, :]
-        lse = lse_ref[0, _pl.ds(i, 1), :]          # [1, Bq] fp32
-        delta = delta_ref[0, _pl.ds(i, 1), :]
-        st = mm(k, q, _NT) * scale                 # [Bk, Bq]: sᵀ
-        if causal:
-            st = jnp.where(ahead >= j * block_k - i * block_q, st, -jnp.inf)
-        if select_ref is not None:
-            st = jnp.where(select_ref[0, :, rows].astype(jnp.int32) != 0, st, -jnp.inf)
-        pt = jnp.exp(st - lse)                     # masked → exp(-inf) = 0
-        dv = dv + mm(pt.astype(do.dtype), do, _NN)
-        dpt = mm(v, do, _NT)
-        dst = (pt * (dpt - delta)).astype(q.dtype)
-        dk = dk + mm(dst, q, _NN)
-        dq_acc[rows, :] += mm(dst, k, _TN)         # [Bq, D]
-        return dk, dv
+        chosen = None if select_ref is None else select_ref[
+            0, :, _pl.ds(_pl.multiple_of(i * block_q, block_q), block_q)]
+        return _bwd_step(k, v, q_ref, do_ref, lse_ref, delta_ref, dq_acc, carry, i, ahead,
+                         j * block_k - i * block_q if causal else None, chosen, scale=scale)
 
     # from the query block that holds this key block's first key on: the
     # blocks before it lie wholly in the masked future
@@ -428,6 +607,53 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         (jnp.zeros((block_k, d), jnp.float32), jnp.zeros((block_k, d_v), jnp.float32)))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @_pl.when(j == _pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+
+def _bwd_folded_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dq_acc, *, block, scale, select_refs=None):
+    """Grid cell (batch·head, j) of a folded causal backward: key block j
+    over query blocks j..n-1, then key block n-1-j over query blocks
+    n-1-j..n-1, n + 1 steps.  As :func:`_bwd_kernel`, but k_ref / v_ref and
+    dk_ref / dv_ref hold the head's whole sequence (resident while its cells
+    go by); ``select_refs`` (the transposed selection's rows of key block j,
+    of key block n-1-j), each [1, Bk, Sq] int8, or None."""
+    j = _pl.program_id(1)
+    n = q_ref.shape[1] // block
+    ahead = _ahead(block, block)
+    init = (jnp.zeros((block, k_ref.shape[2]), jnp.float32),
+            jnp.zeros((block, v_ref.shape[2]), jnp.float32))
+
+    @_pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
+
+    def body(t, carry):
+        first = t < n - j                           # still on key block j
+        kb = jnp.where(first, j, n - 1 - j)
+        qb = jnp.where(first, j + t, t - 1)
+        carry = tuple(jnp.where(t == n - j, a, c) for a, c in zip(init, carry))
+        keys = _pl.ds(_pl.multiple_of(kb * block, block), block)
+        chosen = None
+        if select_refs is not None:
+            lo, hi = select_refs
+            queries = _pl.ds(_pl.multiple_of(qb * block, block), block)
+            chosen = jnp.where(first, lo[0, :, queries], hi[0, :, queries])   # as the forward
+        dk, dv = carry = _bwd_step(k_ref[0, keys, :], v_ref[0, keys, :], q_ref, do_ref, lse_ref,
+                                   delta_ref, dq_acc, carry, qb, ahead, (kb - qb) * block,
+                                   chosen, scale=scale)
+
+        @_pl.when((t == n - j - 1) | (t == n))      # a key block's last query block
+        def _():
+            dk_ref[0, keys, :] = (dk * scale).astype(dk_ref.dtype)
+            dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
+
+        return carry
+
+    _run_steps(n + 1, body, init)
 
     @_pl.when(j == _pl.num_programs(1) - 1)
     def _():
@@ -448,7 +674,7 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
     kv_row, group = _kv_row(bh, bkv), bh // bkv
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
-    nq = sq // block_q
+    nq, nk = sq // block_q, sk // block_k
     # the query rows' statistics as rows: the forward's log-sum-exp, and
     # rowsum(dO ⊙ O) once a row where the kernels used to recompute it (and
     # read o whole) for every key block
@@ -457,11 +683,20 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
                     axis=-1).reshape(bh, nq, block_q)
 
     head = lambda rows, w: _pl.BlockSpec((1, rows, w), lambda b, j: (b, 0, 0))
-    blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (b, j, 0))
-    kv_blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (kv_row(b), j, 0))
+    folded = _folds(causal, sq, sk, (block_q, block_k), d, d_v, q.dtype, group,
+                    select is not None)
+    if folded:   # grid cell j: key blocks j and n-1-j, the head's keys resident
+        grid = (bh, nk // 2)
+        blk = lambda w: head(sk, w)
+        kv_blk = lambda w: _pl.BlockSpec((1, sk, w), lambda b, j: (kv_row(b), 0, 0))
+        selected = (lambda b, j: j, lambda b, j: nk - 1 - j)
+    else:
+        grid = (bh, nk)
+        blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (b, j, 0))
+        kv_blk = lambda w: _pl.BlockSpec((1, block_k, w), lambda b, j: (kv_row(b), j, 0))
+        selected = (lambda b, j: j,)
     part = (lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)) if group == 1 else (
         lambda a: jax.ShapeDtypeStruct((bh,) + a.shape[1:], jnp.float32))
-    static = dict(block_q=block_q, causal=causal, scale=scale)
     in_specs = [
         head(sq, d),                                              # q
         kv_blk(d),                                                # k
@@ -471,26 +706,32 @@ def _flash_bwd_pallas(q, k, v, do, o, lse, causal, scale, interpret,
         head(nq, block_q),                                        # delta
     ]
     operands = (q, k, v, do, lse, delta)
-    if select is None:
-        kernel = functools.partial(_bwd_kernel, **static)
-    else:
+    if select is not None:
         batch_row = _kv_row(bh, select.shape[0])
-        in_specs.append(
-            _pl.BlockSpec((1, block_k, sq), lambda b, j: (batch_row(b), j, 0)))
-        operands += (select.transpose(0, 2, 1),)
+        in_specs += [_pl.BlockSpec((1, block_k, sq),
+                                   lambda b, j, at=at: (batch_row(b), at(b, j), 0))
+                     for at in selected]
+        operands += (select.transpose(0, 2, 1),) * len(selected)
+    n_select = len(operands) - 6
 
-        def kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, select_ref, *outs):
-            _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *outs,
-                        select_ref=select_ref, **static)
+    def kernel(*refs):
+        ins, selects, outs = refs[:6], refs[6:6 + n_select] or None, refs[6 + n_select:]
+        if folded:
+            _bwd_folded_kernel(*ins, *outs, block=block_q, scale=scale, select_refs=selects)
+        else:
+            _bwd_kernel(*ins, *outs, block_q=block_q, causal=causal, scale=scale,
+                        select_ref=selects and selects[0])
+
     dq, dk, dv = _pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype), part(k), part(v)),
-        grid=(bh, sk // block_k),
+        grid=grid,
         in_specs=in_specs,
         out_specs=(head(sq, d), blk(d), blk(d_v)),
         scratch_shapes=[_pltpu.VMEM((sq, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=_BWD_PARAMS,
+        compiler_params=_RESIDENT_PARAMS,
+        name="flash_bwd_folded" if folded else "flash_bwd",
     )(*operands)
     if group > 1:
         dk, dv = (a.reshape((bkv, group) + a.shape[1:]).sum(axis=1).astype(like.dtype)
@@ -815,12 +1056,13 @@ def _kernel_path(q, k, seq_axis=2, qkv_heads=None):
     return "xla", None
 
 
-def _count_dispatch(kernels, grouped=False, selected=False):
+def _count_dispatch(kernels, grouped=False, selected=False, folded=False):
     """One count a traced call site: dispatch is decided at trace time, so
     after a step has compiled the two counters say which path every
     attention call of the program took (the third how many of them had
     fewer key/value heads than query heads, the fourth how many of the
-    kernels' were given a selection)."""
+    kernels' were given a selection, the fifth how many of the kernels'
+    took the folded causal kernels)."""
     from .. import profiler
 
     if kernels:
@@ -831,6 +1073,16 @@ def _count_dispatch(kernels, grouped=False, selected=False):
         profiler.incr("attention_dispatch_grouped")
     if kernels and selected:
         profiler.incr("attention_dispatch_masked")
+    if kernels and folded:
+        profiler.incr("attention_dispatch_folded")
+
+
+def _blockwise_folds(path, launch, causal, q, k, v, seq_axis, group, selected):
+    """Does a call that ``_kernel_path`` sent down ``path`` take the folded
+    kernels?"""
+    return path == "blockwise" and _folds(
+        causal, q.shape[seq_axis], k.shape[seq_axis], launch.blocks, q.shape[-1], v.shape[-1],
+        q.dtype, group, selected)
 
 
 def _head_group(q, k, head_axis):
@@ -999,7 +1251,8 @@ def flash_attention(q, k, v, causal=False, scale=None, select=None):
         scale = 1.0 / math.sqrt(q.shape[-1])
     group = _head_group(q, k, 1)
     path, launch = _kernel_path(q, k)
-    _count_dispatch(path != "xla", group > 1, select is not None)
+    _count_dispatch(path != "xla", group > 1, select is not None,
+                    _blockwise_folds(path, launch, causal, q, k, v, 2, group, select is not None))
     if path == "blockwise":
         return _flash_kernels(q, k, v, causal, float(scale), launch,
                               _as_selection(select))
@@ -1096,7 +1349,8 @@ def _attend_bshd(q, k, v, causal, scale, select=None, with_lse=False):
         scale = 1.0 / math.sqrt(q.shape[-1])
     group = _head_group(q, k, 2)
     path, launch = _kernel_path(q, k, seq_axis=1)
-    _count_dispatch(path != "xla", group > 1, select is not None)
+    _count_dispatch(path != "xla", group > 1, select is not None,
+                    _blockwise_folds(path, launch, causal, q, k, v, 1, group, select is not None))
     lse = None
     with jax.named_scope("attn.core"):
         if path == "blockwise":

@@ -263,6 +263,7 @@ _counters = {
     "kda_traced": 0,                  # chunked gated delta rule (KDA) call sites traced into a program
     "short_conv_traced": 0,           # gated short convolution call sites traced into a program
     "attention_dispatch_masked": 0,   # of the Pallas ones: call sites given a selection of keys a query
+    "attention_dispatch_folded": 0,   # of the Pallas ones: causal call sites traced onto the folded kernels
     "sparse_attention_traced": 0,     # indexer-selected attention call sites traced into a program
     "sparse_attn_tiles_live": 0,      # 512 x 512 score tiles that hold a selected pair, over layers and steps
     "sparse_attn_tiles_causal": 0,    # ... and those at or below the diagonal

@@ -224,3 +224,89 @@ def test_the_scan_kernels_compile_at_the_nemotron_cells_shape(one_chip, no_compi
         x, dl, dl, bc, bc, d).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+
+
+# cell: batch, query heads, key/value heads, length, key width, value width, selected
+FOLDED_ATTENTION = {
+    "keye-vl-2.0-30b-a3b.clm-s8192": (1, 32, 4, 8192, 128, 128, True),
+    "xing4.0-29b-a4b.clm-s4096": (1, 32, 32, 4096, 192, 128, False),
+    "lfm2-24b-a2b.clm-b2-s8192": (2, 32, 8, 8192, 64, 64, False),
+    "nemotron-3-nano-30b-a3b.clm-s8192": (1, 32, 2, 8192, 128, 128, False),
+}
+
+
+@pytest.mark.parametrize("cell", FOLDED_ATTENTION)
+def test_the_folded_causal_kernels_compile_at_the_decoder_cells_shapes(
+        cell, one_chip, no_compile_cache):
+    """The causal blockwise forward (with its log-sum-exp) and one-pass
+    backward at each decoder cell's shapes, bf16, 512 x 512 blocks: an even
+    number of blocks tiles the square score matrix, so both are the FOLDED
+    kernels, whose cells keep a head's rows (forward) or keys (backward)
+    resident in VMEM and read a block pair a step at offsets the step index
+    gives: what an interpreter does not check."""
+    b, h, h_kv, s, d, d_v, selected = FOLDED_ATTENTION[cell]
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    launch = attn_ops._Launch(False, (512, 512))
+    assert attn_ops._pallas_blocks(s, s) == (512, 512)
+    assert attn_ops._folds(True, s, s, (512, 512), d, d_v, jnp.bfloat16, h // h_kv, selected)
+
+    def loss(q, k, v, *select):
+        out, lse = attn_ops._flash_kernels_lse(q, k, v, True, d ** -0.5, launch, *select)
+        return out.astype(jnp.float32).sum(), lse
+
+    operands = (spec((b, h, s, d)), spec((b, h_kv, s, d)), spec((b, h_kv, s, d_v)))
+    operands += (spec((b, s, s), jnp.int8),) if selected else ()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2), has_aux=True)).lower(
+        *operands).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "flash_fwd_folded" in text and "flash_bwd_folded" in text
+
+
+@pytest.mark.parametrize("kernel", ["forward", "backward"])
+def test_the_folded_kernels_code_does_not_grow_with_the_trip_count(
+        kernel, one_chip, no_compile_cache):
+    """The folded kernels at S 4096 and S 8192 with 512 x 512 blocks (9 and 17
+    steps a grid cell), at the keye cell's heads under its int8 selection:
+    the executable's generated code (``memory_analysis``; the Mosaic
+    kernel's bundles are in it) is no larger at 17 steps than at 9 within a
+    tenth, where an unrolled loop's grows with the steps (1.77 x the
+    forward's, 1.43 x the backward's).  Two steps a loop body and the one
+    left over are three copies of the step whatever the count."""
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    scale = 128 ** -0.5
+
+    def code_bytes(s):
+        select = spec((1, s, s), jnp.int8)
+        if kernel == "forward":
+            fn = lambda q, k, v, select: attn_ops._flash_fwd_pallas(
+                q, k, v, True, scale, False, 512, 512, with_lse=True, select=select)
+            operands = (spec((32, s, 128)), spec((4, s, 128)), spec((4, s, 128)), select)
+        else:
+            fn = lambda q, k, v, do, o, lse, select: attn_ops._flash_bwd_pallas(
+                q, k, v, do, o, lse, True, scale, False, 512, 512, select=select)
+            operands = (spec((32, s, 128)), spec((4, s, 128)), spec((4, s, 128)),
+                        spec((32, s, 128)), spec((32, s, 128)), spec((32, s), jnp.float32), select)
+        compiled = jax.jit(fn).lower(*operands).compile()
+        assert ("flash_fwd_folded" if kernel == "forward" else "flash_bwd_folded") in compiled.as_text()
+        return compiled.memory_analysis().generated_code_size_in_bytes
+
+    assert code_bytes(8192) <= 1.1 * code_bytes(4096)
+
+
+@pytest.mark.parametrize("s, folds", [(16384, True), (32768, False)])
+def test_long_causal_calls_compile_folded_only_where_the_rows_fit(s, folds, one_chip,
+                                                                  no_compile_cache):
+    """tools/bench_longcontext.py's causal shapes, 8 heads of 64, bf16, 512 x
+    512 blocks, value and gradient: at S 16384 the folded kernels' resident
+    rows fit in VMEM; at S 32768 the folded backward's would not (the
+    compiler refuses it), so the call keeps the unfolded kernels."""
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    launch = attn_ops._Launch(False, (512, 512))
+
+    def loss(q, k, v):
+        return attn_ops._flash_kernels(q, k, v, True, 0.125, launch).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *(spec((1, 8, s, 64)),) * 3).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert ("flash_fwd_folded" in text) == folds and ("flash_bwd_folded" in text) == folds
